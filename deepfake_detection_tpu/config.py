@@ -321,8 +321,9 @@ class TrainConfig:
     profile_capture: int = 20
 
     # --- misc / infra ---
-    # jax persistent compilation cache dir ("" = off): repeat runs of an
-    # unchanged (program, jax/jaxlib, backend, topology) skip XLA
+    # jax persistent compilation cache dir; JAX_COMPILATION_CACHE_DIR wins
+    # over it, "" = <checkout>/.jax_cache (utils/compile_cache.py): repeat
+    # runs of an unchanged (program, jax/jaxlib, backend, topology) skip XLA
     # backend compilation — re-tracing/lowering still happens, which is
     # why serving layers an AOT executable store on top (PERF.md §9)
     compile_cache_dir: str = ""

@@ -140,9 +140,6 @@ CTYPES_EXEMPT = (
 SHARD_MAP_ALLOWLIST = (
     "deepfake_detection_tpu/parallel/ring_attention.py",
     "deepfake_detection_tpu/parallel/pp.py",
-    # the version shim: imports + signature-probes shard_map so every
-    # legacy caller shares ONE compat surface — it never builds programs
-    "deepfake_detection_tpu/parallel/_compat.py",
 )
 
 

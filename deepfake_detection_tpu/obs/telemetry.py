@@ -305,22 +305,23 @@ def _num(v: float):
 # ---------------------------------------------------------------------------
 
 def peak_flops(device=None) -> float:
-    """Per-chip bf16 peak for the MFU denominator; 0.0 when unknown (CPU —
-    the gauge then reads 0 rather than a meaningless ratio)."""
+    """Per-chip bf16 peak for the MFU denominator.
+
+    0.0 on a non-TPU platform (the gauge then reads 0 rather than a
+    meaningless ratio); a TPU whose ``device_kind`` is not in the table
+    raises — a peak is never assumed for a chip nobody looked up."""
     if device is None:
-        try:
-            import jax
-            device = jax.devices()[0]
-        except Exception:               # noqa: BLE001 — backend-less callers
-            return 0.0
-    kind = getattr(device, "device_kind", "cpu")
-    for k, v in _PEAK_FLOPS.items():
-        if kind.lower().startswith(k.lower()):
-            return v
-    for k, v in _PEAK_FLOPS.items():
-        if k.lower() in kind.lower():
-            return v
-    return 0.0
+        import jax
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return 0.0
+    peak = {k.lower(): v for k, v in _PEAK_FLOPS.items()}.get(
+        device.device_kind.lower())
+    if peak is not None:
+        return peak
+    raise ValueError(
+        f"no bf16 peak recorded for TPU device_kind {device.device_kind!r}: "
+        f"add it to obs/telemetry.py:_PEAK_FLOPS with its source")
 
 
 def forward_flops_per_sample(model, variables, input_shape) -> float:

@@ -13,12 +13,13 @@ Kernel structure (same conventions as ``ops/flash_attention.py``):
 
 * grid ``(B, C tiles, H tiles)`` with the H-tile axis innermost so Pallas
   pipelines one ``(th_in, W, Ct)`` input block at a time through VMEM.
-  Depthwise halos (``th_in = th_out·stride + k − stride``) overlap between
-  consecutive H tiles, which plain blocked BlockSpecs cannot express — the
-  input spec uses **unblocked (element-offset) indexing** over an input the
-  wrapper has already padded in XLA (one pad op; XLA materializes conv
-  padding anyway).
-* the k² taps unroll as static Python loops of strided ``lax.slice`` +
+  Depthwise halos overlap between consecutive H tiles, which plain blocked
+  BlockSpecs cannot express — the input spec is an **element-offset
+  window** (every dim a ``pl.Element``) over an input the wrapper has
+  already padded in XLA (one pad op; XLA materializes conv padding anyway)
+  and, for stride 2, split into its 2×2 polyphase components
+  (``_halo_tiles``) so the kernel never needs a strided slice.
+* the k² taps unroll as static Python loops of unit-stride window slices +
   multiply-accumulate on the VPU, f32 accumulation regardless of input
   dtype; the affine + activation epilogue runs on the accumulator while it
   is still VMEM-resident.
@@ -35,7 +36,7 @@ On non-TPU backends the kernels run under the Pallas interpreter
 gradient parity against the XLA lowering (tests/test_depthwise_pallas.py).
 Outputs declare their varying-mesh-axes set from the input operand
 (``_out_struct``), so the op is check_vma-safe under ``shard_map``
-(parallel/_compat.py) exactly like the flash kernels.
+exactly like the flash kernels.
 """
 
 from __future__ import annotations
@@ -45,16 +46,12 @@ from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover - exercised only on exotic installs
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .conv import resolve_padding
+from .flash_attention import _out_struct, _scratch, resolve_interpret
 
 __all__ = ["fused_depthwise", "FUSED_DW_ACTS"]
 
@@ -64,29 +61,13 @@ FUSED_DW_ACTS = ("none", "silu", "relu")
 _LANES = 128
 
 
-def _vmem_spec(block_shape, index_map, unblocked: bool = False):
-    kwargs = {}
-    if pltpu is not None:
-        kwargs["memory_space"] = pltpu.VMEM
-    if unblocked:
-        kwargs["indexing_mode"] = pl.Unblocked()
-    return pl.BlockSpec(block_shape, index_map, **kwargs)
-
-
-def _scratch(shape):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, jnp.float32)
-    return pl.MemoryRef(shape, jnp.float32)  # interpreter fallback
-
-
-def _out_struct(shape, dtype, like):
-    """ShapeDtypeStruct inheriting ``like``'s varying-mesh-axes set so the
-    same kernels work standalone and inside ``shard_map`` (check_vma)."""
-    typeof = getattr(jax, "typeof", None)
-    vma = getattr(typeof(like), "vma", None) if typeof is not None else None
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+def _vmem_spec(block_shape, index_map, element: bool = False):
+    """``element=True``: every dim is a ``pl.Element`` window, so
+    ``index_map`` returns element offsets, not block indices (Mosaic wants
+    all dims of an operand element-indexed or none)."""
+    if element:
+        block_shape = tuple(pl.Element(d) for d in block_shape)
+    return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
 
 
 def _act_f32(name: str):
@@ -113,12 +94,14 @@ def _to_tuple(v) -> Tuple[int, int]:
     return (v, v) if isinstance(v, int) else tuple(v)
 
 
-def _pick_block_h(w: int, ct: int, kh: int, stride: int,
+def _pick_block_h(wph: int, ct: int, kh: int, stride: int,
                   ho: int, budget: int = 2 * 1024 * 1024) -> int:
-    """Largest output-rows-per-tile whose f32 input halo block fits the VMEM
-    budget (Pallas double-buffers, so stay well under the 16 MB arena)."""
+    """Largest output-rows-per-tile whose f32 input halo block (all stride²
+    phases) fits the VMEM budget (Pallas double-buffers, so stay well under
+    the 16 MB arena)."""
     th = max(1, min(ho, 8))
-    while th > 1 and (th * stride + kh - stride) * w * ct * 4 > budget:
+    halo = (kh - 1) // stride
+    while th > 1 and stride * stride * (th + halo) * wph * ct * 4 > budget:
         th -= 1
     return th
 
@@ -129,6 +112,58 @@ def _channel_tile(c: int) -> int:
     if c % _LANES == 0:
         return _LANES
     return c
+
+
+def _halo_tiles(xp, kh: int, kw: int, stride: int, ho: int, wo: int):
+    """Phase-split, tile-padded input + its halo BlockSpec factory.
+
+    Mosaic has neither a strided slice of a loaded value nor a strided load
+    of packed (bf16) data, so a stride-``s`` conv is fed as its ``s²``
+    polyphase components — ``xph[b, p·s+q, i, j] = xp[b, i·s+p, j·s+q]`` —
+    and tap ``(r, c)`` becomes a unit-stride window of phase ``(r%s, c%s)``
+    at offset ``(r//s, c//s)``.  Stride 1 is the single-phase case (a free
+    reshape).  Rows are padded so every H tile's halo block is in-bounds.
+
+    Returns ``(xph, th_out, n_h, spec)`` with ``spec(b_of, c_of, h_of)``
+    building the element-indexed input BlockSpec from grid-index pickers.
+    """
+    b, hp, wp, c = xp.shape
+    ct = _channel_tile(c)
+    wph = wo + (kw - 1) // stride
+    th_out = _pick_block_h(wph, ct, kh, stride, ho)
+    n_h = -(-ho // th_out)
+    th_in = th_out + (kh - 1) // stride
+    hph = (n_h - 1) * th_out + th_in
+    # lax.pad: negative high padding crops rows/cols no tap window reaches
+    xp = lax.pad(xp, jnp.zeros((), xp.dtype),
+                 ((0, 0, 0), (0, hph * stride - hp, 0),
+                  (0, wph * stride - wp, 0), (0, 0, 0)))
+    xph = xp.reshape(b, hph, stride, wph, stride, c)
+    xph = xph.transpose(0, 2, 4, 1, 3, 5).reshape(
+        b, stride * stride, hph, wph, c)
+
+    def spec(b_of, c_of, h_of):
+        # a lone whole-C tile (C not a lane multiple) takes a literal 0
+        # lane offset: Mosaic must prove the offset divides the 128 tiling
+        # and cannot see that the channel grid index is always 0 there
+        def index_map(*g):
+            return (b_of(*g), 0, h_of(*g) * th_out, 0,
+                    0 if ct == c else c_of(*g) * ct)
+        return _vmem_spec((1, stride * stride, th_in, wph, ct), index_map,
+                          element=True)
+    return xph, th_out, n_h, spec
+
+
+def _tap_reader(x_ref, stride: int, th_out: int, wo: int):
+    """``tap(r, c)`` → the f32 ``(th_out, wo, ct)`` window of the loaded
+    polyphase halo block that kernel tap ``(r, c)`` multiplies."""
+    xv = x_ref[0].astype(jnp.float32)
+
+    def tap(r, c):
+        r0, c0 = r // stride, c // stride
+        return xv[(r % stride) * stride + c % stride,
+                  r0:r0 + th_out, c0:c0 + wo, :]
+    return tap
 
 
 # ---------------------------------------------------------------------------
@@ -142,16 +177,12 @@ def _fwd_kernel(x_ref, w_ref, s_ref, b_ref, y_ref, *z_ref, stride, kh, kw,
     pre-affine output the backward consumes) exists only on the
     residual-saving call — the primal never allocates it."""
     ct = x_ref.shape[-1]
-    xv = x_ref[0].astype(jnp.float32)
+    tap = _tap_reader(x_ref, stride, th_out, wo)
     acc = jnp.zeros((th_out, wo, ct), jnp.float32)
     for r in range(kh):
         for s in range(kw):
-            tap = lax.slice(
-                xv, (r, s, 0),
-                (r + (th_out - 1) * stride + 1, s + (wo - 1) * stride + 1,
-                 ct),
-                (stride, stride, 1))
-            acc = acc + tap * w_ref[r, s][None, None, :].astype(jnp.float32)
+            acc = acc + tap(r, s) * w_ref[r, s][None, None, :].astype(
+                jnp.float32)
     if z_ref:
         z_ref[0][0] = acc
     u = acc * s_ref[0][None, None, :] + b_ref[0][None, None, :]
@@ -160,28 +191,20 @@ def _fwd_kernel(x_ref, w_ref, s_ref, b_ref, y_ref, *z_ref, stride, kh, kw,
 
 def _dw_call(xp, w, scale, bias, *, stride, act, ho, wo, out_dtype,
              want_z, interpret):
-    """Padded-layout forward: ``xp (B, Hp, Wp, C)`` pre-padded so that every
-    H tile's halo block is in-bounds; returns ``y (B, Ho, Wo, C)`` and (when
-    ``want_z``) the f32 pre-affine conv output for the backward."""
-    b, hp, wp, c = xp.shape
+    """Padded-layout forward: ``xp (B, Hp, Wp, C)`` carries the conv
+    padding; returns ``y (B, Ho, Wo, C)`` and (when ``want_z``) the f32
+    pre-affine conv output for the backward."""
+    b, c = xp.shape[0], xp.shape[-1]
     kh, kw = w.shape[0], w.shape[1]
     ct = _channel_tile(c)
-    th_out = _pick_block_h(wp, ct, kh, stride, ho)
-    n_h = -(-ho // th_out)
-    th_in = th_out * stride + kh - stride
-    # tiling may overshoot Ho (last tile) — pad H so every halo block is
-    # in-bounds; the overshoot rows are sliced off below
-    need_hp = (n_h * th_out - 1) * stride + kh
-    if need_hp > hp:
-        xp = jnp.pad(xp, ((0, 0), (0, need_hp - hp), (0, 0), (0, 0)))
-        hp = need_hp
+    xph, th_out, n_h, x_spec = _halo_tiles(xp, kh, kw, stride, ho, wo)
+    # tiling may overshoot Ho (last tile); the overshoot rows are sliced off
     ho_p = n_h * th_out
 
     grid = (b, c // ct, n_h)
     in_specs = [
-        _vmem_spec((1, th_in, wp, ct),
-                   lambda bi, ci, hi: (bi, hi * th_out * stride, 0, ci * ct),
-                   unblocked=True),
+        x_spec(lambda bi, ci, hi: bi, lambda bi, ci, hi: ci,
+               lambda bi, ci, hi: hi),
         _vmem_spec((kh, kw, ct), lambda bi, ci, hi: (0, 0, ci)),
         _vmem_spec((1, ct), lambda bi, ci, hi: (0, ci)),
         _vmem_spec((1, ct), lambda bi, ci, hi: (0, ci)),
@@ -200,7 +223,7 @@ def _dw_call(xp, w, scale, bias, *, stride, act, ho, wo, out_dtype,
     out = pl.pallas_call(
         kern, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, interpret=interpret,
-    )(xp, w, scale, bias)
+    )(xph, w, scale, bias)
     if want_z:
         y, z = out
         return y[:, :ho], z[:, :ho]
@@ -216,7 +239,6 @@ def _dwgrad_kernel(x_ref, dz_ref, dw_ref, acc_ref, *, stride, kh, kw, th_out,
     """One (c-tile, b, h-tile) grid cell accumulating ``dw[r·kw+s, c] +=
     Σ_{rows,cols} dz ⊙ x_shift(r,s)`` into VMEM scratch; written once at the
     last (b, h) step."""
-    ct = x_ref.shape[-1]
     bi = pl.program_id(1)
     hi = pl.program_id(2)
     nb = pl.num_programs(1)
@@ -226,16 +248,11 @@ def _dwgrad_kernel(x_ref, dz_ref, dw_ref, acc_ref, *, stride, kh, kw, th_out,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    xv = x_ref[0].astype(jnp.float32)
+    tap = _tap_reader(x_ref, stride, th_out, wo)
     dzv = dz_ref[0].astype(jnp.float32)
     for r in range(kh):
         for s in range(kw):
-            tap = lax.slice(
-                xv, (r, s, 0),
-                (r + (th_out - 1) * stride + 1, s + (wo - 1) * stride + 1,
-                 ct),
-                (stride, stride, 1))
-            acc_ref[r * kw + s, :] += jnp.sum(tap * dzv, axis=(0, 1))
+            acc_ref[r * kw + s, :] += jnp.sum(tap(r, s) * dzv, axis=(0, 1))
 
     @pl.when(jnp.logical_and(bi == nb - 1, hi == nh - 1))
     def _finalize():
@@ -245,14 +262,9 @@ def _dwgrad_kernel(x_ref, dz_ref, dw_ref, acc_ref, *, stride, kh, kw, th_out,
 def _dwgrad_call(xp, dz, kh, kw, *, stride, ho, wo, interpret):
     """dw (kh, kw, C) from the padded input and the (zero-padded to the tile
     grid) upstream conv-output gradient."""
-    b, hp, wp, c = xp.shape
+    b, c = xp.shape[0], xp.shape[-1]
     ct = _channel_tile(c)
-    th_out = _pick_block_h(wp, ct, kh, stride, ho)
-    n_h = -(-ho // th_out)
-    th_in = th_out * stride + kh - stride
-    need_hp = (n_h * th_out - 1) * stride + kh
-    if need_hp > hp:
-        xp = jnp.pad(xp, ((0, 0), (0, need_hp - hp), (0, 0), (0, 0)))
+    xph, th_out, n_h, x_spec = _halo_tiles(xp, kh, kw, stride, ho, wo)
     ho_p = n_h * th_out
     if ho_p > ho:
         # zero rows contribute nothing to the correlation
@@ -264,10 +276,8 @@ def _dwgrad_call(xp, dz, kh, kw, *, stride, ho, wo, interpret):
         kern,
         grid=(c // ct, b, n_h),
         in_specs=[
-            _vmem_spec((1, th_in, wp, ct),
-                       lambda ci, bi, hi: (bi, hi * th_out * stride, 0,
-                                           ci * ct),
-                       unblocked=True),
+            x_spec(lambda ci, bi, hi: bi, lambda ci, bi, hi: ci,
+                   lambda ci, bi, hi: hi),
             _vmem_spec((1, th_out, wo, ct),
                        lambda ci, bi, hi: (bi, hi, 0, ci)),
         ],
@@ -275,7 +285,7 @@ def _dwgrad_call(xp, dz, kh, kw, *, stride, ho, wo, interpret):
         out_shape=_out_struct((kh * kw, c), jnp.float32, xp),
         scratch_shapes=[_scratch((kh * kw, ct))],
         interpret=interpret,
-    )(xp, dz)
+    )(xph, dz)
     return dw.reshape(kh, kw, c)
 
 
@@ -315,8 +325,7 @@ def fused_depthwise(x: jnp.ndarray, w: jnp.ndarray,
     assert sh == sw, f"anisotropic depthwise stride unsupported ({sh},{sw})"
     stride = int(sh)
     kh, kw = int(w.shape[0]), int(w.shape[1])
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret, "fused_depthwise")
 
     pad = resolve_padding(padding, (kh, kw), 1, stride)
     if pad == "SAME":

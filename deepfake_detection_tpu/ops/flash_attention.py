@@ -38,43 +38,51 @@ On non-TPU backends the same kernels run under the Pallas interpreter
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on some CPU-only installs; the rest of
-    # the package (and the interpreter path) must keep importing
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover - exercised only on exotic installs
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention"]
+
+_logger = logging.getLogger(__name__)
 
 _NEG_INF = float("-inf")
 _LANES = 128          # scalar-per-row scratch is lane-replicated to 128
 
+_warned_interpreted = set()
+
+
+def resolve_interpret(interpret: Optional[bool], kernel: str) -> bool:
+    """A Pallas kernel's ``interpret`` default: compiled on TPU, interpreted
+    elsewhere (how the CPU suite checks parity).  An interpreted run is
+    never a measurement, so it is logged — once per kernel — at WARNING;
+    chip paths assert the backend before relying on the default."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if interpret and kernel not in _warned_interpreted:
+        _warned_interpreted.add(kernel)
+        _logger.warning("%s runs under the Pallas INTERPRETER (backend %r)"
+                        " — correctness only, not the compiled kernel",
+                        kernel, jax.default_backend())
+    return interpret
+
 
 def _vmem_spec(block_shape, index_map):
-    if pltpu is not None:
-        return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
-    return pl.BlockSpec(block_shape, index_map)
+    return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
 
 
 def _smem_scalar_spec():
     """(1, 1) int32 scalar operand (offsets); scalars live in SMEM on TPU."""
-    if pltpu is not None:
-        return pl.BlockSpec((1, 1), lambda *_: (0, 0),
-                            memory_space=pltpu.SMEM)
-    return pl.BlockSpec((1, 1), lambda *_: (0, 0))
+    return pl.BlockSpec((1, 1), lambda *_: (0, 0), memory_space=pltpu.SMEM)
 
 
 def _scratch(shape):
     """float32 VMEM scratch buffer declaration."""
-    if pltpu is not None:
-        return pltpu.VMEM(shape, jnp.float32)
-    return pl.MemoryRef(shape, jnp.float32)  # interpreter fallback
+    return pltpu.VMEM(shape, jnp.float32)
 
 
 def _as_scalar(x) -> jnp.ndarray:
@@ -88,8 +96,7 @@ def _out_struct(shape, dtype, like):
     mesh axes they vary over; inherit that from an input operand so the same
     kernels work standalone and under any mesh.
     """
-    typeof = getattr(jax, "typeof", None)   # pre-0.6 jax: no VMA types
-    vma = getattr(typeof(like), "vma", None) if typeof is not None else None
+    vma = jax.typeof(like).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -424,8 +431,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         f"flash_attention supports self-attention shapes only "
         f"(q{q.shape} k{k.shape} v{v.shape} must be equal)")
     b, l, h, d = q.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret, "flash_attention")
     scale = scale if scale is not None else d ** -0.5
     block_q = min(block_q, _round_up(l, 128))
     block_k = min(block_k, _round_up(l, 128))

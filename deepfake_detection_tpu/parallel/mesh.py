@@ -70,8 +70,7 @@ def initialize_distributed(cluster=None, hostname: Optional[str] = None,
     # NOTE: must run before anything touches the XLA backend (so no
     # jax.process_count()/jax.devices() here — they'd initialize it and make
     # the distributed init fail).
-    from ._compat import distributed_is_initialized
-    if distributed_is_initialized():
+    if jax.distributed.is_initialized():
         return  # already initialized (e.g. by the TPU pod runtime)
     kwargs = {}
     if cluster.coordinator_address:

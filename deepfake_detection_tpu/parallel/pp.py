@@ -28,11 +28,9 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import _compat
-from ._compat import shard_map
 
 __all__ = ["gpipe_apply", "gpipe_transformer_tower",
            "pipeline_sharding", "stack_block_params"]
@@ -60,7 +58,7 @@ def gpipe_apply(block_apply: Callable, stacked_params: Any, x: jnp.ndarray,
     Output is valid on every stage (the last stage's results are summed
     across the axis — all other stages contribute zeros).
     """
-    s_count = _compat.axis_size(axis_name)
+    s_count = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     m_count = num_microbatches
     b = x.shape[0]
@@ -101,7 +99,7 @@ def gpipe_apply(block_apply: Callable, stacked_params: Any, x: jnp.ndarray,
     # outs starts as plain zeros and must be marked varying for the scan
     # carry type to be stable
     buf0 = jnp.where(idx == 0, micro[0], jnp.zeros_like(micro[0]))
-    outs0 = _compat.pcast_varying(jnp.zeros_like(micro), axis_name)
+    outs0 = lax.pcast(jnp.zeros_like(micro), axis_name, to="varying")
     (_, outs), _ = lax.scan(step, (buf0, outs0),
                             jnp.arange(s_count + m_count - 1))
     # only the last stage holds real outputs; psum broadcasts them

@@ -29,11 +29,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import _compat
-from ._compat import shard_map
 
 __all__ = ["ring_attention", "ring_flash_attention", "ulysses_attention",
            "ring_self_attention", "full_attention"]
@@ -64,7 +62,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     Call inside ``shard_map`` with the sequence dim sharded over
     ``axis_name``.  K/V rotate ``axis_size`` times; accumulation is float32.
     """
-    n = _compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     b, lq, h, d = q.shape
@@ -106,7 +104,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # fori_loop carry type matches the (sharded, hence varying) K/V blocks
     # (pre-0.6 jax has no varying-manual-axes type system — no-op there)
     def vary(x):
-        return _compat.pcast_varying(x, axis_name)
+        return lax.pcast(x, axis_name, to="varying")
     acc0 = vary(jnp.zeros((b, lq, h, d), jnp.float32))
     m0 = vary(jnp.full((b, h, lq), -jnp.inf, jnp.float32))
     l0 = vary(jnp.zeros((b, h, lq), jnp.float32))
@@ -160,14 +158,14 @@ def ring_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     while dQ accumulates locally; each per-block gradient is the Pallas
     backward kernel pair, reusing the forward's global logsumexp.
     """
-    from ..ops.flash_attention import (_bwd_dkv, _bwd_dq, _fwd, _round_up)
+    from ..ops.flash_attention import (_bwd_dkv, _bwd_dq, _fwd, _round_up,
+                                       resolve_interpret)
 
-    n = _compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     b, lq, h, d = q.shape
     lk = k.shape[1]
     scale_ = scale if scale is not None else d ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret, "ring_flash_attention")
     block_q = min(block_q, _round_up(lq, 128))
     block_k = min(block_k, _round_up(lk, 128))
     lpq, lpk = _round_up(lq, block_q), _round_up(lk, block_k)
@@ -183,7 +181,7 @@ def ring_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return jnp.transpose(x, (0, 2, 1, 3))
 
     def vary(x):
-        return _compat.pcast_varying(x, axis_name)
+        return lax.pcast(x, axis_name, to="varying")
 
     # K/V (and dK/dV in the backward) travel the ring in their raw
     # (B, l, H, D) layout: the ppermute link is the scarce ICI resource,
@@ -275,7 +273,7 @@ def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     Re-shards seq→heads, runs dense local attention on H/n heads over the
     full sequence, re-shards back.  Requires ``H % axis_size == 0``.
     """
-    n = _compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     assert q.shape[2] % n == 0, f"heads {q.shape[2]} not divisible by {n}"
 
     def to_heads(x):  # (B, L/n, H, D) -> (B, L, H/n, D)
@@ -312,5 +310,5 @@ def ring_self_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     sharded = shard_map(
         functools.partial(fn, axis_name=seq_axis, causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        **_compat.shard_map_check_kwargs(not interpreted_flash))
+        check_vma=not interpreted_flash)
     return sharded(q, k, v)

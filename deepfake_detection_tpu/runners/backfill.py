@@ -161,7 +161,8 @@ class _Pipeline:
             store = ExecutableStore(cfg.warmstart_dir)
             fields = self._store_fields(cfg, model)
             try:
-                compiled, manifest = store.load(fields)
+                compiled, manifest = store.load(
+                    fields, execution_devices=list(self.mesh.devices.flat))
                 self.warm_source = "store"
             except WarmstartMiss as miss:
                 compiled = None
@@ -202,7 +203,8 @@ class _Pipeline:
                     self._golden_input(), self._bsh),
                 self._mean, self._std)))
             if store.save(fields, self._compiled, golden_scores=scores,
-                          params_fingerprint=self._fingerprint()):
+                          params_fingerprint=self._fingerprint(),
+                          execution_devices=list(self.mesh.devices.flat)):
                 _logger.info("warm store: serialized %s", fields["bucket"])
 
     # ------------------------------------------------------------------
@@ -355,14 +357,10 @@ def run_backfill(cfg, stop: Optional[threading.Event] = None
                                    install_backend_compile_listener)
 
     cfg.validate_required()
-    if getattr(cfg, "compile_cache_dir", ""):
-        # jax persistent compilation cache: the fallback tier under the
-        # AOT executable store (PERF.md §9) — before the first compile
-        import jax
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.abspath(cfg.compile_cache_dir))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    # jax persistent compilation cache: the fallback tier under the AOT
+    # executable store — before the first compile
+    from ..utils.compile_cache import setup_compile_cache
+    setup_compile_cache(cfg.compile_cache_dir)
     install_backend_compile_listener()
     stop = stop if stop is not None else threading.Event()
     chaos = chaos_from_env()

@@ -113,16 +113,10 @@ def build_engine(cfg):
     # contract is checked against the whole start, not just the engine
     install_backend_compile_listener()
 
-    if cfg.compile_cache_dir:
-        # jax's persistent compilation cache: the fallback tier under
-        # the AOT executable store — must be configured before the first
-        # compile (PERF.md §9; size/time floors dropped so CPU-sized
-        # serving programs actually persist)
-        import jax
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.abspath(cfg.compile_cache_dir))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    # jax's persistent compilation cache: the fallback tier under the
+    # AOT executable store — before the first compile
+    from ..utils.compile_cache import setup_compile_cache
+    setup_compile_cache(cfg.compile_cache_dir)
     t_import = time.monotonic()
     _logger.info("building %s (in_chans=%d, canvas %d², dtype=%s)",
                  cfg.model, cfg.in_chans, cfg.image_size, cfg.dtype)

@@ -124,6 +124,8 @@ def main(argv=None) -> None:
                         "parity, bf16/int8 = the engine's PTQ serving "
                         "modes (tools/quant_parity.py measures the drift)")
     args = p.parse_args(argv)
+    from ..utils.compile_cache import setup_compile_cache
+    setup_compile_cache()
     if not args.images:
         print("Please input your images. e.g. python -m "
               "deepfake_detection_tpu.runners.test image1 image2")

@@ -59,6 +59,7 @@ from ..train import (EXIT_PREEMPTED, CheckpointCorrupt, CheckpointSaver,
                      set_learning_rate, train_one_epoch, validate,
                      wait_pending_saves)
 from ..utils import get_outdir, setup_default_logging, update_summary
+from ..utils.compile_cache import setup_compile_cache
 
 _logger = logging.getLogger("train")
 
@@ -168,13 +169,8 @@ def build_datasets(cfg: TrainConfig, input_size, pack_dir=None,
 
 def main(cfg: TrainConfig) -> Dict[str, float]:
     """Train to completion; returns the best eval metrics."""
-    if cfg.compile_cache_dir:
-        # jax persistent compilation cache (PERF.md §9): restarted runs
-        # skip the XLA compile wall — must land before the first compile
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.abspath(cfg.compile_cache_dir))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    # restarted runs skip the XLA compile wall — before the first compile
+    setup_compile_cache(cfg.compile_cache_dir)
     rank = jax.process_index()
     if cfg.tp_size > 1:
         if cfg.mesh_shape is not None or cfg.fsdp:
@@ -198,6 +194,16 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
     dp_size = int(mesh.shape.get(batch_axis, n_dev))
     _logger.info("Training with %d devices, mesh %s, process %d/%d",
                  n_dev, dict(mesh.shape), rank, jax.process_count())
+    if (cfg.fused_depthwise == "pallas" or cfg.attn_impl == "flash") and \
+            jax.default_backend() != "tpu" and \
+            "cpu" not in (jax.config.jax_platforms or ""):
+        # the kernels' interpret default is "not on TPU": right where the
+        # CPU was asked for by name (tests, CI), wrong for a chip run that
+        # lost its chip — that one must not train on the interpreter
+        raise RuntimeError(
+            f"--fused-depthwise pallas / --attn-impl flash need a TPU; jax "
+            f"fell back to {jax.default_backend()!r} without being asked "
+            f"(JAX_PLATFORMS={jax.config.jax_platforms!r})")
     if cfg.fused_depthwise == "pallas" and n_dev > 1 and \
             jax.default_backend() == "tpu":
         # chip-gated residue of the GSPMD migration (ROADMAP chip-debt):
@@ -752,7 +758,7 @@ def launch_main(argv=None) -> Dict[str, float]:
     setup_default_logging()
     cfg = TrainConfig.from_args(argv)
     if _looks_like_torch_checkpoint(cfg.initial_checkpoint):
-        # fail before mesh construction and the (relay-expensive) jitted
+        # fail before mesh construction and the jitted
         # init, not minutes into main() with a cryptic msgpack error
         raise ValueError(
             f"--initial-checkpoint {cfg.initial_checkpoint} is a torch "

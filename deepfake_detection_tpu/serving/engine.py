@@ -483,6 +483,10 @@ class InferenceEngine:
                           {b for (b, _c) in list(e.compiled)})}
                 for mid, e in list(self._models.items())},
             "breaker": self.breaker.state,
+            # the device the executables run on, as jax reports it — a
+            # chip deployment that came up on the CPU is visible here
+            "device": {"platform": jax.devices()[0].platform,
+                       "kind": jax.devices()[0].device_kind},
             "queue_depth": int(self.metrics.queue_depth),
             "inflight": int(self.metrics.inflight),
         }
@@ -610,7 +614,8 @@ class InferenceEngine:
         from .warmstart import WarmstartMiss
         fields = self._store_fields(entry, bucket, chans)
         try:
-            compiled, manifest = self.warmstart.load(fields)
+            compiled, manifest = self.warmstart.load(
+                fields, execution_devices=jax.devices()[:1])
         except WarmstartMiss as e:
             if e.reason == "absent":
                 self.metrics.warmstart_misses_total.inc()
@@ -684,7 +689,8 @@ class InferenceEngine:
                                       entry.variables, gx))
         if self.warmstart.save(fields, entry.compiled[(bucket, chans)],
                                golden_scores=scores,
-                               params_fingerprint=entry.fingerprint):
+                               params_fingerprint=entry.fingerprint,
+                               execution_devices=jax.devices()[:1]):
             self.metrics.warmstart_serialized_total.inc()
 
     def _compile_units(self, entry: _ModelEntry,

@@ -9,7 +9,7 @@ with an injected clock, while ``serving/engine.py`` wires them to the
 real device loop and ``tools/chaos_serve.py`` proves them end-to-end
 against a live server under injected faults.
 
-Failure taxonomy (what an HTTP client sees):
+Failure classes (what an HTTP client sees):
 
 * :class:`NonFiniteScores` — the device batch executed but produced
   NaN/Inf rows.  Mapped to **503** (+ Retry-After): the *request* was

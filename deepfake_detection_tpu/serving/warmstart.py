@@ -29,7 +29,7 @@ from __future__ import annotations
 import logging
 import os
 import pickle
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Sequence, Tuple
 
 from . import warmkey
 
@@ -63,8 +63,13 @@ class ExecutableStore:
                 and os.path.exists(self.manifest_path(key)))
 
     # -- load ----------------------------------------------------------
-    def load(self, fields: Dict[str, Any]) -> Tuple[Any, Dict[str, Any]]:
-        """Deserialize the executable for ``fields``.
+    def load(self, fields: Dict[str, Any],
+             execution_devices: Sequence[Any]
+             ) -> Tuple[Any, Dict[str, Any]]:
+        """Deserialize the executable for ``fields`` onto
+        ``execution_devices`` — the devices it was compiled for (jax binds
+        a loaded executable to EVERY local device when given none, which
+        breaks a one-device program on a multi-device host).
 
         Returns ``(compiled, manifest)`` or raises :class:`WarmstartMiss`
         with a loud reason.  The caller MUST still run the golden-batch
@@ -88,14 +93,16 @@ class ExecutableStore:
                 payload, in_tree, out_tree = pickle.loads(f.read())
             from jax.experimental import serialize_executable
             compiled = serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree)
+                payload, in_tree, out_tree,
+                execution_devices=execution_devices)
         except Exception as e:  # corrupt pickle, version skew, XLA reject
             raise WarmstartMiss("deserialize-failed", f"{key[:12]}: {e}")
         return compiled, manifest
 
     # -- save ----------------------------------------------------------
     def save(self, fields: Dict[str, Any], compiled: Any, *,
-             golden_scores: Any, params_fingerprint: str) -> bool:
+             golden_scores: Any, params_fingerprint: str,
+             execution_devices: Sequence[Any]) -> bool:
         """Serialize ``compiled`` under its content key.
 
         Best-effort: serialization failures (unsupported backend, full
@@ -114,7 +121,8 @@ class ExecutableStore:
             # loud fallback, so refuse it here and let that spawn ride
             # the compile-cache tier instead.
             serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree)
+                payload, in_tree, out_tree,
+                execution_devices=execution_devices)
             blob = pickle.dumps((payload, in_tree, out_tree))
             warmkey.write_atomic(self.exe_path(key), blob)
             manifest = {

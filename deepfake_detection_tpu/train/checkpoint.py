@@ -301,22 +301,6 @@ def save_sharded_checkpoint(path: str, state: Any,
         multihost_utils.sync_global_devices("dfd_sharded_save_meta")
 
 
-def _partial_restore_kwargs(ocp, partial: bool) -> Dict[str, Any]:
-    """PyTreeRestore kwargs for restoring a SUBSET of the saved tree.
-
-    Current orbax spells it ``partial_restore=True``; the legacy idiom is
-    ``transforms={}`` (restore exactly the item structure, drop extra
-    checkpoint keys).  Detected by signature so both orbax generations
-    work."""
-    if not partial:
-        return {}
-    import inspect
-    params = inspect.signature(ocp.args.PyTreeRestore.__init__).parameters
-    if "partial_restore" in params:
-        return {"partial_restore": True}
-    return {"transforms": {}}
-
-
 def _fresh_opt_sd(sd: Dict[str, Any], target_state: Any) -> Dict[str, Any]:
     """``--no-resume-opt`` substitution shared by both restore paths:
     weights/EMA from the checkpoint, optimizer state + step fresh."""
@@ -387,7 +371,7 @@ def restore_sharded_checkpoint(path: str, target_state: Any,
         # optimizer state, no wasted shard reads
         sd = dict(ckptr.restore(path, args=ocp.args.PyTreeRestore(
             item=template, restore_args=restore_args,
-            **_partial_restore_kwargs(ocp, not load_opt))))
+            partial_restore=not load_opt)))
     sd = {k: jax.tree.map(uncommit, target_sd[k], v) for k, v in sd.items()}
     for k in nones:
         sd[k] = None
@@ -448,13 +432,13 @@ def load_sharded_for_eval(path: str, variables: Dict[str, Any],
         # key presence is not enough: an EMA-less TrainState serializes
         # ema=None, which still appears in the tree metadata
         md = ckptr.metadata(path)
-        ema_md = (getattr(md, "item_metadata", md) or {}).get("ema")
+        ema_md = (md.item_metadata or {}).get("ema")
         has_ema = use_ema and isinstance(ema_md, dict) and "params" in ema_md
         item = {"ema": tmpl} if has_ema else tmpl
         restore_args = ocp.checkpoint_utils.construct_restore_args(item)
         out = ckptr.restore(path, args=ocp.args.PyTreeRestore(
             item=item, restore_args=restore_args,
-            **_partial_restore_kwargs(ocp, True)))
+            partial_restore=True))
     out = dict(out["ema"] if has_ema else out)
     if has_ema:
         _logger.info("Loaded EMA stream from %s", path)

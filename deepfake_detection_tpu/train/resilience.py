@@ -125,8 +125,8 @@ def allreduce_flags(flags: np.ndarray) -> np.ndarray:
     flags = np.asarray(flags, np.int32)
     if jax.process_count() == 1:
         return flags
-    from ..parallel._compat import coordination_client
-    client = coordination_client()
+    from jax._src import distributed  # no public KV-store accessor
+    client = distributed.global_state.client
     if client is None:  # pragma: no cover - pod runtimes init elsewhere
         raise RuntimeError(
             "multi-process run without a jax.distributed coordination "

@@ -30,6 +30,8 @@ _REF = "/root/reference/dfd/timm"
 def _load_reference_efficientnet():
     """Reference torch efficientnet module via the importlib harness."""
     torch = pytest.importorskip("torch")
+    if not os.path.isdir(_REF):
+        pytest.skip(f"reference torch sources not present at {_REF}")
     import collections.abc
     import types
     if "torch._six" not in sys.modules:

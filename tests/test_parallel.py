@@ -176,7 +176,7 @@ class TestDistributeBn:
         np.testing.assert_array_equal(np.asarray(out["mean"]), 1.0)
 
     def test_reduce_inside_shard_map(self, devices):
-        from deepfake_detection_tpu.parallel._compat import shard_map
+        from jax import shard_map
         mesh = make_mesh()
 
         def f(stats):
@@ -189,7 +189,7 @@ class TestDistributeBn:
                                    np.full((8, 1), 3.5))
 
     def test_broadcast_inside_shard_map(self, devices):
-        from deepfake_detection_tpu.parallel._compat import shard_map
+        from jax import shard_map
         mesh = make_mesh()
 
         def f(stats):

@@ -1,0 +1,114 @@
+"""The main path's Pallas kernels compile for a v5e chip — no chip needed.
+
+libtpu's compiler is installed in the CPU sandbox and compiles for a
+*described* (not attached) ``v5e:2x2`` topology, so Mosaic's verdict on
+each kernel at published widths (flagship / B4 depthwise stages, ViT-B/16
+attention) is a two-second test instead of a chip call.  Interpret mode
+cannot see what this sees: unaligned tiles, VMEM overflow, unsupported
+strided accesses.  Nothing runs, so nothing here is a measurement.
+
+Only one process may hold libtpu, so the topology is described inside a
+module-scoped fixture (never at import), every compile happens in the
+test's own process, and all such tests live in this one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepfake_detection_tpu.ops.depthwise_pallas import fused_depthwise
+from deepfake_detection_tpu.ops.flash_attention import flash_attention
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device executable is written to the persistent cache but
+    # cannot be read back without a chip — keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, *specs):
+    compiled = jax.jit(fn).lower(*specs).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "no Mosaic kernel in the compiled program"
+    return compiled
+
+
+# (H=W, C, k, stride): flagship 600² stages, then B4 380² stages — 3×3 and
+# 5×5, stride 1 and 2, lane-multiple channel counts and not
+_DW_STAGES = [
+    pytest.param(300, 256, 3, 1, id="v4-300-c256-k3s1"),
+    pytest.param(300, 192, 3, 2, id="v4-300-c192-k3s2"),
+    pytest.param(150, 288, 5, 2, id="v4-150-c288-k5s2"),
+    pytest.param(38, 1344, 5, 1, id="v4-38-c1344-k5s1"),
+    pytest.param(19, 3840, 3, 1, id="v4-19-c3840-k3s1"),
+    pytest.param(190, 144, 3, 2, id="b4-190-c144-k3s2"),
+    pytest.param(48, 336, 5, 1, id="b4-48-c336-k5s1"),
+    pytest.param(24, 960, 5, 2, id="b4-24-c960-k5s2"),
+]
+
+
+def _dw_specs(one_chip, hw, c, k):
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return (S((2, hw, hw, c), jnp.bfloat16), S((k, k, 1, c), jnp.float32),
+            S((c,), jnp.float32))
+
+
+@pytest.mark.parametrize("hw,c,k,stride", _DW_STAGES)
+def test_depthwise_eval_forward_compiles(one_chip, hw, c, k, stride):
+    """Serving form: folded-BN affine + SiLU epilogue inside the kernel."""
+    x, w, sb = _dw_specs(one_chip, hw, c, k)
+    _compile(lambda x, w, s, b: fused_depthwise(
+        x, w, s, b, stride=stride, act="silu", interpret=False),
+        x, w, sb, sb)
+
+
+@pytest.mark.parametrize("hw,c,k,stride", _DW_STAGES)
+def test_depthwise_train_grad_compiles(one_chip, hw, c, k, stride):
+    """Training form (identity epilogue): primal forward, the dx pass
+    through the reused forward kernel and the ``dwgrad`` reduction."""
+    x, w, _ = _dw_specs(one_chip, hw, c, k)
+    _compile(jax.grad(lambda x, w: fused_depthwise(
+        x, w, None, None, stride=stride, act="none",
+        interpret=False).astype(jnp.float32).sum(), argnums=(0, 1)), x, w)
+
+
+def test_depthwise_residual_forward_compiles(one_chip):
+    """Affine + act under grad: the residual-saving (``want_z``) forward."""
+    x, w, sb = _dw_specs(one_chip, 150, 288, 5)
+    _compile(jax.grad(lambda x, w, s, b: fused_depthwise(
+        x, w, s, b, stride=2, act="silu",
+        interpret=False).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3)),
+        x, w, sb, sb)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_attention_vit_b16_compiles(one_chip, grad):
+    """ViT-B/16 at 224²: 197 tokens, 12 heads of 64, bf16."""
+    qkv = jax.ShapeDtypeStruct((8, 197, 12, 64), jnp.bfloat16,
+                               sharding=one_chip)
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, interpret=False)
+    if grad:
+        _compile(jax.grad(lambda q, k, v: attn(q, k, v).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)), qkv, qkv, qkv)
+    else:
+        _compile(attn, qkv, qkv, qkv)

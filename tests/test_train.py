@@ -48,6 +48,31 @@ class TestTrainState:
         state = set_learning_rate(state, 0.01)
         assert get_learning_rate(state) == pytest.approx(0.01)
 
+    def test_lr_rewrite_does_not_retrace_the_placed_step(self, devices):
+        """The per-update LR rewrite must keep the leaf on the state's mesh:
+        an unplaced scalar is a new jit signature, i.e. a second full
+        compile of the train step after the first update."""
+        from deepfake_detection_tpu.parallel import (
+            batch_sharding, make_train_mesh, own_and_place,
+            place_train_state, replicated_sharding, train_state_shardings)
+        mesh = make_train_mesh()
+        model, state, _ = _tiny_setup()
+        tx = create_optimizer(_opt_cfg(), inject=True)
+        shardings = train_state_shardings(state, mesh)
+        state = place_train_state(state, shardings)
+        step = make_train_step(model, tx, cross_entropy, mesh=mesh,
+                               state_shardings=shardings)
+        n = len(devices)
+        x = jax.device_put(jnp.zeros((n, 32, 32, 3)), batch_sharding(mesh))
+        y = jax.device_put(jnp.zeros((n,), jnp.int32), batch_sharding(mesh))
+        rng = own_and_place(np.asarray(jax.random.PRNGKey(0)),
+                            replicated_sharding(mesh))
+        state, _ = step(state, x, y, rng)
+        state = set_learning_rate(state, 0.01)
+        state, _ = step(state, x, y, rng)
+        assert step._cache_size() == 1
+        assert get_learning_rate(state) == pytest.approx(0.01)
+
     def test_donate_false_keeps_input_tree_live(self):
         """ADVICE r4: donate=False opts out of consuming ``variables``."""
         model = create_model("mnasnet_small", num_classes=2, in_chans=3)
@@ -390,8 +415,7 @@ class TestUnifiedStepParity:
         import optax
         from jax import lax
         from jax.sharding import PartitionSpec as P
-        from deepfake_detection_tpu.parallel._compat import (
-            shard_map, shard_map_check_kwargs)
+        from jax import shard_map
         from deepfake_detection_tpu.utils.metrics import accuracy
 
         def fb(params, stats, x, y, rng):
@@ -424,7 +448,7 @@ class TestUnifiedStepParity:
         return jax.jit(shard_map(
             local_step, mesh=mesh,
             in_specs=(P(), P("data"), P("data"), P()),
-            out_specs=(P(), P()), **shard_map_check_kwargs(True)))
+            out_specs=(P(), P()), check_vma=True))
 
     @pytest.mark.slow   # tier-1 budget: the full pre-migration shard_map
     # oracle (~14 s, compiles both step programs); the unified-step

@@ -246,7 +246,7 @@ def test_store_load_reasons():
     """WarmstartMiss reasons drive the miss/fallback split — pin them."""
     with pytest.raises(WarmstartMiss) as e:
         ExecutableStore("/tmp/definitely-empty-warmstart-store").load(
-            _fields())
+            _fields(), execution_devices=jax.devices()[:1])
     assert e.value.reason == "absent"
 
 

@@ -31,8 +31,6 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.join(REPO, ".jax_cache"))
 
 
 def _log(msg: str) -> None:
@@ -79,6 +77,10 @@ def main() -> None:
 
     import jax
     import jax.numpy as jnp
+
+    from deepfake_detection_tpu.utils.compile_cache import \
+        setup_compile_cache
+    setup_compile_cache()
     import numpy as np
 
     from deepfake_detection_tpu.data import (DeepFakeClipDataset,

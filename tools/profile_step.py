@@ -22,9 +22,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"))
-
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -38,6 +35,10 @@ def main() -> None:
 
     import jax
     import jax.numpy as jnp
+
+    from deepfake_detection_tpu.utils.compile_cache import \
+        setup_compile_cache
+    setup_compile_cache()
     import numpy as np
     from types import SimpleNamespace
 
