@@ -681,6 +681,13 @@ class ServeConfig:
     # concurrent bucket compiles during warmup (0 = auto, 1 = serial)
     warm_parallel: int = 0
 
+    # --- on-demand profiler capture (obs/profiler.py) ---
+    # SIGUSR2 or `touch <profile-dir>/PROFILE` traces the engine worker's
+    # next --profile-capture device batches into
+    # <profile-dir>/profile/ondemand-<batch>; "" = no capture hook
+    profile_dir: str = ""
+    profile_capture: int = 20
+
     # ------------------------------------------------------------------
     def warm_priority_buckets(self) -> Tuple[int, ...]:
         s = str(self.warm_priority).strip()
@@ -722,6 +729,9 @@ class ServeConfig:
                              f"got {self.cache_near_radius}")
         if int(self.warm_parallel) < 0:
             raise ValueError("--warm-parallel must be >= 0 (0 = auto)")
+        if int(self.profile_capture) < 1:
+            raise ValueError(f"--profile-capture must be >= 1, got "
+                             f"{self.profile_capture}")
         bad = [b for b in self.warm_priority_buckets()
                if b not in self.buckets]
         if bad:

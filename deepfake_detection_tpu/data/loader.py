@@ -35,6 +35,7 @@ from typing import Any, Callable, Iterator, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 _logger = logging.getLogger(__name__)
 
@@ -53,20 +54,23 @@ LOADER_BACKENDS = ("thread", "shm")
 
 
 class LoaderStats:
-    """Monotonic DeviceLoader wait counters (obs/telemetry.py input gauges).
+    """Monotonic DeviceLoader counters (obs/telemetry.py input gauges).
 
-    Two ``time.monotonic`` deltas per batch around blocks the loader
-    ALREADY performs — no new syncs, no locks (single writer: the consumer
-    thread; telemetry reads are torn-proof float loads under the GIL).
+    ``time.monotonic`` deltas around what the loader ALREADY does for each
+    batch — no new syncs, no locks (single writer: the consumer thread;
+    telemetry reads are torn-proof float loads under the GIL).  Each timed
+    region is also a ``dfd.input.*`` span on the profiler's clock.
     """
 
-    __slots__ = ("batches", "host_wait_s", "stage_block_s", "augment_elided")
+    __slots__ = ("batches", "host_wait_s", "stage_block_s", "stage_s",
+                 "augment_elided")
 
     def __init__(self):
         self.batches = 0        # batches staged to device
         self.host_wait_s = 0.0  # blocked in next(host_loader) — input starved
         self.stage_block_s = 0.0  # blocked in the slab-recycle
         # block_until_ready — prologue/staging backpressure (device busy)
+        self.stage_s = 0.0      # in _stage: device_put + prologue dispatch
         self.augment_elided = 0  # host augment stages elided by
         # --augment-device (samples x stages moved into the prologue)
 
@@ -75,11 +79,15 @@ class HostLoaderStats:
     """Producer-side thread-backend counters (written by the producer
     thread; same single-writer torn-proof contract as LoaderStats)."""
 
-    __slots__ = ("batches", "fetch_s", "put_wait_s")
+    __slots__ = ("batches", "fetch_s", "load_s", "collate_s", "mixup_s",
+                 "put_wait_s")
 
     def __init__(self):
         self.batches = 0        # batches collated
-        self.fetch_s = 0.0      # decode+transform+collate time
+        self.fetch_s = 0.0      # load_s + collate_s + mixup_s
+        self.load_s = 0.0       # pool.map over the batch: decode+transform
+        self.collate_s = 0.0    # fast_collate: stack to one uint8 array
+        self.mixup_s = 0.0      # the collate_mixup call (uint8 blend)
         self.put_wait_s = 0.0   # blocked on the full prefetch queue
         # (consumer slower than the pipeline — healthy backpressure)
 
@@ -153,10 +161,11 @@ class HostLoader:
         return len(self.sampler) // self.batch_size
 
     def _load_one(self, index: int) -> Tuple[np.ndarray, int]:
-        rng = np.random.default_rng(
-            np.random.SeedSequence([self.seed, self.epoch, int(index)]))
-        img, target = self.dataset.__getitem__(int(index), rng=rng)
-        return np.asarray(img, dtype=np.uint8), target
+        with TraceAnnotation("dfd.input.sample", index=int(index)):
+            rng = np.random.default_rng(
+                np.random.SeedSequence([self.seed, self.epoch, int(index)]))
+            img, target = self.dataset.__getitem__(int(index), rng=rng)
+            return np.asarray(img, dtype=np.uint8), target
 
     def __iter__(self) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
         batches, vms = epoch_batches(self.sampler, self.batch_size,
@@ -165,21 +174,23 @@ class HostLoader:
         chaos = _loader_chaos()
         q: "queue.Queue" = queue.Queue(maxsize=self.prefetch_depth)
         stop = threading.Event()
+        stats = self.stats
 
-        def put(item) -> bool:
+        def put(item, bi: int) -> bool:
             """Bounded put that keeps observing ``stop`` (an abandoned
             consumer otherwise deadlocks the producer on the full queue)."""
             t0 = time.monotonic()
             try:
-                while not stop.is_set():
-                    try:
-                        q.put(item, timeout=0.1)
-                        return True
-                    except queue.Full:
-                        continue
-                return False
+                with TraceAnnotation("dfd.input.put_wait", batch=bi):
+                    while not stop.is_set():
+                        try:
+                            q.put(item, timeout=0.1)
+                            return True
+                        except queue.Full:
+                            continue
+                    return False
             finally:
-                self.stats.put_wait_s += time.monotonic() - t0
+                stats.put_wait_s += time.monotonic() - t0
 
         def produce():
             with ThreadPoolExecutor(self.num_workers) as pool:
@@ -196,23 +207,34 @@ class HostLoader:
                                         "batch %d",
                                         chaos.arg("stall_loader", 120.0), bi)
                         time.sleep(chaos.arg("stall_loader", 120.0))
-                    t_fetch = time.monotonic()
-                    samples = list(pool.map(self._load_one, batch_idx))
-                    images, targets = fast_collate(samples)
+                    # the three phases of a batch, each a span on the
+                    # profiler's clock and a counter; fetch_s is their sum
+                    t0 = time.monotonic()
+                    with TraceAnnotation("dfd.input.load", batch=bi):
+                        samples = list(pool.map(self._load_one, batch_idx))
+                    t1 = time.monotonic()
+                    with TraceAnnotation("dfd.input.collate", batch=bi):
+                        images, targets = fast_collate(samples)
+                    t2 = t3 = time.monotonic()
                     if self.collate_mixup is not None:
                         mrng = np.random.default_rng(np.random.SeedSequence(
                             [self.seed, self.epoch, bi, 0x77]))
-                        images, targets = self.collate_mixup(images, targets,
-                                                             mrng)
-                    self.stats.fetch_s += time.monotonic() - t_fetch
-                    self.stats.batches += 1
+                        with TraceAnnotation("dfd.input.mixup", batch=bi):
+                            images, targets = self.collate_mixup(
+                                images, targets, mrng)
+                        t3 = time.monotonic()
+                    stats.load_s += t1 - t0
+                    stats.collate_s += t2 - t1
+                    stats.mixup_s += t3 - t2
+                    stats.fetch_s += t3 - t0
+                    stats.batches += 1
                     if vms is not None:
                         item: Any = (images, targets, vms[bi])
                     else:
                         item = (images, targets)
-                    if not put(item):
+                    if not put(item, bi):
                         return
-                put(None)
+                put(None, len(batches))
 
         t = threading.Thread(target=produce, daemon=True)
         t.start()
@@ -458,18 +480,23 @@ class DeviceLoader:
                 # buffers, so batch k's prologue (the only reader of the
                 # slab) must have RUN before we pull the next host batch
                 t0 = time.monotonic()
-                jax.block_until_ready(prev_x)
+                with TraceAnnotation("dfd.input.stage_block", batch=bi - 1):
+                    jax.block_until_ready(prev_x)   # batch bi-1's prologue
                 stats.stage_block_s += time.monotonic() - t0
                 prev_x = None
             try:
                 t0 = time.monotonic()
-                item = next(it)
+                with TraceAnnotation("dfd.input.host_wait", batch=bi):
+                    item = next(it)
                 stats.host_wait_s += time.monotonic() - t0
             except StopIteration:
                 break
-            staged = self._stage(item, base_key, batch_index=bi,
-                                 indices=None if batches is None
-                                 else batches[bi])
+            t0 = time.monotonic()
+            with TraceAnnotation("dfd.input.stage", batch=bi):
+                staged = self._stage(item, base_key, batch_index=bi,
+                                     indices=None if batches is None
+                                     else batches[bi])
+            stats.stage_s += time.monotonic() - t0
             bi += 1
             stats.batches += 1
             if pending is not None:

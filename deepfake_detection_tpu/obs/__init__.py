@@ -23,6 +23,7 @@ _LAZY = {
     "peak_flops": "telemetry", "resilience_collector": "telemetry",
     "MetricsServer": "server", "start_metrics_server": "server",
     "ProfilerCapture": "profiler", "TRIGGER_FILENAME": "profiler",
+    "start_trace": "profiler",
 }
 
 __all__ = ["SCHEMA_VERSION", "EventLog", "iter_records", "read_records",

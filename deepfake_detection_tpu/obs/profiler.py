@@ -1,9 +1,16 @@
-"""On-demand profiler capture for a running training job.
+"""Profiler sessions: the one way to open one, and on-demand capture.
+
+:func:`start_trace` is the only caller of ``jax.profiler.start_trace`` in
+the package: the ``--profile N`` window (train/trainer.py), the on-demand
+capture below and the serving engine's capture hook all open their session
+through it, so every trace an operator takes is taken the same way — with
+the Python tracer off.
 
 The ``--profile N`` flag traces the first steps of epoch 0 and is gone —
 but "where did this step's milliseconds go" questions arrive mid-run, at
 step 300k, on a job nobody wants to restart.  Two triggers start a
-bounded ``jax.profiler.trace`` window on a LIVE run:
+bounded trace window on a LIVE run (a trainer, or a server's engine
+worker, which counts device batches where the trainer counts updates):
 
 * ``SIGUSR2`` — single-host ergonomics: ``kill -USR2 <pid>``.
 * ``touch <output_dir>/PROFILE`` — multi-host ergonomics: the file is
@@ -33,18 +40,37 @@ from typing import Optional
 
 _logger = logging.getLogger(__name__)
 
-__all__ = ["ProfilerCapture", "TRIGGER_FILENAME"]
+__all__ = ["ProfilerCapture", "TRIGGER_FILENAME", "start_trace"]
 
 TRIGGER_FILENAME = "PROFILE"
 
 
+def start_trace(trace_dir: str) -> None:
+    """Open a profiler session that writes under ``trace_dir``.
+
+    The Python tracer is OFF: it hooks every call of every host thread, and
+    the host threads are what feed the chip, so a capture taken with it
+    distorts the host-bound loop it looks at.  Host tracer level 1 keeps
+    the program's own ``dfd.*`` spans (``TraceAnnotation``) and the
+    runtime's launch events, on the clock the device planes use.  The
+    device's trace mode is left at the backend's default.  Stop with
+    ``jax.profiler.stop_trace()``."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+
+
 class ProfilerCapture:
-    """Bounded on-demand trace windows over a running train loop.
+    """Bounded on-demand trace windows over a running loop.
 
     The trainer calls :meth:`poll` at its drain cadence (file trigger
     check) and :meth:`on_step` once per step (window start/stop
-    management).  ``telemetry`` (optional TrainTelemetry) gets a
-    ``profile_capture`` event per completed window.
+    management); the serving engine's worker calls :meth:`on_step` once
+    per loop with its device-batch counter and :meth:`poll` while idle.
+    ``telemetry`` (optional TrainTelemetry) gets a ``profile_capture``
+    event per completed window.
     """
 
     def __init__(self, output_dir: str, num_steps: int = 20,
@@ -128,7 +154,7 @@ class ProfilerCapture:
         self._trace_dir = os.path.join(self.output_dir, "profile",
                                        f"ondemand-{step_index}")
         try:
-            jax.profiler.start_trace(self._trace_dir)
+            start_trace(self._trace_dir)
         except Exception as e:          # noqa: BLE001 — never kill the run
             _logger.warning("profiler capture failed to start: %r", e)
             return

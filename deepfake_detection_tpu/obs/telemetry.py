@@ -20,6 +20,11 @@ boundaries add two more counters (``input_*``): time blocked in
 ``block_until_ready`` (prologue/staging backpressure) — both are waits the
 loader already performed; the tracker only timestamps them.
 
+The process's compilations are counted too (``compiles_total`` and the
+seconds jax spent tracing, lowering and in the backend's compiler): one
+``jax.monitoring`` listener, installed when this module is imported so that
+whatever is built before a registry exists is still on its books.
+
 Throughput (img/s over the drain window) times the per-sample forward
 FLOP count from ``tools/flops_breakdown.py`` (× 3 for fwd+bwd, the
 standard training approximation) against the device's peak rate to give a
@@ -37,8 +42,11 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional
+
+from jax import monitoring
 
 from ..utils.metrics import LatencyHistogram
 from ..utils.prometheus import PromText
@@ -84,7 +92,60 @@ _COUNTER_CATALOG = (
     ("watchdog_near_misses_total", "Heartbeats older than 0.5x the "
      "watchdog timeout when they landed"),
     ("events_total", "Lifecycle events recorded to the JSONL log"),
+    ("compiles_total", "Programs the backend built in this process (a load "
+     "from the persistent cache counts: a program was built either way)"),
+    ("jax_trace_seconds_total", "Seconds jax spent tracing functions to "
+     "jaxprs"),
+    ("jax_lower_seconds_total", "Seconds jax spent lowering jaxprs to MLIR "
+     "modules"),
+    ("backend_compile_seconds_total", "Seconds in the backend's compiler "
+     "(or loading its output from the persistent cache)"),
 )
+
+# jax.monitoring duration events -> the counter each feeds.  Process-wide
+# totals, because a compilation belongs to the process and most of them
+# happen in set-up, before any registry is built.
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace_seconds_total",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        "jax_lower_seconds_total",
+    "/jax/core/compile/backend_compile_duration":
+        "backend_compile_seconds_total",
+}
+_compile_totals = {"compiles_total": 0.0,
+                   **{k: 0.0 for k in _COMPILE_EVENTS.values()}}
+_compile_lock = threading.Lock()        # warm-ups compile on several threads
+_compile_roots = threading.local()      # per thread: name -> [(start, secs)]
+
+
+def _on_compile_event(event: str, duration_secs: float, **_kw) -> None:
+    name = _COMPILE_EVENTS.get(event)
+    if name is None:
+        return
+    # jax traces a jit that is called inside another's trace and reports
+    # both, the inner one first (5000 events for one EfficientNet step, a
+    # third of their sum nested): an event's seconds count less those of
+    # the events of its kind that ended on this thread since it began
+    roots = _compile_roots.__dict__.setdefault(name, [])
+    start = time.monotonic() - duration_secs
+    own = duration_secs
+    while roots and roots[-1][0] >= start:
+        own -= roots.pop()[1]
+    roots.append((start, duration_secs))
+    if len(roots) > 8192:               # top-level events only pile up
+        del roots[:4096]
+    with _compile_lock:
+        _compile_totals[name] += max(own, 0.0)
+        if name == "backend_compile_seconds_total":
+            _compile_totals["compiles_total"] += 1
+
+
+monitoring.register_event_duration_secs_listener(_on_compile_event)
+
+
+def _compile_collector() -> Dict[str, Dict[str, float]]:
+    with _compile_lock:
+        return {"counters": dict(_compile_totals)}
 
 _GAUGE_CATALOG = (
     ("up", "1 while the trainer's telemetry is live"),
@@ -146,7 +207,8 @@ class TrainTelemetry:
             os.environ.get("DFD_RESTART_COUNT", 0) or 0)
         self.h_step = LatencyHistogram(_STEP_BOUNDS)
         self.h_data_wait = LatencyHistogram(_STEP_BOUNDS)
-        self._collectors: List[Callable[[], Dict[str, Dict[str, float]]]] = []
+        self._collectors: List[Callable[[], Dict[str, Dict[str, float]]]] = [
+            _compile_collector]
         # drain-window accumulators (single-writer: the train loop).  The
         # window length is the SUM of per-step wall times, not a monotonic
         # anchor: per-step wall (trainer batch_time) already covers the
@@ -375,6 +437,8 @@ def loader_collector(device_loader, name: str = "train"):
             # wait on the prologue output): the per-drain breakdown's
             # attribution of "where the augment milliseconds live"
             f"input_{name}_stage_block_seconds_total": st.stage_block_s,
+            # device_put + prologue dispatch of each batch (host time)
+            f"input_{name}_stage_seconds_total": st.stage_s,
             # samples x host-chain stages (warp/blur/mixup-blend) elided
             # by device-side augmentation
             f"input_{name}_host_augment_stages_elided_total":
@@ -393,6 +457,11 @@ def loader_collector(device_loader, name: str = "train"):
         hstats = getattr(host, "stats", None)
         if hstats is not None:           # thread backend producer stats
             c[f"input_{name}_fetch_seconds_total"] = hstats.fetch_s
+            # fetch = load (the pool's decode+transform) + collate (stack)
+            # + mixup (the uint8 blend), the producer's three phases
+            c[f"input_{name}_load_seconds_total"] = hstats.load_s
+            c[f"input_{name}_collate_seconds_total"] = hstats.collate_s
+            c[f"input_{name}_mixup_seconds_total"] = hstats.mixup_s
             c[f"input_{name}_backpressure_seconds_total"] = hstats.put_wait_s
         if hasattr(host, "ring_depth"):  # shm backend
             c[f"input_{name}_worker_respawns_total"] = host.respawn_count

@@ -167,6 +167,18 @@ def build_engine(cfg):
                  list(cfg.buckets), 1 + len(specs),
                  " (staged)" if cfg.warm_staged else "")
     engine.warmup(staged=cfg.warm_staged)
+    if cfg.profile_dir:
+        from ..obs.profiler import ProfilerCapture
+        os.makedirs(cfg.profile_dir, exist_ok=True)
+        engine.profiler = ProfilerCapture(cfg.profile_dir,
+                                          num_steps=cfg.profile_capture)
+        if not engine.profiler.install():
+            _logger.warning("not in the main thread: SIGUSR2 profiler "
+                            "trigger unavailable (the PROFILE file works)")
+        _logger.info("profiler capture: kill -USR2 %d or touch %s traces "
+                     "%d device batches", os.getpid(),
+                     os.path.join(cfg.profile_dir, "PROFILE"),
+                     cfg.profile_capture)
     if engine.chaos.active:
         _logger.warning("DFD_CHAOS active: %s", sorted(engine.chaos.points))
     cache = None

@@ -95,6 +95,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..cache.content import tree_fingerprint
 from ..chaos import chaos_from_env
@@ -292,6 +293,10 @@ class InferenceEngine:
         #: runner; start() hands it (plus the fingerprint resolver) to
         #: the batcher, and a reload commit purges the orphaned entries
         self.verdict_cache = None
+        #: on-demand trace capture (obs/profiler.py ProfilerCapture),
+        #: attached by the runner: the worker reports its device-batch
+        #: counter once per loop and polls the trigger file while idle
+        self.profiler = None
 
         self.add_model(self.default_model_id, model, variables,
                        image_size=image_size, img_num=img_num, dtype=dtype)
@@ -925,7 +930,8 @@ class InferenceEngine:
                 # the largest LIVE bucket: split it — each chunk is still
                 # a pre-compiled bucket, dispatched back-to-back (fully
                 # warmed, cap == max_batch and this is one chunk)
-                cap = self._warm_buckets(entry, chans)[-1]
+                warm = self._warm_buckets(entry, chans)
+                cap = warm[-1]
                 for i0 in range(0, len(grp), cap):
                     sub = grp[i0:i0 + cap]
                     seq = self._batch_seq
@@ -936,10 +942,13 @@ class InferenceEngine:
                         raise RuntimeError(
                             f"chaos: injected score-fn exception "
                             f"(batch {seq})")
-                    buf, bucket = self._pad_batch(
-                        entry, [r.array for r in sub], chans)
-                    out = self._run(entry, bucket, chans,
-                                    entry.variables, jax.device_put(buf))
+                    with TraceAnnotation(
+                            "dfd.serve.stage", batch=seq, rows=len(sub),
+                            bucket=pick_bucket(len(sub), warm)):
+                        buf, bucket = self._pad_batch(
+                            entry, [r.array for r in sub], chans)
+                        out = self._run(entry, bucket, chans,
+                                        entry.variables, jax.device_put(buf))
                     now = time.monotonic()
                     for r in sub:
                         r.timings["queue"] = now - r.enqueue_t
@@ -1054,12 +1063,20 @@ class InferenceEngine:
             # serving back
             raise SystemExit("chaos: serve_kill")
         self._maybe_apply_reload()
+        profiler = self.profiler
+        if profiler is not None:
+            # cheap flag check when idle; opens and closes a trace window
+            # counted in device batches
+            profiler.on_step(self._batch_seq)
         with self._pending_lock:
             pending = list(self._pending)
         if not pending:
             # device idle: block for the first request, then coalesce
             # within the deadline window
-            requests = batcher.next_batch(timeout=0.05)
+            if profiler is not None:
+                profiler.poll()         # PROFILE trigger file: 1 stat
+            with TraceAnnotation("dfd.serve.wait"):
+                requests = batcher.next_batch(timeout=0.05)
             if requests:
                 try:
                     self._stage(requests)
@@ -1081,17 +1098,18 @@ class InferenceEngine:
         requests: List[Request] = []
         out = pending[-1].out              # last sub-batch lands last
         flush_at = time.monotonic() + batcher.deadline_s
-        while len(requests) < batcher.max_batch and gen == self._gen:
-            if self._out_ready(out) and time.monotonic() >= flush_at:
-                break
-            r = batcher.take(timeout=0.001)
-            if r is not None:
+        with TraceAnnotation("dfd.serve.gather"):
+            while len(requests) < batcher.max_batch and gen == self._gen:
+                if self._out_ready(out) and time.monotonic() >= flush_at:
+                    break
+                r = batcher.take(timeout=0.001)
+                if r is not None:
+                    requests.append(r)
+            while len(requests) < batcher.max_batch and gen == self._gen:
+                r = batcher.take(timeout=0.0)
+                if r is None:
+                    break
                 requests.append(r)
-        while len(requests) < batcher.max_batch and gen == self._gen:
-            r = batcher.take(timeout=0.0)
-            if r is None:
-                break
-            requests.append(r)
         if gen != self._gen:
             # a recovery fired while we gathered (a REAL device hang parks
             # the worker right here, endlessly re-polling is_ready): the
@@ -1111,7 +1129,8 @@ class InferenceEngine:
         err: Optional[Exception] = None
         for st in pending:
             try:
-                self._complete(st, gen)
+                with TraceAnnotation("dfd.serve.complete", batch=st.seq):
+                    self._complete(st, gen)
             except Exception as e:                 # noqa: BLE001
                 if gen != self._gen:
                     return             # recovery already owns the ledger
@@ -1178,6 +1197,8 @@ class InferenceEngine:
             pending, self._pending = self._pending, []
         for st in pending:
             self._fail(st.requests, RuntimeError("server shutting down"))
+        if self.profiler is not None:
+            self.profiler.close()      # ends an open capture, frees SIGUSR2
 
     # ------------------------------------------------------------------
     # watchdog recovery (serving/resilience.py runs the monitor thread)

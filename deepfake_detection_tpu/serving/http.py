@@ -52,6 +52,7 @@ from typing import Optional, Tuple
 from urllib.parse import parse_qs
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 from PIL import Image
 
 from ..cache import clip_phash, content_hash
@@ -309,8 +310,9 @@ class _Handler(BaseHTTPRequestHandler):
                                max(1, int(round(e.retry_after_s)))})
             return
         ctype_full = self.headers.get("Content-Type") or ""
-        frames, json_model = (self._decode_frames(body, ctype_full)
-                              if body else (None, None))
+        with TraceAnnotation("dfd.serve.decode", bytes=len(body)):
+            frames, json_model = (self._decode_frames(body, ctype_full)
+                                  if body else (None, None))
         if frames is None:
             self._respond_json(400, {"error": "undecodable image payload"})
             return

@@ -12,6 +12,7 @@ metric allreduce (it lives inside the compiled step).
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import signal
@@ -22,7 +23,9 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, annotate_function
 
+from ..obs.profiler import start_trace
 from ..utils.metrics import AverageMeter, auc
 from .resilience import Preempted, RewindRequested
 from .state import TrainState, get_learning_rate, set_learning_rate
@@ -82,6 +85,12 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
     share) — so enabling it adds NO device syncs; its optional
     ``.profiler`` (obs/profiler.py) gets a per-step window check and a
     per-drain trigger-file poll for on-demand trace capture.
+
+    The loop writes three spans into any open profiler session, on the
+    device trace's clock: ``dfd.train.step`` (a step annotation carrying
+    ``step_num``, around the dispatch), ``dfd.train.drain`` and
+    ``dfd.train.recovery_save``.  All its timers read ``time.monotonic``,
+    the clock of the loader's counters they are divided by.
     """
     if cfg.mixup > 0 and hasattr(loader, "mixup_enabled"):
         if cfg.mixup_off_epoch and epoch >= cfg.mixup_off_epoch:
@@ -90,7 +99,7 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
     batch_time_m, data_time_m = AverageMeter(), AverageMeter()
     losses_m, prec1_m = AverageMeter(), AverageMeter()
 
-    end = time.time()
+    end = time.monotonic()
     num_batches = len(loader)
     last_idx = num_batches - 1
     num_updates = epoch * num_batches + start_batch
@@ -126,6 +135,7 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
     drain_bad_acc = 0
     profiler = getattr(telemetry, "profiler", None)
 
+    @functools.partial(annotate_function, name="dfd.train.drain")
     def _drain() -> None:
         nonlocal nonfinite_total, drain_wait_acc, drain_bad_acc
         t_drain = time.monotonic()
@@ -160,10 +170,10 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
     for batch_idx, batch in enumerate(loader, start=start_batch):
         x, y = batch[0], batch[1]
         last_batch = batch_idx == last_idx
-        data_time_m.update(time.time() - end)
+        data_time_m.update(time.monotonic() - end)
 
         if profile_n and batch_idx == profile_start and not profiling:
-            jax.profiler.start_trace(os.path.join(output_dir, "profile"))
+            start_trace(os.path.join(output_dir, "profile"))
             profiling = True
 
         if chaos is not None and chaos.fires("nanbatch", num_updates):
@@ -184,7 +194,8 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
             step_exec = _compile_aligned(train_step, "train_step",
                                          state, x, y, step_rng)
         first_step = False
-        state, metrics = (step_exec or train_step)(state, x, y, step_rng)
+        with StepTraceAnnotation("dfd.train.step", step_num=num_updates):
+            state, metrics = (step_exec or train_step)(state, x, y, step_rng)
 
         if profiling and (batch_idx + 1 >= profile_start + profile_n
                           or last_batch):
@@ -201,7 +212,7 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
 
         if last_batch or batch_idx % cfg.log_interval == 0:
             _drain()
-        batch_time_m.update(time.time() - end)
+        batch_time_m.update(time.monotonic() - end)
         if telemetry is not None:
             # host floats the loop already holds — no device access
             telemetry.on_step(bs, data_time_m.val, batch_time_m.val)
@@ -311,7 +322,7 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
                 if telemetry is not None:
                     telemetry.inc("recovery_snapshots_total")
                 raise Preempted(epoch, batch_idx, resilience.stop_signum)
-        end = time.time()
+        end = time.monotonic()
 
     return state, OrderedDict([("loss", losses_m.avg),
                                ("prec1", prec1_m.avg),
@@ -319,6 +330,7 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
                                ("nonfinite", nonfinite_total)])
 
 
+@functools.partial(annotate_function, name="dfd.train.recovery_save")
 def _save_recovery(saver, state, meta, epoch: int, batch_idx: int,
                    num_updates: int, sync: bool = False) -> None:
     """In-epoch recovery snapshot with exact loop position in the meta.

@@ -810,6 +810,45 @@ class TestOverheadGuard:
         assert snap["counters"]["steps_total"] == 5
         assert snap["counters"]["drains_total"] >= 2
 
+    def test_spans_and_phase_counters_add_no_sync(self, devices, monkeypatch,
+                                                  tmp_path):
+        """The dfd.* spans cost no device sync, with a profiler session
+        open or not; the DeviceLoader still blocks exactly once per staged
+        batch after the first (its slab-recycle wait, nothing new); and
+        every per-phase / compile counter is a host number."""
+        from deepfake_detection_tpu.obs import (TrainTelemetry,
+                                                loader_collector, start_trace)
+        calls = {"n": 0}
+        real = jax.block_until_ready
+
+        def counting(x):
+            calls["n"] += 1
+            return real(x)
+
+        monkeypatch.setattr(jax, "block_until_ready", counting)
+        self._run_epoch(None, devices)
+        baseline = calls["n"]
+        start_trace(str(tmp_path))
+        try:
+            calls["n"] = 0
+            self._run_epoch(TrainTelemetry(), devices)
+            traced = calls["n"]
+        finally:
+            jax.profiler.stop_trace()
+        assert traced == baseline, \
+            "an open profiler session changed the loop's sync count"
+
+        loader = _mixup_loader()
+        calls["n"] = 0
+        n = sum(1 for _ in loader)
+        assert calls["n"] == n - 1, \
+            "the loader's spans/counters added a block_until_ready"
+        t = TrainTelemetry()
+        t.register_collector(loader_collector(loader))
+        for k, v in t.snapshot()["counters"].items():
+            assert type(v) in (int, float), (k, type(v))
+        loader.close()
+
 
 # ---------------------------------------------------------------------------
 # Watchdog dump file + near-miss counter (satellite)
@@ -1009,8 +1048,14 @@ class TestObsReport:
                             data_wait_frac=0.2, device_wait_frac=0.5,
                             host_frac=0.3, loss=1.0 / u, prec1=50.0,
                             lr=0.1, mfu=0.41,
-                            counters={"steps_total": u,
-                                      "recovery_snapshots_total": 1})
+                            counters={
+                                "steps_total": u,
+                                "recovery_snapshots_total": 1,
+                                "input_train_batches_total": u,
+                                "input_train_fetch_seconds_total": 9.0,
+                                "input_train_load_seconds_total": 1.5,
+                                "input_train_collate_seconds_total": 2.5,
+                                "input_train_mixup_seconds_total": 5.0})
             log.event("rewind", reason="3 consecutive bad steps")
             log.event("epoch_end", epoch=0, train={"loss": 0.33})
         out = subprocess.run(
@@ -1022,6 +1067,8 @@ class TestObsReport:
         assert "| 0 |" in out.stdout          # the epoch row
         assert "rewind" in out.stdout         # resilience event surfaced
         assert "recovery_snapshots_total = 1" in out.stdout
+        assert "host fetch 9.0s = load 1.5s + collate 2.5s + mixup 5.0s" \
+            in out.stdout
         # the mesh line (ISSUE 12 satellite): topology from run_start
         assert "mesh: batch=8 × model=1 (8 devices)" in out.stdout
         tail = subprocess.run(
@@ -1216,3 +1263,156 @@ class TestLoaderStats:
         assert out["counters"]["input_train_batches_total"] == n
         assert out["counters"]["input_train_fetch_seconds_total"] > 0
         loader.close()
+
+
+# ---------------------------------------------------------------------------
+# Host spans on the profiler's clock + per-phase / compile counters
+# ---------------------------------------------------------------------------
+
+class _Forwarding:
+    """An attribute-forwarding proxy around the host loader, as the
+    benchmark's HostTap is: the program must find ``stats`` through it."""
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def __len__(self):
+        return len(self._inner)
+
+    def __iter__(self):
+        return iter(self._inner)
+
+
+def _mixup_loader(wrap=False):
+    from deepfake_detection_tpu.data import FastCollateMixup, SyntheticDataset
+    from deepfake_detection_tpu.data.loader import create_loader
+    loader = create_loader(
+        SyntheticDataset(16, (32, 32, 3), 2, 0), (3, 32, 32), batch_size=4,
+        is_training=True, num_workers=2, dtype=jnp.float32,
+        collate_mixup=FastCollateMixup(0.1, 0.1, 2))
+    if wrap:
+        loader.loader = _Forwarding(loader.loader)
+    return loader
+
+
+def _trace_spans(trace_dir):
+    """[(name, {stat: value})] of every dfd.* span in the trace's host
+    plane."""
+    from jax.profiler import ProfileData
+    files = sorted(trace_dir.rglob("*.xplane.pb"))
+    assert files, "the profiler wrote no .xplane.pb"
+    spans = []
+    for plane in ProfileData.from_file(str(files[-1])).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("dfd."):
+                        spans.append((ev.name, dict(ev.stats)))
+    return spans
+
+
+class TestSpans:
+    def test_loader_and_train_loop_spans_reach_the_trace(self, devices,
+                                                         tmp_path):
+        """One traced epoch of the thread-backend loader under
+        train_one_epoch: every dfd.input.* / dfd.train.* span is in the
+        profiler's own file with its identifier, and the producer's and the
+        consumer's spans of one batch carry the same ``batch``."""
+        from deepfake_detection_tpu.losses import soft_target_cross_entropy
+        from deepfake_detection_tpu.models import create_model, init_model
+        from deepfake_detection_tpu.obs import start_trace
+        from deepfake_detection_tpu.optim import create_optimizer
+        from deepfake_detection_tpu.train import (create_train_state,
+                                                  make_train_step,
+                                                  train_one_epoch)
+        model = create_model("mnasnet_small", num_classes=2, in_chans=3)
+        variables = init_model(model, jax.random.PRNGKey(0), (2, 32, 32, 3),
+                               training=True)
+        tx = create_optimizer(SimpleNamespace(
+            opt="sgd", opt_eps=1e-8, momentum=0.9, weight_decay=0.0,
+            lr=1e-3), inject=True)
+        state = create_train_state(variables, tx)
+        step = make_train_step(model, tx, soft_target_cross_entropy,
+                               mesh=None, bn_mode="global")
+        loader = _mixup_loader()
+        cfg = _loop_cfg(recovery_interval=2)
+        state, _ = train_one_epoch(0, step, state, loader, cfg,
+                                   jax.random.PRNGKey(1))      # compiles
+        loader.set_epoch(1)
+        start_trace(str(tmp_path))
+        try:
+            train_one_epoch(1, step, state, loader, cfg,
+                            jax.random.PRNGKey(1))
+        finally:
+            jax.profiler.stop_trace()
+        loader.close()
+        spans = _trace_spans(tmp_path)
+        by_name = {}
+        for name, stats in spans:
+            by_name.setdefault(name, []).append(stats)
+        n = len(loader)
+        for name in ("load", "collate", "mixup", "put_wait", "host_wait",
+                     "stage", "stage_block"):
+            got = by_name.get(f"dfd.input.{name}")
+            assert got, f"no dfd.input.{name} span in the trace"
+            assert all("batch" in st for st in got), (name, got)
+        assert all("index" in st for st in by_name["dfd.input.sample"])
+        assert len(by_name["dfd.input.sample"]) == n * 4
+        # epoch 1 of a 4-batch loader: updates 4..7
+        assert sorted(st["step_num"] for st in by_name["dfd.train.step"]) \
+            == list(range(n, 2 * n))
+        assert by_name.get("dfd.train.drain")
+        assert by_name.get("dfd.train.recovery_save")
+        produced = {st["batch"] for st in by_name["dfd.input.load"]}
+        staged = {st["batch"] for st in by_name["dfd.input.stage"]}
+        assert produced == staged == set(range(n))
+
+    @pytest.mark.parametrize("wrap", [False, True],
+                             ids=["plain", "proxied"])
+    def test_phase_counters_sum_to_fetch(self, devices, wrap):
+        """fetch = load + collate + mixup, and loader_collector exposes the
+        four new counters — also through an attribute-forwarding proxy
+        around the host loader (the benchmark's HostTap)."""
+        from deepfake_detection_tpu.obs import loader_collector
+        loader = _mixup_loader(wrap)
+        n = sum(1 for _ in loader)
+        st = loader.loader.stats
+        assert st.batches == n
+        assert st.load_s > 0 and st.collate_s > 0 and st.mixup_s > 0
+        assert st.load_s + st.collate_s + st.mixup_s == \
+            pytest.approx(st.fetch_s, rel=1e-9)
+        c = loader_collector(loader)()["counters"]
+        assert c["input_train_load_seconds_total"] == st.load_s
+        assert c["input_train_collate_seconds_total"] == st.collate_s
+        assert c["input_train_mixup_seconds_total"] == st.mixup_s
+        assert c["input_train_stage_seconds_total"] == loader.stats.stage_s > 0
+        assert c["input_train_fetch_seconds_total"] == st.fetch_s
+        loader.close()
+
+    def test_compiles_total_counts_each_program_once(self, devices):
+        from deepfake_detection_tpu.obs import TrainTelemetry
+        t = TrainTelemetry()
+        names = ("compiles_total", "jax_trace_seconds_total",
+                 "jax_lower_seconds_total", "backend_compile_seconds_total")
+
+        def read():
+            c = t.snapshot()["counters"]
+            return [c[k] for k in names]
+
+        f = jax.jit(lambda x: x * 3.0 + 1.0)
+        a, b = np.ones((5,), np.float32), np.ones((7,), np.float32)
+        c0 = read()
+        f(a).block_until_ready()
+        c1 = read()
+        f(a).block_until_ready()                # same shape: nothing built
+        c2 = read()
+        f(b).block_until_ready()                # a new shape: one program
+        c3 = read()
+        assert c1[0] - c0[0] == 1 and c2 == c1 and c3[0] - c2[0] == 1
+        assert all(x1 > x0 for x0, x1 in zip(c0[1:], c1[1:]))
+        # a second registry reads the same process-wide books
+        assert [TrainTelemetry().snapshot()["counters"][k]
+                for k in names] == c3

@@ -640,3 +640,78 @@ def test_serve_config_validation():
         ServeConfig(buckets=(0, 4))
     with pytest.raises(ValueError):
         ServeConfig(buckets=(1, 64), max_queue=32)   # queue < max bucket
+
+
+# ---------------------------------------------------------------------------
+# on-demand profiler capture through the engine worker (obs/profiler.py)
+# ---------------------------------------------------------------------------
+
+def test_profile_trigger_yields_one_bounded_capture_with_batch_spans(
+        tmp_path):
+    """A PROFILE file in the capture directory traces the worker's next
+    device batches once: the trace holds dfd.serve.stage and
+    dfd.serve.complete of the same ``batch``; with no trigger the idle
+    path opens no session."""
+    from jax.profiler import ProfileData
+
+    from deepfake_detection_tpu.obs import ProfilerCapture
+
+    class Counting(ProfilerCapture):
+        polls = 0
+
+        def poll(self):
+            self.polls += 1
+            super().poll()
+
+    def wait_for(cond, what, seconds=20.0):
+        deadline = time.monotonic() + seconds
+        while not cond():
+            assert time.monotonic() < deadline, f"timed out: {what}"
+            time.sleep(0.01)
+
+    model = create_model(_MODEL, num_classes=2, in_chans=3)
+    variables = _perturbed_variables(model, _SIZE, 3, seed=1)
+    engine = InferenceEngine(model, variables, image_size=_SIZE, img_num=1,
+                             buckets=(1,))
+    cap = engine.profiler = Counting(str(tmp_path), num_steps=2)
+    batcher = MicroBatcher(max_batch=1, deadline_ms=1.0, max_queue=8,
+                           metrics=engine.metrics)
+    engine.start(batcher)
+    try:
+        payload = _payloads(1, seed=5)[0]
+        batcher.submit(payload, timeout_s=5).result(timeout=5)
+        seen = cap.polls
+        wait_for(lambda: cap.polls >= seen + 2, "two idle loops")
+        assert not cap.active and cap.captures_total == 0
+        assert not (tmp_path / "profile").exists()
+
+        (tmp_path / "PROFILE").touch()
+        wait_for(lambda: cap.active, "the capture to start")
+        assert not (tmp_path / "PROFILE").exists(), "trigger not consumed"
+        for _ in range(3):      # the window is two device batches
+            batcher.submit(payload, timeout_s=5).result(timeout=5)
+        wait_for(lambda: cap.captures_total == 1, "the capture to stop")
+        assert not cap.active
+    finally:
+        engine.stop()
+        batcher.close()
+    dirs = list((tmp_path / "profile").iterdir())
+    assert len(dirs) == 1 and dirs[0].name.startswith("ondemand-")
+    files = sorted(dirs[0].rglob("*.xplane.pb"))
+    assert files, "the capture wrote no .xplane.pb"
+    stats = {"dfd.serve.stage": [], "dfd.serve.complete": []}
+    names = set()
+    for plane in ProfileData.from_file(str(files[-1])).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    names.add(ev.name)
+                    if ev.name in stats:
+                        stats[ev.name].append(dict(ev.stats))
+    first = int(dirs[0].name.split("-")[1])
+    staged = {st["batch"] for st in stats["dfd.serve.stage"]}
+    assert staged == {first, first + 1}, "the window is not two batches"
+    assert first in {st["batch"] for st in stats["dfd.serve.complete"]}
+    assert all(st["rows"] == 1 and st["bucket"] == 1
+               for st in stats["dfd.serve.stage"])
+    assert {"dfd.serve.wait", "dfd.serve.gather"} <= names

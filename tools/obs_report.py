@@ -197,6 +197,11 @@ def summarize(paths: list) -> None:
                     f"host-wait {hw:.1f}s, prologue stage-block {sb:.1f}s")
             if fetch is not None:
                 line += f", host fetch {fetch:.1f}s"
+                parts = [(k, last.get(f"input_train_{k}_seconds_total"))
+                         for k in ("load", "collate", "mixup")]
+                if all(v is not None for _, v in parts):
+                    line += " = " + " + ".join(f"{k} {v:.1f}s"
+                                               for k, v in parts)
             print(line + ")")
     resil = [e for e in events if e.get("event") in
              ("rewind", "preempted", "resume")]
