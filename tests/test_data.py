@@ -10,6 +10,7 @@ from deepfake_detection_tpu.data import (DeepFakeClipDataset,
                                          FastCollateMixup, SyntheticDataset,
                                          create_deepfake_loader_v3,
                                          fast_collate, resolve_data_config)
+from deepfake_detection_tpu.data import mixup as mixup_mod
 from deepfake_detection_tpu.data.auto_augment import (
     augment_and_mix_transform, auto_augment_transform, rand_augment_transform)
 from deepfake_detection_tpu.data.random_erasing import random_erasing
@@ -551,6 +552,111 @@ class TestMixup:
         assert soft.shape == (2, 2)
         np.testing.assert_allclose(soft.sum(-1), 1.0, atol=1e-5)
         assert out.dtype == np.uint8
+
+    # -- the tiled blend (PR 25) against the whole-batch expression -------
+
+    _SHAPES = [(2, 4, 4, 3), (3, 37, 5, 12), (5, 16, 16, 12),
+               (4, 5, 300, 128),         # one row > a tile, and many rows
+               (3, 600, 600, 12)]        # the flagship's batch
+    _LAMS = ["beta_near_0", "beta_near_1", 0.5, 0.25]   # .5 and .25: ties
+
+    @staticmethod
+    def _oracle(images, lam):
+        """The blend as it was before it was tiled, kept as the oracle."""
+        mixed = images.astype(np.float32) * lam + \
+            images[::-1].astype(np.float32) * (1.0 - lam)
+        np.round(mixed, out=mixed)
+        return mixed.astype(np.uint8)
+
+    @staticmethod
+    def _lam(which):
+        if not isinstance(which, str):
+            return which
+        draws = _rng(25).beta(0.1, 0.1, size=256)    # the flagship's alpha
+        if which == "beta_near_0":
+            return float(draws[draws < 0.05].max())
+        return float(draws[draws > 0.95].min())
+
+    @staticmethod
+    def _batch(shape, seed=0):
+        return _rng(seed).integers(0, 256, shape, dtype=np.uint8)
+
+    @pytest.mark.parametrize("lam", _LAMS)
+    @pytest.mark.parametrize("shape", _SHAPES, ids=str)
+    def test_tiled_blend_bit_identical(self, shape, lam):
+        imgs, lam = self._batch(shape), self._lam(lam)
+        assert 0.0 < lam < 1.0
+        want = self._oracle(imgs, lam)
+        if lam in (0.5, 0.25):           # the case really holds ties
+            exact = imgs.astype(np.float64) * lam + \
+                imgs[::-1].astype(np.float64) * (1.0 - lam)
+            assert (exact % 1.0 == 0.5).any()
+        got = mixup_mod._blend_tiled(imgs, lam)
+        assert got.dtype == np.uint8 and got.shape == imgs.shape
+        np.testing.assert_array_equal(got, want)
+
+    @pytest.mark.parametrize("lam", _LAMS)
+    @pytest.mark.parametrize("shape", _SHAPES[:3], ids=str)
+    def test_tiled_blend_many_tiles(self, shape, lam, monkeypatch):
+        """A tile far smaller than the batch: several tiles, the last one
+        partial where H is no multiple of the tile's rows (37 = 7 x 5 + 2)."""
+        monkeypatch.setattr(mixup_mod, "_TILE_ELEMS", 1024)
+        imgs, lam = self._batch(shape, seed=1), self._lam(lam)
+        np.testing.assert_array_equal(mixup_mod._blend_tiled(imgs, lam),
+                                      self._oracle(imgs, lam))
+
+    @pytest.mark.parametrize("view", ["batch_strided", "channel_slice",
+                                      "row_strided", "transposed"])
+    def test_tiled_blend_strided_input(self, view):
+        """The shm ring hands in a view of a slab; any strides must do."""
+        slab = self._batch((6, 40, 18, 12), seed=2)
+        imgs = {"batch_strided": slab[::2],
+                "channel_slice": slab[:3, :, :, 2:11],
+                "row_strided": slab[:3, ::3],
+                "transposed": slab[:3].transpose(0, 2, 1, 3)}[view]
+        assert not imgs.flags["C_CONTIGUOUS"]
+        before = slab.copy()
+        lam = self._lam("beta_near_0")
+        got = mixup_mod._blend_tiled(imgs, lam)
+        np.testing.assert_array_equal(
+            got, self._oracle(np.ascontiguousarray(imgs), lam))
+        assert got.flags["C_CONTIGUOUS"]
+        assert not np.shares_memory(got, slab)
+        np.testing.assert_array_equal(slab, before)      # input only read
+
+    @pytest.mark.parametrize("shape", [(1, 8, 8, 3), (3, 1, 7, 12),
+                                       (0, 8, 8, 3), (2, 0, 8, 3)], ids=str)
+    def test_tiled_blend_degenerate_shapes(self, shape):
+        imgs = self._batch(shape, seed=3)
+        np.testing.assert_array_equal(mixup_mod._blend_tiled(imgs, 0.3),
+                                      self._oracle(imgs, 0.3))
+
+    @pytest.mark.parametrize("seed", [3, 4, 5])
+    def test_collate_mixup_is_the_oracle_on_its_own_draw(self, seed):
+        """Through __call__: the draw, the soft targets and the blend, with
+        a fresh output that shares nothing with the (unchanged) input."""
+        m = FastCollateMixup(mixup_alpha=0.1, label_smoothing=0.1,
+                             num_classes=2)
+        imgs, tgts = self._batch((5, 16, 16, 12), seed), np.arange(5) % 2
+        kept = imgs.copy()
+        lam = float(_rng(seed).beta(0.1, 0.1))
+        out, soft = m(imgs, tgts, _rng(seed))
+        np.testing.assert_array_equal(out, self._oracle(kept, lam))
+        np.testing.assert_array_equal(
+            soft, mixup_mod.mixup_target_np(tgts, 2, lam, 0.1))
+        np.testing.assert_array_equal(imgs, kept)
+        assert out is not imgs and not np.shares_memory(out, imgs)
+        assert vars(m) == vars(FastCollateMixup(0.1, 0.1, 2))   # stateless
+
+    @pytest.mark.parametrize("how", ["blend_off", "lam_one"])
+    def test_collate_mixup_early_returns_the_input_itself(self, how):
+        m = FastCollateMixup(mixup_alpha=0.1, label_smoothing=0.1,
+                             num_classes=2, blend=(how != "blend_off"))
+        m.mixup_enabled = how != "lam_one"           # lam stays 1.0
+        imgs = self._batch((4, 8, 8, 3))
+        out, soft = m(imgs, np.arange(4) % 2, _rng(6))
+        assert out is imgs
+        assert soft.shape == (4, 2)
 
 
 # ---------------------------------------------------------------------------
