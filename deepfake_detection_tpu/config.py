@@ -163,6 +163,9 @@ class TrainConfig:
     data: str = ""                       # root dir(s), ':'-separated for multi-dir
     eval_data: str = ""                  # separate eval root(s); default: split from train
     dataset: str = "deepfake_v3"         # deepfake_v3 | folder | synthetic
+    # | synthetic-tokens | tokens (rows of int32 ids from --data FILE): the
+    # sequence models' datasets (data/tokens.py)
+    seq_len: int = 0                     # tokens a row, for the token datasets
     train_split: float = 0.95            # seeded train/val split fraction
     split_seed: int = 42
     label_balance: bool = False          # fake-bucket balancing (dataset.py:460-491)
@@ -217,6 +220,7 @@ class TrainConfig:
     # --- optimization ---
     opt: str = "rmsproptf"
     opt_eps: float = 1e-8
+    opt_beta2: Optional[float] = None    # adam/adamw b2; None: optax's 0.999
     momentum: float = 0.9
     weight_decay: float = 1e-5
     lr: Optional[float] = None           # if None: batch*world*basic_lr (train.py:814)

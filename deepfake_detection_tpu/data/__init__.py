@@ -24,6 +24,7 @@ from .packed import (PackedCacheStale, PackedDataset, PackedShardCorrupt,
 from .samplers import (OrderedShardedSampler, ShardedTrainSampler,
                        epoch_batches)
 from .shm_ring import ShmRing, ShmRingLoader
+from .tokens import SyntheticTokenDataset, TokenFileDataset
 from .transforms_factory import (create_transform, transforms_deepfake_eval_v3,
                                  transforms_deepfake_train_passthrough,
                                  transforms_deepfake_train_v3,
@@ -34,7 +35,7 @@ from .transforms_factory import (create_transform, transforms_deepfake_eval_v3,
 _LAZY = {
     "DeviceLoader": "loader", "HostLoader": "loader",
     "create_deepfake_loader_v3": "loader", "create_loader": "loader",
-    "fast_collate": "loader",
+    "fast_collate": "loader", "create_token_loader": "loader",
     "FastCollateMixup": "mixup", "mixup_batch": "mixup",
     "RandomErasing": "random_erasing", "random_erasing": "random_erasing",
 }

@@ -48,7 +48,8 @@ from .transforms_factory import (transforms_deepfake_eval_v3,
                                  transforms_deepfake_train_v3)
 
 __all__ = ["fast_collate", "HostLoader", "DeviceLoader", "LoaderStats",
-           "HostLoaderStats", "create_loader", "create_deepfake_loader_v3"]
+           "HostLoaderStats", "create_loader", "create_deepfake_loader_v3",
+           "create_token_loader"]
 
 LOADER_BACKENDS = ("thread", "shm")
 
@@ -103,16 +104,20 @@ def _loader_chaos():
     return chaos_from_env()
 
 
-def fast_collate(samples: Sequence[Tuple[np.ndarray, int]]
-                 ) -> Tuple[np.ndarray, np.ndarray]:
-    """Stack uint8 NHWC samples + int labels (reference :12-46).
+def fast_collate(samples: Sequence[Tuple[np.ndarray, int]],
+                 dtype=np.uint8) -> Tuple[np.ndarray, np.ndarray]:
+    """Stack uint8 NHWC samples + int labels (reference :12-46).  Token
+    rows (``dtype`` int32, data/tokens.py) stack the same way, their
+    per-position targets into one int32 array.
 
     AugMix multi-view samples — ``(S, H, W, C)`` per sample — collate
     split-major: ``[view0 of all samples, view1 of all samples, ...]`` with
     labels tiled, the layout ``jsd_cross_entropy`` splits back apart
     (reference fast_collate tuple branch, loader.py:15-27).
     """
-    images = np.stack([s[0] for s in samples]).astype(np.uint8, copy=False)
+    images = np.stack([s[0] for s in samples]).astype(dtype, copy=False)
+    if isinstance(samples[0][1], np.ndarray):
+        return images, np.stack([s[1] for s in samples])
     targets = np.asarray([s[1] for s in samples], dtype=np.int64)
     if images.ndim == 5:                       # (B, S, H, W, C)
         b, s = images.shape[:2]
@@ -144,6 +149,9 @@ class HostLoader:
         self.valid_mask = valid_mask
         self.epoch = 0
         self.stats = HostLoaderStats()
+        # what a sample is stacked as: uint8 images, or a token dataset's
+        # int32 ids (data/tokens.py)
+        self.sample_dtype = getattr(dataset, "sample_dtype", np.uint8)
         # mid-epoch resume: skip producing batches < start_batch while
         # keeping their ABSOLUTE indices for every per-batch RNG, so a
         # fast-forwarded epoch's remaining batches are bit-identical to an
@@ -165,7 +173,7 @@ class HostLoader:
             rng = np.random.default_rng(
                 np.random.SeedSequence([self.seed, self.epoch, int(index)]))
             img, target = self.dataset.__getitem__(int(index), rng=rng)
-            return np.asarray(img, dtype=np.uint8), target
+            return np.asarray(img, dtype=self.sample_dtype), target
 
     def __iter__(self) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
         batches, vms = epoch_batches(self.sampler, self.batch_size,
@@ -214,7 +222,8 @@ class HostLoader:
                         samples = list(pool.map(self._load_one, batch_idx))
                     t1 = time.monotonic()
                     with TraceAnnotation("dfd.input.collate", batch=bi):
-                        images, targets = fast_collate(samples)
+                        images, targets = fast_collate(
+                            samples, self.sample_dtype)
                     t2 = t3 = time.monotonic()
                     if self.collate_mixup is not None:
                         mrng = np.random.default_rng(np.random.SeedSequence(
@@ -248,6 +257,10 @@ class HostLoader:
             stop.set()
 
 
+def _identity_prologue(images, key):
+    return images
+
+
 class DeviceLoader:
     """Device-side prologue with async double buffering.
 
@@ -267,7 +280,8 @@ class DeviceLoader:
                  img_num: int = 4, seed: int = 0,
                  sharding: Optional[Any] = None,
                  color_jitter=None, flicker: float = 0.0,
-                 stem_s2d: bool = False, device_augment: Optional[Any] = None):
+                 stem_s2d: bool = False, device_augment: Optional[Any] = None,
+                 identity_prologue: bool = False):
         self.loader = loader
         self.img_num = img_num
         self.stem_s2d = stem_s2d
@@ -290,6 +304,11 @@ class DeviceLoader:
             max_count=re_count, num_splits=re_num_splits,
             img_num=img_num) if re_prob > 0.0 else None
         self._step = 0
+        if identity_prologue:
+            # token ids (create_token_loader) have nothing to cast, normalize
+            # or erase: the staged array is the step's input
+            self._prologue = jax.jit(_identity_prologue)
+            return
 
         mean_j = jnp.asarray(self._mean)
         std_j = jnp.asarray(self._std)
@@ -571,6 +590,22 @@ def _build_loader(dataset, transform, batch_size: int, is_training: bool,
         raise ValueError(f"loader_backend must be one of {LOADER_BACKENDS}, "
                          f"got {loader_backend!r}")
     return DeviceLoader(host, seed=seed, **device_kwargs)
+
+
+def create_token_loader(
+        dataset, batch_size: int, is_training: bool = False,
+        num_workers: int = 1, distributed: bool = False,
+        num_shards: int = 1, shard_index: int = 0, seed: int = 42,
+        prefetch_depth: int = 2, sharding: Optional[Any] = None,
+        valid_mask: Optional[bool] = None) -> DeviceLoader:
+    """Loader of a token dataset (data/tokens.py): the sharded samplers, the
+    thread-backed :class:`HostLoader` and the :class:`DeviceLoader` staging
+    as every other loader has them (the same spans and counters), int32 on
+    the wire and the identity for a prologue."""
+    return _build_loader(
+        dataset, None, batch_size, is_training, 0, None, distributed,
+        num_shards, shard_index, seed, num_workers, prefetch_depth,
+        valid_mask, dict(sharding=sharding, identity_prologue=True))
 
 
 def create_loader(

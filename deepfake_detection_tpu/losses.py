@@ -21,7 +21,7 @@ import jax.numpy as jnp
 __all__ = [
     "cross_entropy", "label_smoothing_cross_entropy",
     "soft_target_cross_entropy", "jsd_cross_entropy", "create_loss_fn",
-    "one_hot",
+    "one_hot", "next_token_loss",
 ]
 
 
@@ -86,6 +86,44 @@ def jsd_cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray,
     kl = (probs * (jnp.log(jnp.clip(probs, 1e-7, 1.0)) - logp_mix[None]))
     kl = kl.sum(axis=(1, 2)) / split
     return loss + alpha * kl.mean()
+
+
+def next_token_loss(hidden: jnp.ndarray, embedding: jnp.ndarray,
+                    targets: jnp.ndarray, chunk: int = 2048,
+                    weight: Optional[jnp.ndarray] = None):
+    """Mean cross-entropy of ``hidden @ embedding.T`` against integer
+    ``targets`` (batch, L), and the token accuracy in percent, over the
+    positions whose target is not negative (and whose row ``weight`` is not
+    zero).  The logits are made ``chunk`` positions at a time and made again
+    in the backward pass, so the (L, rows) float32 matrix is never whole:
+    16,384 x 25,008 of it would be 1.6 GB, its gradient as much again."""
+    b, l, d = hidden.shape
+    chunk = min(chunk, l)
+    pad = -l % chunk
+    if pad:
+        hidden = jnp.pad(hidden, ((0, 0), (0, pad), (0, 0)))
+        targets = jnp.pad(targets, ((0, 0), (0, pad)), constant_values=-1)
+    nc = (l + pad) // chunk
+    e = embedding.astype(hidden.dtype)
+    w = jnp.ones((b,), jnp.float32) if weight is None \
+        else weight.astype(jnp.float32)
+
+    @jax.checkpoint
+    def one(h, t):
+        logits = jnp.einsum("bld,vd->blv", h, e,
+                            preferred_element_type=jnp.float32)
+        tt = jnp.maximum(t, 0)
+        picked = jnp.take_along_axis(logits, tt[..., None], axis=-1)[..., 0]
+        valid = (t >= 0).astype(jnp.float32) * w[:, None]
+        nll = (jax.nn.logsumexp(logits, axis=-1) - picked) * valid
+        hit = (jnp.argmax(logits, axis=-1) == tt).astype(jnp.float32) * valid
+        return nll.sum(), hit.sum(), valid.sum()
+
+    nll, hit, n = jax.lax.map(lambda ht: one(*ht), (
+        hidden.reshape(b, nc, chunk, d).swapaxes(0, 1),
+        targets.reshape(b, nc, chunk).swapaxes(0, 1)))
+    n = jnp.maximum(n.sum(), 1.0)
+    return nll.sum() / n, 100.0 * hit.sum() / n
 
 
 def create_loss_fn(cfg) -> Callable:

@@ -76,6 +76,8 @@ _PEAK_FLOPS = {
 _COUNTER_CATALOG = (
     ("steps_total", "Train steps dispatched"),
     ("samples_total", "Training samples consumed"),
+    ("train_tokens_total", "Tokens of the train steps dispatched (sequence "
+     "models: rows x positions of each step's batch; 0 for image batches)"),
     ("drains_total", "Metric drain boundaries (telemetry records)"),
     ("step_seconds_total", "Wall seconds spent in the train loop"),
     ("data_wait_seconds_total", "Seconds the loop blocked on next(loader)"),
@@ -253,8 +255,9 @@ class TrainTelemetry:
 
     # -- hot-loop hooks ------------------------------------------------
     def on_step(self, n_samples: int, data_wait_s: float,
-                step_wall_s: float) -> None:
-        """Once per loop iteration; host floats only."""
+                step_wall_s: float, tokens: int = 0) -> None:
+        """Once per loop iteration; host floats only.  ``tokens``: rows x
+        positions of a sequence batch (``--seq-len``), 0 for images."""
         self._win_steps += 1
         self._win_wall += step_wall_s
         self._win_samples += int(n_samples)
@@ -264,6 +267,7 @@ class TrainTelemetry:
         with self._lock:
             self._c["steps_total"] += 1
             self._c["samples_total"] += n_samples
+            self._c["train_tokens_total"] += tokens
             self._c["step_seconds_total"] += step_wall_s
             self._c["data_wait_seconds_total"] += data_wait_s
 

@@ -30,6 +30,19 @@ offsets held in SMEM, so the same kernels serve the standalone op (offsets
 ``parallel/ring_attention.py``).  Fully-masked tiles are skipped with
 ``pl.when``.
 
+``window`` (causal sliding window: query ``t`` sees keys ``s`` with
+``0 <= t - s < window``) does more than mask: the innermost grid dimension
+shrinks to the tiles a block's window can touch and the index maps start at
+the block's first such tile, so tiles outside the window are neither
+fetched nor visited.  Grouped heads: ``k`` may carry fewer heads than ``q``
+and ``v`` fewer still and a wider head (``H % Hk == 0``, ``H % Hv == 0``;
+query head ``h`` reads key head ``h // (H / Hk)`` and value head
+``h // (H / Hv)``) through the index maps alone, with no repeated copy;
+dK/dV come out per query head and are summed over each group outside.
+``dot_dtype`` feeds the MXU operands in that dtype (bfloat16 for a bf16
+model; accumulation stays float32); the default keeps float32 operands.
+Callers that pass none of the three get the kernels and grids they had.
+
 On non-TPU backends the same kernels run under the Pallas interpreter
 (``interpret=True``), which is how the CPU test suite checks parity against
 ``parallel.ring_attention.full_attention`` for values *and* gradients.
@@ -66,7 +79,9 @@ def resolve_interpret(interpret: Optional[bool], kernel: str) -> bool:
     if interpret and kernel not in _warned_interpreted:
         _warned_interpreted.add(kernel)
         _logger.warning("%s runs under the Pallas INTERPRETER (backend %r)"
-                        " — correctness only, not the compiled kernel",
+                        " — correctness only, not the compiled kernel: the "
+                        "interpreter visits the same tiles (window skipping "
+                        "included) but times nothing",
                         kernel, jax.default_backend())
     return interpret
 
@@ -106,12 +121,41 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
+def _tiles_in_window(block_a: int, block_b: int, window: int, n: int) -> int:
+    """How many ``block_b`` tiles a ``block_a`` block's window can touch
+    (at most ``n``): the span is ``block_a + window - 1`` positions at any
+    alignment."""
+    return min(n, (block_a + window - 2) // block_b + 2)
+
+
+def _first_k_tile(iq, bq: int, bk: int, window: int):
+    """First key tile the window of q block ``iq`` reaches:
+    max(0, floor((iq*bq - window + 1) / bk)), on a non-negative numerator
+    so the truncating integer division is the floor."""
+    w = -(-window // bk) + 1
+    return jnp.maximum((iq * bq + (w * bk - (window - 1))) // bk - w, 0)
+
+
+def _first_q_tile(jk, bq: int, bk: int):
+    """First query tile that can see key block ``jk`` (causal)."""
+    return (jk * bk) // bq
+
+
+def _dots(dot_dtype):
+    """(operand dtype, scale q before the score dot?).  float32 operands
+    keep the original expression (q * scale, then the dot)."""
+    if dot_dtype is None:
+        return jnp.float32, True
+    return dot_dtype, False
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, seq_len, causal):
+                acc_ref, m_ref, l_ref, *, scale, seq_len, causal,
+                window=None, dot_dtype=None):
     """One (bh, q-block, k-tile) grid cell of the online softmax.
 
     ``q_off``/``kv_off`` are *global* sequence offsets of this Q shard / KV
@@ -133,24 +177,36 @@ def _fwd_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    relevant = jk * bk < seq_len               # tile has ≥1 un-padded key
+    cd, scale_q = _dots(dot_dtype)
+    jt = jk                                    # the key tile this cell reads
+    if window is not None:
+        jt = _first_k_tile(iq, bq, bk, window) + jk
+    relevant = jt * bk < seq_len               # tile has ≥1 un-padded key
     if causal:
         last_q = q_off + (iq + 1) * bq - 1
-        relevant = jnp.logical_and(relevant, kv_off + jk * bk <= last_q)
+        relevant = jnp.logical_and(relevant, kv_off + jt * bk <= last_q)
 
     @pl.when(relevant)
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
+        if scale_q:
+            q = q_ref[0].astype(jnp.float32) * scale
+        else:
+            q = q_ref[0].astype(cd)
+        k = k_ref[0].astype(cd)
+        v = v_ref[0].astype(cd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        k_loc = jk * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        if not scale_q:
+            s = s * scale
+        k_loc = jt * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         invalid = k_loc >= seq_len
         if causal:
             q_pos = q_off + iq * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 0)
             invalid = jnp.logical_or(invalid, kv_off + k_loc > q_pos)
+            if window is not None:
+                invalid = jnp.logical_or(
+                    invalid, q_pos - (kv_off + k_loc) >= window)
         s = jnp.where(invalid, _NEG_INF, s)
 
         m_prev = m_ref[:, :1]                                  # (BQ, 1)
@@ -163,7 +219,7 @@ def _fwd_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         corr = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_safe))
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(cd), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -181,33 +237,66 @@ def _fwd_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                                       lse_ref.shape[1:])
 
 
+def _kv_map(bh_q: int, bh_kv: int, tile=None):
+    """Index map of a key or value operand on a (bh, q block, k tile) grid:
+    query head ``b`` reads head ``b // group``; ``tile(i, j)`` names the
+    key tile (default: ``j``).  The plain map where nothing is grouped."""
+    g = bh_q // bh_kv
+    if g == 1 and tile is None:
+        return lambda b, i, j: (b, j, 0)
+    tile = tile or (lambda i, j: j)
+    return lambda b, i, j: (b // g, tile(i, j), 0)
+
+
+def _k_tile_map(block_q, block_k, window, nk):
+    """(k tiles on the grid, tile(i, j)) of the (bh, q block, k tile) grids:
+    all of them, or under a window only those the block's window touches,
+    clamped into range (the kernel skips what the clamp repeats)."""
+    if window is None:
+        return nk, None
+    return _tiles_in_window(block_q, block_k, window, nk), lambda i, j: \
+        jnp.minimum(_first_k_tile(i, block_q, block_k, window) + j, nk - 1)
+
+
+def _kernel_kwargs(window, dot_dtype):
+    """Static arguments only the new paths pass on."""
+    kw = {}
+    if window is not None:
+        kw["window"] = window
+    if dot_dtype is not None:
+        kw["dot_dtype"] = dot_dtype
+    return kw
+
+
 def _fwd(q, k, v, scale, block_q, block_k, causal, seq_len, interpret,
-         q_off=0, kv_off=0):
-    """Padded-layout forward: (BH, Lq, D), (BH, Lk, D)² → (out, lse)."""
+         q_off=0, kv_off=0, window=None, dot_dtype=None):
+    """Padded-layout forward: (BH, Lq, D), (BHk, Lk, D), (BHv, Lk, Dv) →
+    (out (BH, Lq, Dv), lse)."""
     bh, lpq, d = q.shape
-    lpk = k.shape[1]
-    grid = (bh, lpq // block_q, lpk // block_k)
+    lpk, dv = k.shape[1], v.shape[2]
+    nkt, tile = _k_tile_map(block_q, block_k, window, lpk // block_k)
+    grid = (bh, lpq // block_q, nkt)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, seq_len=seq_len,
-                          causal=causal),
+                          causal=causal, **_kernel_kwargs(window, dot_dtype)),
         grid=grid,
         in_specs=[
             _smem_scalar_spec(),
             _smem_scalar_spec(),
             _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            _vmem_spec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            _vmem_spec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            _vmem_spec((1, block_k, d), _kv_map(bh, k.shape[0], tile)),
+            _vmem_spec((1, block_k, dv), _kv_map(bh, v.shape[0], tile)),
         ],
         out_specs=[
-            _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            _vmem_spec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             _vmem_spec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            _out_struct((bh, lpq, d), q.dtype, q),
+            _out_struct((bh, lpq, dv), q.dtype, q),
             _out_struct((bh, lpq, _LANES), jnp.float32, q),
         ],
         scratch_shapes=[
-            _scratch((block_q, d)),
+            _scratch((block_q, dv)),
             _scratch((block_q, _LANES)),
             _scratch((block_q, _LANES)),
         ],
@@ -222,7 +311,8 @@ def _fwd(q, k, v, scale, block_q, block_k, causal, seq_len, interpret,
 
 def _bwd_dkv_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, scale, seq_len, causal):
+                    *, scale, seq_len, causal, window=None, dot_dtype=None,
+                    q_tiles=None):
     """One (bh, k-block, q-tile) grid cell accumulating dK, dV."""
     bk, d = k_ref.shape[1], k_ref.shape[2]
     bq = q_ref.shape[1]
@@ -237,37 +327,52 @@ def _bwd_dkv_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
+    cd, scale_q = _dots(dot_dtype)
+    it = iq                                  # the query tile this cell reads
     relevant = jk * bk < seq_len
+    if window is not None:
+        it = _first_q_tile(jk, bq, bk) + iq
+        # past the last query tile the clamped map repeats it; and the
+        # tile's first row must still be inside the block's last window
+        relevant = jnp.logical_and(relevant, it < q_tiles)
+        relevant = jnp.logical_and(
+            relevant, it * bq - (jk * bk + bk - 1) < window)
     if causal:
         # this q tile's last global row must reach the k block's first row
-        last_q = q_off + (iq + 1) * bq - 1
+        last_q = q_off + (it + 1) * bq - 1
         relevant = jnp.logical_and(relevant, kv_off + jk * bk <= last_q)
 
     @pl.when(relevant)
     def _accumulate():
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        q = q_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+        k = k_ref[0].astype(cd)
+        v = v_ref[0].astype(cd)
+        q = q_ref[0].astype(cd)
+        do = do_ref[0].astype(cd)
         lse = lse_ref[0, :, :1]                                 # (BQ, 1)
         delta = delta_ref[0, :, :1]
-        s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
+        s = jax.lax.dot_general(q * scale if scale_q else q, k,
+                                (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
+        if not scale_q:
+            s = s * scale
         k_loc = jk * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         invalid = k_loc >= seq_len
         if causal:
-            q_pos = q_off + iq * bq + jax.lax.broadcasted_iota(
+            q_pos = q_off + it * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 0)
             invalid = jnp.logical_or(invalid, kv_off + k_loc > q_pos)
+            if window is not None:
+                invalid = jnp.logical_or(
+                    invalid, q_pos - (kv_off + k_loc) >= window)
         p = jnp.where(invalid, 0.0, jnp.exp(s - lse))           # (BQ, BK)
         dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            p.astype(cd), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * scale
         dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            ds.astype(cd), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(iq == nq - 1)
@@ -278,7 +383,7 @@ def _bwd_dkv_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
 
 def _bwd_dq_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, dq_ref, dq_acc, *, scale, seq_len,
-                   causal):
+                   causal, window=None, dot_dtype=None):
     """One (bh, q-block, k-tile) grid cell accumulating dQ."""
     bq, d = q_ref.shape[1], q_ref.shape[2]
     bk = k_ref.shape[1]
@@ -292,33 +397,43 @@ def _bwd_dq_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    relevant = jk * bk < seq_len
+    cd, scale_q = _dots(dot_dtype)
+    jt = jk
+    if window is not None:
+        jt = _first_k_tile(iq, bq, bk, window) + jk
+    relevant = jt * bk < seq_len
     if causal:
         last_q = q_off + (iq + 1) * bq - 1
-        relevant = jnp.logical_and(relevant, kv_off + jk * bk <= last_q)
+        relevant = jnp.logical_and(relevant, kv_off + jt * bk <= last_q)
 
     @pl.when(relevant)
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+        q = q_ref[0].astype(cd)
+        k = k_ref[0].astype(cd)
+        v = v_ref[0].astype(cd)
+        do = do_ref[0].astype(cd)
         lse = lse_ref[0, :, :1]                                 # (BQ, 1)
         delta = delta_ref[0, :, :1]
-        s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
+        s = jax.lax.dot_general(q * scale if scale_q else q, k,
+                                (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        k_loc = jk * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        if not scale_q:
+            s = s * scale
+        k_loc = jt * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         invalid = k_loc >= seq_len
         if causal:
             q_pos = q_off + iq * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 0)
             invalid = jnp.logical_or(invalid, kv_off + k_loc > q_pos)
+            if window is not None:
+                invalid = jnp.logical_or(
+                    invalid, q_pos - (kv_off + k_loc) >= window)
         p = jnp.where(invalid, 0.0, jnp.exp(s - lse))
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * scale
         dq_acc[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
+            ds.astype(cd), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(jk == nk - 1)
@@ -327,58 +442,78 @@ def _bwd_dq_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
 
 
 def _bwd_dkv(q, k, v, do, lse, delta, scale, block_q, block_k, causal,
-             seq_len, interpret, q_off=0, kv_off=0):
-    """dK, dV for one KV buffer, streaming Q tiles.  Padded layout."""
+             seq_len, interpret, q_off=0, kv_off=0, window=None,
+             dot_dtype=None):
+    """dK, dV for one KV buffer, streaming Q tiles.  Padded layout.  With
+    grouped heads the result is per QUERY head, (BH, Lk, ·): the caller sums
+    each group."""
     bh, lpq, d = q.shape
-    lpk = k.shape[1]
+    lpk, dv = k.shape[1], v.shape[2]
+    nq = lpq // block_q
+    kw = _kernel_kwargs(window, dot_dtype)
+    if window is None:
+        nqt, q_map = nq, lambda b, j, i: (b, i, 0)
+    else:
+        nqt = _tiles_in_window(block_k, block_q, window, nq)
+        kw["q_tiles"] = nq
+        q_map = lambda b, j, i: (b, jnp.minimum(               # noqa: E731
+            _first_q_tile(j, block_q, block_k) + i, nq - 1), 0)
+    gk, gv = bh // k.shape[0], bh // v.shape[0]
+    k_map = (lambda b, j, i: (b, j, 0)) if gk == 1 else \
+        (lambda b, j, i: (b // gk, j, 0))
+    v_map = (lambda b, j, i: (b, j, 0)) if gv == 1 else \
+        (lambda b, j, i: (b // gv, j, 0))
     kern = functools.partial(_bwd_dkv_kernel, scale=scale, seq_len=seq_len,
-                             causal=causal)
+                             causal=causal, **kw)
     return pl.pallas_call(
         kern,
-        grid=(bh, lpk // block_k, lpq // block_q),
+        grid=(bh, lpk // block_k, nqt),
         in_specs=[
             _smem_scalar_spec(),
             _smem_scalar_spec(),
-            _vmem_spec((1, block_q, d), lambda b, j, i: (b, i, 0)),   # q
-            _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),   # k
-            _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),   # v
-            _vmem_spec((1, block_q, d), lambda b, j, i: (b, i, 0)),   # do
-            _vmem_spec((1, block_q, _LANES), lambda b, j, i: (b, i, 0)),
-            _vmem_spec((1, block_q, _LANES), lambda b, j, i: (b, i, 0)),
+            _vmem_spec((1, block_q, d), q_map),                       # q
+            _vmem_spec((1, block_k, d), k_map),                       # k
+            _vmem_spec((1, block_k, dv), v_map),                      # v
+            _vmem_spec((1, block_q, dv), q_map),                      # do
+            _vmem_spec((1, block_q, _LANES), q_map),
+            _vmem_spec((1, block_q, _LANES), q_map),
         ],
         out_specs=[
             _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            _vmem_spec((1, block_k, dv), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
             _out_struct((bh, lpk, d), jnp.float32, k),
-            _out_struct((bh, lpk, d), jnp.float32, k),
+            _out_struct((bh, lpk, dv), jnp.float32, k),
         ],
         scratch_shapes=[
             _scratch((block_k, d)),
-            _scratch((block_k, d)),
+            _scratch((block_k, dv)),
         ],
         interpret=interpret,
     )(_as_scalar(q_off), _as_scalar(kv_off), q, k, v, do, lse, delta)
 
 
 def _bwd_dq(q, k, v, do, lse, delta, scale, block_q, block_k, causal,
-            seq_len, interpret, q_off=0, kv_off=0):
+            seq_len, interpret, q_off=0, kv_off=0, window=None,
+            dot_dtype=None):
     """dQ for this Q shard against one KV buffer, streaming K tiles."""
     bh, lpq, d = q.shape
-    lpk = k.shape[1]
+    lpk, dv = k.shape[1], v.shape[2]
+    nkt, tile = _k_tile_map(block_q, block_k, window, lpk // block_k)
     kern = functools.partial(_bwd_dq_kernel, scale=scale, seq_len=seq_len,
-                             causal=causal)
+                             causal=causal,
+                             **_kernel_kwargs(window, dot_dtype))
     return pl.pallas_call(
         kern,
-        grid=(bh, lpq // block_q, lpk // block_k),
+        grid=(bh, lpq // block_q, nkt),
         in_specs=[
             _smem_scalar_spec(),
             _smem_scalar_spec(),
             _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),   # q
-            _vmem_spec((1, block_k, d), lambda b, i, j: (b, j, 0)),   # k
-            _vmem_spec((1, block_k, d), lambda b, i, j: (b, j, 0)),   # v
-            _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),   # do
+            _vmem_spec((1, block_k, d), _kv_map(bh, k.shape[0], tile)),  # k
+            _vmem_spec((1, block_k, dv), _kv_map(bh, v.shape[0], tile)),  # v
+            _vmem_spec((1, block_q, dv), lambda b, i, j: (b, i, 0)),  # do
             _vmem_spec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
             _vmem_spec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
         ],
@@ -395,14 +530,24 @@ def _delta(do, out):
     return jnp.broadcast_to(d[..., None], (*d.shape, _LANES))
 
 
-def _bwd(scale, block_q, block_k, causal, interpret, seq_len, res, g):
+def _group_sum(x, heads: int):
+    """(BH, L, D) per query head -> (heads, L, D): each group's sum."""
+    if x.shape[0] == heads:
+        return x
+    return x.reshape(heads, x.shape[0] // heads, *x.shape[1:]).sum(axis=1)
+
+
+def _bwd(scale, block_q, block_k, causal, interpret, seq_len, res, g,
+         window=None, dot_dtype=None):
     q, k, v, out, lse = res
     do = g[0] if isinstance(g, (tuple, list)) else g
     delta = _delta(do, out)
+    kw = _kernel_kwargs(window, dot_dtype)
     dk, dv = _bwd_dkv(q, k, v, do, lse, delta, scale, block_q, block_k,
-                      causal, seq_len, interpret)
+                      causal, seq_len, interpret, **kw)
     dq = _bwd_dq(q, k, v, do, lse, delta, scale, block_q, block_k,
-                 causal, seq_len, interpret)
+                 causal, seq_len, interpret, **kw)
+    dk, dv = _group_sum(dk, k.shape[0]), _group_sum(dv, v.shape[0])
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -413,35 +558,49 @@ def _bwd(scale, block_q, block_k, causal, interpret, seq_len, res, g):
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = False, scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Fused O(L) -memory attention.  Shapes ``(B, L, H, D) → (B, L, H, D)``
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None,
+                    dot_dtype=None) -> jnp.ndarray:
+    """Fused O(L) -memory attention.  Shapes ``(B, L, H, D) → (B, L, H, Dv)``
     (same convention as :func:`parallel.ring_attention.full_attention`).
 
     The Q buffer pads to a ``block_q`` multiple and the KV buffer to a
-    ``block_k`` multiple (head dim to the 128-lane width); pad keys are
+    ``block_k`` multiple (head dims to the 128-lane width); pad keys are
     masked inside the kernel, so any static shape works.  Gradients flow
     through a custom VJP whose backward is also Pallas.  ``interpret``
     defaults to True off-TPU so tests run on the CPU interpreter.
+
+    ``window`` (needs ``causal``): query ``t`` sees keys ``t - window < s <=
+    t``; tiles outside are skipped, not masked.  ``k`` ``(B, L, Hk, D)`` and
+    ``v`` ``(B, L, Hv, Dv)`` may carry fewer heads than ``q`` (grouped, see
+    the module's text) and ``v`` another head size.  ``dot_dtype`` is the
+    MXU operands' dtype (default float32).
     """
     assert q.ndim == 4, f"expected (B, L, H, D), got {q.shape}"
-    # self-attention shapes only: prep() folds (B, H) together and pads with
+    # one sequence length only: prep() folds (B, H) together and pads with
     # q's L, so a cross-attention Lk != Lq would die deep inside prep with an
     # opaque reshape error — reject it here instead
-    assert q.shape == k.shape == v.shape, (
-        f"flash_attention supports self-attention shapes only "
-        f"(q{q.shape} k{k.shape} v{v.shape} must be equal)")
     b, l, h, d = q.shape
+    hk, hv, d_v = k.shape[2], v.shape[2], v.shape[3]
+    assert q.shape[:2] == k.shape[:2] == v.shape[:2] and k.shape[3] == d \
+        and h % hk == 0 and h % hv == 0, (
+        f"flash_attention needs one batch and length, one q/k head size and "
+        f"grouped head counts (q{q.shape} k{k.shape} v{v.shape})")
+    assert window is None or (causal and window > 0), \
+        "window needs causal=True and a positive size"
     interpret = resolve_interpret(interpret, "flash_attention")
     scale = scale if scale is not None else d ** -0.5
     block_q = min(block_q, _round_up(l, 128))
     block_k = min(block_k, _round_up(l, 128))
     lpq = _round_up(l, block_q)
     lpk = _round_up(l, block_k)
-    dp = _round_up(d, 128)
+    kw = _kernel_kwargs(window, dot_dtype)
 
     def prep(x, lp):  # (B, L, H, D) -> (B*H, lp, Dp)
-        x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, l, d)
-        return jnp.pad(x, ((0, 0), (0, lp - l), (0, dp - d)))
+        hx, dx = x.shape[2], x.shape[3]
+        x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * hx, l, dx)
+        return jnp.pad(x, ((0, 0), (0, lp - l),
+                           (0, _round_up(dx, 128) - dx)))
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=())
     def _op(qp, kp, vp):
@@ -454,13 +613,14 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     def _fwd_call(qp, kp, vp):
         return _fwd(qp, kp, vp, scale, block_q, block_k, causal, l,
-                    interpret)
+                    interpret, **kw)
 
     def _op_bwd(res, g):
-        return _bwd(scale, block_q, block_k, causal, interpret, l, res, g)
+        return _bwd(scale, block_q, block_k, causal, interpret, l, res, g,
+                    **kw)
 
     _op.defvjp(_op_fwd, _op_bwd)
 
     out = _op(prep(q, lpq), prep(k, lpk), prep(v, lpk))
-    out = out[:, :l, :d].reshape(b, h, l, d)
+    out = out[:, :l, :d_v].reshape(b, h, l, d_v)
     return jnp.transpose(out, (0, 2, 1, 3))

@@ -43,10 +43,13 @@ def weight_decay_mask(params) -> Any:
 
 
 def _base_optimizer(name: str, learning_rate, *, opt_eps: float,
-                    momentum: float, weight_decay: float,
-                    mask) -> optax.GradientTransformation:
+                    momentum: float, weight_decay: float, mask,
+                    beta2: Optional[float] = None
+                    ) -> optax.GradientTransformation:
     """Build one optimizer by (already lowercased, prefix-stripped) name."""
     wd = weight_decay
+    # a recipe's second-moment decay (adam, adamw); None keeps optax's own
+    betas = {} if beta2 is None else {"b2": beta2}
 
     if name == "sgd":
         # reference uses nesterov=True (optim_factory.py:48-50)
@@ -57,11 +60,11 @@ def _base_optimizer(name: str, learning_rate, *, opt_eps: float,
     elif name == "adam":
         tx = optax.chain(
             optax.add_decayed_weights(wd, mask) if wd else optax.identity(),
-            optax.adam(learning_rate, eps=opt_eps),
+            optax.adam(learning_rate, eps=opt_eps, **betas),
         )
     elif name == "adamw":
         tx = optax.adamw(learning_rate, eps=opt_eps, weight_decay=wd,
-                         mask=mask)
+                         mask=mask, **betas)
     elif name == "nadam":
         tx = optax.chain(
             optax.add_decayed_weights(wd, mask) if wd else optax.identity(),
@@ -128,8 +131,9 @@ def create_optimizer(cfg, params=None, learning_rate: Optional[float] = None,
     """Build the optimizer from a TrainConfig-like object.
 
     ``cfg`` needs: opt, opt_eps, momentum, weight_decay, and (if
-    ``learning_rate`` not given) lr.  ``params`` is only used to note that
-    masks are structural (callable masks are used, so params may be None).
+    ``learning_rate`` not given) lr; ``opt_beta2`` (adam, adamw) is read if
+    it is there.  ``params`` is only used to note that masks are structural
+    (callable masks are used, so params may be None).
     """
     del params
     opt_name = cfg.opt.lower()
@@ -161,7 +165,8 @@ def create_optimizer(cfg, params=None, learning_rate: Optional[float] = None,
     def make(learning_rate):
         tx = _base_optimizer(base_name, learning_rate, opt_eps=cfg.opt_eps,
                              momentum=cfg.momentum,
-                             weight_decay=weight_decay, mask=mask)
+                             weight_decay=weight_decay, mask=mask,
+                             beta2=getattr(cfg, "opt_beta2", None))
         if len(parts) > 1 and parts[0] == "lookahead":
             tx = lookahead(tx)
         return tx

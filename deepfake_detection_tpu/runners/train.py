@@ -41,7 +41,9 @@ import numpy as np
 
 from ..config import ClusterConfig, TrainConfig
 from ..data import (DeepFakeClipDataset, FastCollateMixup, SyntheticDataset,
-                    create_deepfake_loader_v3, resolve_data_config)
+                    SyntheticTokenDataset, TokenFileDataset,
+                    create_deepfake_loader_v3, create_token_loader,
+                    resolve_data_config)
 from ..losses import create_loss_fn, cross_entropy
 from ..models import (create_deepfake_model, create_deepfake_model_v3,
                       create_deepfake_model_v4, create_model, init_model)
@@ -115,7 +117,8 @@ def build_model(cfg: TrainConfig, in_chans: int):
 
 
 def build_datasets(cfg: TrainConfig, input_size, pack_dir=None,
-                   pack_image_size=None) -> Tuple[Any, Any]:
+                   pack_image_size=None, vocab_rows: int = 0
+                   ) -> Tuple[Any, Any]:
     """Train/eval dataset construction (reference train.py:422-504).
 
     ``pack_dir`` (``--data-packed``, resolved through
@@ -125,6 +128,21 @@ def build_datasets(cfg: TrainConfig, input_size, pack_dir=None,
     bit-identical at matching pack resolution.  A stale or mismatched
     pack raises at construction, never trains on skewed data.
     """
+    if cfg.dataset in ("synthetic-tokens", "tokens"):
+        # the sequence models' rows (data/tokens.py): whole documents of
+        # --seq-len ids below the vocabulary rows the model holds
+        if cfg.seq_len <= 0 or vocab_rows <= 0:
+            raise ValueError(f"--dataset {cfg.dataset} needs --seq-len and a "
+                             "model of the sequence task")
+        if cfg.dataset == "synthetic-tokens":
+            n = max(cfg.batch_size * 8, 16)
+            return (SyntheticTokenDataset(n, cfg.seq_len, vocab_rows,
+                                          cfg.seed),
+                    SyntheticTokenDataset(max(n // 2, 8), cfg.seq_len,
+                                          vocab_rows, cfg.seed + 1))
+        return (TokenFileDataset(cfg.data, cfg.seq_len, vocab_rows),
+                TokenFileDataset(cfg.eval_data or cfg.data, cfg.seq_len,
+                                 vocab_rows))
     c, h, w = input_size
     if cfg.dataset == "synthetic":
         if pack_dir:
@@ -244,10 +262,15 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
     img_num = max(1, in_chans // 3)
 
     model = build_model(cfg, in_chans)
+    # a model of the sequence task takes (batch, L) ids; no parameter's
+    # shape depends on L, so a short row initializes it
+    sequence_task = bool(getattr(model, "sequence_task", False))
     init_rng, rng = jax.random.split(rng)
-    variables = init_model(model, init_rng,
-                           (1, input_size[1], input_size[2], in_chans),
-                           training=True)
+    variables = init_model(model, init_rng, (1, 8), training=True,
+                           dtype=jnp.int32) if sequence_task else \
+        init_model(model, init_rng,
+                   (1, input_size[1], input_size[2], in_chans),
+                   training=True)
     n_params = sum(x.size for x in jax.tree.leaves(variables["params"]))
     _logger.info("Model %s created, param count: %d", cfg.model, n_params)
 
@@ -256,7 +279,7 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
     # (create_train_state donates the buffers).  The shape is what the
     # LOADER feeds the model — pixel-shuffled under --stem-s2d.
     fwd_flops = 0.0
-    if not cfg.no_telemetry:
+    if not cfg.no_telemetry and not sequence_task:
         from ..obs import forward_flops_per_sample
         flop_shape = (1, input_size[1] // 2, input_size[2] // 2,
                       4 * in_chans) if cfg.stem_s2d else \
@@ -438,7 +461,8 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
                          "starting fresh", output_dir)
     train_ds, eval_ds = build_datasets(
         cfg, input_size, pack_dir=data_config.get("pack_dir"),
-        pack_image_size=data_config.get("pack_image_size"))
+        pack_image_size=data_config.get("pack_image_size"),
+        vocab_rows=getattr(model, "vocab_rows", 0))
     sharding = batch_sharding(mesh)
     # loaders produce the *per-process* slice of the global batch; the device
     # prologue assembles the global sharded array
@@ -460,20 +484,32 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
     collate_mixup = FastCollateMixup(cfg.mixup, cfg.smoothing,
                                      cfg.num_classes) if cfg.mixup > 0 \
         else None
-    train_loader = create_deepfake_loader_v3(
-        train_ds, input_size, local_batch, is_training=True,
-        re_prob=cfg.reprob, re_mode=cfg.remode, re_count=cfg.recount,
-        re_split=cfg.resplit, re_max=cfg.remax, color_jitter=cfg.color_jitter,
-        num_aug_splits=cfg.aug_splits, collate_mixup=collate_mixup,
-        flicker=cfg.flicker, rotate_range=cfg.rotate_range,
-        blur_radius=1, blur_prob=cfg.blur_prob,
-        device_color_jitter=not cfg.host_color_jitter,
-        fused_geom=not cfg.host_geom,
-        augment_device=cfg.augment_device == "on", **loader_kwargs)
-    eval_loader = create_deepfake_loader_v3(
-        eval_ds, input_size, eval_local_batch, is_training=False,
-        eval_crop=cfg.eval_crop,
-        **loader_kwargs)                          # eval bs ×2 (train.py:492)
+    if sequence_task:
+        token_kwargs = dict(
+            num_workers=cfg.workers, seed=cfg.seed, sharding=sharding,
+            distributed=jax.process_count() > 1,
+            num_shards=jax.process_count(), shard_index=rank,
+            prefetch_depth=cfg.prefetch_depth)
+        train_loader = create_token_loader(train_ds, local_batch,
+                                           is_training=True, **token_kwargs)
+        eval_loader = create_token_loader(eval_ds, eval_local_batch,
+                                          is_training=False, **token_kwargs)
+    else:
+        train_loader = create_deepfake_loader_v3(
+            train_ds, input_size, local_batch, is_training=True,
+            re_prob=cfg.reprob, re_mode=cfg.remode, re_count=cfg.recount,
+            re_split=cfg.resplit, re_max=cfg.remax,
+            color_jitter=cfg.color_jitter,
+            num_aug_splits=cfg.aug_splits, collate_mixup=collate_mixup,
+            flicker=cfg.flicker, rotate_range=cfg.rotate_range,
+            blur_radius=1, blur_prob=cfg.blur_prob,
+            device_color_jitter=not cfg.host_color_jitter,
+            fused_geom=not cfg.host_geom,
+            augment_device=cfg.augment_device == "on", **loader_kwargs)
+        eval_loader = create_deepfake_loader_v3(
+            eval_ds, input_size, eval_local_batch, is_training=False,
+            eval_crop=cfg.eval_crop,
+            **loader_kwargs)                      # eval bs ×2 (train.py:492)
 
     train_loss_fn = create_loss_fn(cfg)
     # tp runs use global-BN semantics: the transformer families carry no

@@ -74,9 +74,15 @@ def make_train_step(model, tx: optax.GradientTransformation,
                     state_shardings: Optional[Any] = None) -> Callable:
     """Build ``train_step(state, x, y, rng) -> (state, metrics)``.
 
-    ``x`` is the (globally) batch-sharded NHWC input, ``y`` int labels or
-    soft targets.  ``metrics`` = {'loss', 'prec1'} global-batch scalars
-    (replaces the per-step ``reduce_tensor`` calls, train.py:625-627).
+    ``x`` is the (globally) batch-sharded input: an NHWC batch with ``y``
+    int labels or soft targets, or, for a model of the sequence task (one
+    with ``sequence_task``, e.g. models/phi4flash.py), ``(batch, L)`` token
+    ids with ``y`` the ids shifted by one (negative = no target).  There the
+    model's ``sequence_loss`` makes the chunked next-token loss
+    (losses.py:next_token_loss) and ``loss_fn`` is not called.  ``metrics``
+    = {'loss', 'prec1'} global-batch scalars (replaces the per-step
+    ``reduce_tensor`` calls, train.py:625-627); for the sequence task
+    'prec1' is the token accuracy.  ``batch_stats`` may be an empty tree.
 
     ``mesh`` + ``axis`` (default: the mesh's own data axis) select the
     unified GSPMD path: the batch is constrained to ``P(axis)``, local-BN
@@ -106,8 +112,20 @@ def make_train_step(model, tx: optax.GradientTransformation,
     """
     assert bn_mode in ("local", "global"), bn_mode
     assert grad_accum >= 1
+    sequence_task = bool(getattr(model, "sequence_task", False))
 
     def forward_backward_one(params, batch_stats, x, y, rng):
+        if sequence_task:
+            def seq_lossf(p):
+                (loss, acc), mut = model.apply(
+                    {"params": p, "batch_stats": batch_stats}, x, y,
+                    training=True, mutable=["batch_stats"],
+                    rngs={"dropout": rng}, method="sequence_loss")
+                return loss, (acc, mut.get("batch_stats", batch_stats))
+            (loss, (acc, new_stats)), grads = jax.value_and_grad(
+                seq_lossf, has_aux=True)(params)
+            return loss, grads, new_stats, acc
+
         def lossf(p):
             variables = {"params": p, "batch_stats": batch_stats}
             out = model.apply(variables, x, training=True,
@@ -229,13 +247,20 @@ def make_eval_step(model, loss_fn: Callable = cross_entropy,
     validation is exact (the reference accepted the duplicate error,
     loader.py:794-796).  Returns {'loss', 'prec1', 'count'} where loss/prec1
     are means over valid samples in this batch (reference validate,
-    train.py:703-767).
+    train.py:703-767).  A model of the sequence task reports its
+    next-token loss and token accuracy and no logits.
     """
 
     @jax.jit
     def step(state: TrainState, x, y,
              valid: Optional[jnp.ndarray] = None) -> Dict[str, jnp.ndarray]:
         variables = state.ema_variables if use_ema else state.variables
+        if getattr(model, "sequence_task", False):
+            loss, acc = model.apply(variables, x, y, training=False,
+                                    weight=valid, method="sequence_loss")
+            return {"loss": loss, "prec1": acc,
+                    "count": (valid.sum() if valid is not None
+                              else jnp.asarray(x.shape[0]))}
         logits = model.apply(variables, x, training=False)
         loss = loss_fn(logits, y, weight=valid)
         prec1 = accuracy(logits, y, weight=valid)

@@ -105,6 +105,8 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
     num_updates = epoch * num_batches + start_batch
     nonfinite_total = 0
     lr = get_learning_rate(state)
+    # a token dataset's rows (--seq-len): their positions count as tokens
+    seq_len = getattr(cfg, "seq_len", 0)
     chaos = getattr(resilience, "chaos", None)
     if chaos is not None and not chaos.active:
         chaos = None
@@ -215,7 +217,8 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
         batch_time_m.update(time.monotonic() - end)
         if telemetry is not None:
             # host floats the loop already holds — no device access
-            telemetry.on_step(bs, data_time_m.val, batch_time_m.val)
+            telemetry.on_step(bs, data_time_m.val, batch_time_m.val,
+                              tokens=x.size if seq_len else 0)
         if profiler is not None:
             # cheap flag check when idle; manages an active trace window
             profiler.on_step(num_updates, metrics.get("loss"))

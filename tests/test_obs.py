@@ -794,10 +794,10 @@ class TestOverheadGuard:
         seen_types = []
 
         class Checked(TrainTelemetry):
-            def on_step(self, n, data_wait_s, step_wall_s):
+            def on_step(self, n, data_wait_s, step_wall_s, tokens=0):
                 seen_types.extend([type(n), type(data_wait_s),
-                                   type(step_wall_s)])
-                super().on_step(n, data_wait_s, step_wall_s)
+                                   type(step_wall_s), type(tokens)])
+                super().on_step(n, data_wait_s, step_wall_s, tokens)
 
         t = Checked()
         calls["n"] = 0

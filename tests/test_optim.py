@@ -99,6 +99,37 @@ def test_factory_dispatch_and_step(name):
     assert "learning_rate" in state.hyperparams
 
 
+def _three_steps(tx, seed=0):
+    rng = np.random.default_rng(seed)
+    params = {"kernel": jnp.ones((3, 4)), "bias": jnp.zeros(4)}
+    state = tx.init(params)
+    for _ in range(3):
+        grads = jax.tree.map(
+            lambda p: jnp.asarray(rng.normal(size=p.shape), p.dtype), params)
+        updates, state = tx.update(grads, state, params)
+        params = optax.apply_updates(params, updates)
+    return jax.tree.leaves(params)
+
+
+@pytest.mark.parametrize("name", ["adam", "adamw"])
+def test_opt_beta2_reaches_the_second_moment(name):
+    """``--opt-beta2`` is the recipe's b2; left out (or None) the program is
+    optax's default, bit for bit."""
+    cfg = _Cfg()
+    cfg.opt, cfg.weight_decay = name, 0.0
+    default = _three_steps(create_optimizer(cfg))
+    cfg.opt_beta2 = None
+    for a, b in zip(_three_steps(create_optimizer(cfg)), default):
+        np.testing.assert_array_equal(a, b)
+    cfg.opt_beta2 = 0.95
+    decay = {"weight_decay": 0.0} if name == "adamw" else {}
+    plain = getattr(optax, name)(cfg.lr, b2=0.95, eps=cfg.opt_eps, **decay)
+    got = _three_steps(create_optimizer(cfg))
+    for a, b, c in zip(got, _three_steps(plain), default):
+        np.testing.assert_array_equal(a, b)
+        assert float(jnp.max(jnp.abs(a - c))) > 1e-6
+
+
 def test_factory_invalid_name():
     cfg = _Cfg()
     cfg.opt = "doesnotexist"

@@ -9,6 +9,7 @@ cleanup on close.
 """
 
 import os
+import time
 
 import numpy as np
 import pytest
@@ -90,6 +91,12 @@ class CrashOnceDataset:
         if (index == self.crash_index and os.getpid() != self.parent_pid
                 and not os.path.exists(self.sentinel)):
             open(self.sentinel, "w").close()
+            # die mid-sample, not mid-ack: a kill while this worker's queue
+            # feeder thread is still writing an earlier sample's ack leaves
+            # done_q's write lock held for good, and no ack of any worker
+            # arrives again (seen under a loaded box; shm_ring.py's
+            # lost-ack net re-dispatches but cannot free the lock)
+            time.sleep(0.5)
             os._exit(3)
         return self.base.__getitem__(index, rng=rng)
 

@@ -3,7 +3,8 @@
 libtpu's compiler is installed in the CPU sandbox and compiles for a
 *described* (not attached) ``v5e:2x2`` topology, so Mosaic's verdict on
 each kernel at published widths (flagship / B4 depthwise stages, ViT-B/16
-attention) is a two-second test instead of a chip call.  Interpret mode
+attention, Phi-4-mini-flash's attention and selective scan at 16,384
+tokens) is a two-second test instead of a chip call.  Interpret mode
 cannot see what this sees: unaligned tiles, VMEM overflow, unsupported
 strided accesses.  Nothing runs, so nothing here is a measurement.
 
@@ -21,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from deepfake_detection_tpu.ops.depthwise_pallas import fused_depthwise
 from deepfake_detection_tpu.ops.flash_attention import flash_attention
+from deepfake_detection_tpu.ops.selective_scan import selective_scan
 
 
 @pytest.fixture(scope="module")
@@ -112,3 +114,38 @@ def test_flash_attention_vit_b16_compiles(one_chip, grad):
             jnp.float32).sum(), argnums=(0, 1, 2)), qkv, qkv, qkv)
     else:
         _compile(attn, qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("window,block", [(512, 256), (None, 512)],
+                         ids=["window", "full"])
+def test_flash_attention_phi4flash_16k_compiles(one_chip, window, block):
+    """Phi-4-mini-flash at 16,384 tokens, forward and both backward kernels:
+    40 query heads and 20 key heads of 64 reading 10 value pairs of 128
+    through the index maps, bf16 operands, the window layer's shrunk grid
+    and the full layer's causal one, at the model's own block sizes."""
+    spec = lambda h, d: jax.ShapeDtypeStruct(               # noqa: E731
+        (1, 16384, h, d), jnp.bfloat16, sharding=one_chip)
+    _compile(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, block_q=block, block_k=block,
+        dot_dtype=jnp.bfloat16, interpret=False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), spec(40, 64), spec(20, 64), spec(10, 128))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_selective_scan_phi4flash_16k_compiles(one_chip, grad):
+    """Phi-4-mini-flash's recurrence at 16,384 tokens: 5120 channels (five
+    blocks of 1024 as dense tiles), 16 states, chunks of 128 (the backward
+    kernel's 8.3 MB of rebuilt states in VMEM)."""
+    spec = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dt, sharding=one_chip)
+    args = (spec(1, 16384, 5120, dt=jnp.bfloat16), spec(1, 16384, 5120),
+            spec(5120, 16), spec(1, 16384, 16, dt=jnp.bfloat16),
+            spec(1, 16384, 16, dt=jnp.bfloat16), spec(5120))
+
+    def scan(*a):
+        return selective_scan(*a, chunk=128, impl="pallas", interpret=False)
+    if grad:
+        _compile(jax.grad(lambda *a: scan(*a).astype(jnp.float32).sum(),
+                          argnums=range(6)), *args)
+    else:
+        _compile(scan, *args)
