@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from ..losses import next_token_loss
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import flash_attention, tile_census
 from ..ops.selective_scan import selective_scan
 from ..registry import register_model
 from .helpers import maybe_remat
@@ -48,6 +48,18 @@ MAMBA, WINDOW, FULL, GMU, CROSS = "mamba", "window", "full", "gmu", "cross"
 def layer_schedule(self_periods: int, cross_periods: int) -> Tuple[str, ...]:
     return (MAMBA, WINDOW) * self_periods + (MAMBA, FULL) + \
         (GMU, CROSS) * cross_periods
+
+
+def _flash_block(window: Optional[int]) -> int:
+    """The attention kernels' block (q and k alike): what a layer's call
+    passes and what the model's census counts.  The two constants come from
+    one probe, on a v5e at 16,384 tokens and these head sizes (PERF.md
+    section 6, PR 27): of ten block shapes 1024 x 1024 was the fastest
+    that fits VMEM there (a layer's kernels 221.8 ms at 512, 120.6 at
+    1024), and under the window of 512, 512 beat 256 and 1024 (30.7 against
+    50.2 and 35.7 ms).  Another chip, length or head size wants its own
+    probe."""
+    return 512 if window is not None else 1024
 
 
 def lambda_init(layer: int) -> float:
@@ -181,7 +193,7 @@ class _Layer(nn.Module):
             window = self.window if self.kind == WINDOW else None
             scale = dh ** -0.5
             if self.attn_impl == "flash":
-                blk = 256 if window is not None else 512
+                blk = _flash_block(window)
                 o = flash_attention(
                     q, k, v, causal=True, window=window, scale=scale,
                     block_q=blk, block_k=blk,
@@ -260,6 +272,24 @@ class Phi4Flash(nn.Module):
             elif kind == FULL:
                 kv = out
         return self.final_ln(x)
+
+    def attn_tiles_visited(self, seq_len: int) -> int:
+        """Grid cells the attention kernels visit in one train step over one
+        row of ``seq_len`` tokens: each attention layer's query heads times
+        the three kernels' counts (ops/flash_attention.py:tile_census; a
+        forward made again under remat is not counted again).  Static per
+        shape: a census, not a measurement.  0 where the dense path runs."""
+        if self.attn_impl != "flash":
+            return 0
+        visited = 0
+        for kind in layer_schedule(self.self_periods, self.cross_periods):
+            if kind in (WINDOW, FULL, CROSS):
+                window = self.window if kind == WINDOW else None
+                blk = _flash_block(window)
+                visited += self.n_heads * sum(
+                    c["visited"] for c in tile_census(
+                        seq_len, blk, blk, True, window).values())
+        return visited
 
     def __call__(self, ids, training: bool = False):
         """Logits over the rows held, (batch, L, vocab_rows), float32."""

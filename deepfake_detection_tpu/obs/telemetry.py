@@ -78,6 +78,12 @@ _COUNTER_CATALOG = (
     ("samples_total", "Training samples consumed"),
     ("train_tokens_total", "Tokens of the train steps dispatched (sequence "
      "models: rows x positions of each step's batch; 0 for image batches)"),
+    ("attn_tiles_visited_total", "Grid cells of the flash-attention kernels "
+     "(forward, dK/dV, dQ) with a visible pair, over the train steps "
+     "dispatched: rows x the model's per-row census (0 for image models). "
+     "Attention's work goes with the square of --seq-len and with the "
+     "kernels' blocks, train_tokens_total with neither: read the two rates "
+     "together when either changes between runs"),
     ("drains_total", "Metric drain boundaries (telemetry records)"),
     ("step_seconds_total", "Wall seconds spent in the train loop"),
     ("data_wait_seconds_total", "Seconds the loop blocked on next(loader)"),
@@ -186,9 +192,13 @@ class TrainTelemetry:
     def __init__(self, event_log: Optional[Any] = None,
                  flops_per_sample: float = 0.0,
                  peak_flops: float = 0.0,
-                 meta: Optional[Dict[str, Any]] = None):
+                 meta: Optional[Dict[str, Any]] = None,
+                 attn_tiles_per_sample: int = 0):
         self.event_log = event_log
         self.flops_per_sample = float(flops_per_sample)
+        # attention-kernel cells a step visits per row: a sequence model's
+        # attn_tiles_visited(seq_len); 0 for images
+        self.attn_tiles_per_sample = int(attn_tiles_per_sample)
         self.peak = float(peak_flops)
         self.meta = dict(meta or {})
         self.profiler = None          # optional obs.profiler.ProfilerCapture
@@ -268,6 +278,8 @@ class TrainTelemetry:
             self._c["steps_total"] += 1
             self._c["samples_total"] += n_samples
             self._c["train_tokens_total"] += tokens
+            self._c["attn_tiles_visited_total"] += \
+                n_samples * self.attn_tiles_per_sample
             self._c["step_seconds_total"] += step_wall_s
             self._c["data_wait_seconds_total"] += data_wait_s
 

@@ -27,8 +27,25 @@ All matmuls run on the MXU in float32 accumulation
 keys, causal) is computed from ``broadcasted_iota`` against dynamic global
 offsets held in SMEM, so the same kernels serve the standalone op (offsets
 0) and every step of ring attention (offsets = ring position, see
-``parallel/ring_attention.py``).  Fully-masked tiles are skipped with
-``pl.when``.
+``parallel/ring_attention.py``).
+
+**Cells by class.**  :func:`tile_visible` says, from a grid cell's indices
+alone, whether its tile holds any unmasked pair:
+
+* *outside* (none: above the causal diagonal, past ``seq_len``, outside the
+  window): not computed and not fetched.  Where the sequence offsets are
+  static (Python ints, as the standalone op passes) the causal index maps
+  clamp to the block's last (forward, dQ) or first (dK/dV) visible tile, so
+  consecutive outside cells repeat a block index and Pallas issues no copy.
+  Under ring attention the offsets are traced scalars that an index map
+  cannot read: the maps stay plain there and the cell is only skipped.
+* *visited* (the rest): the masked body, wholly visible tiles included (a
+  body without the mask for those measures 0.1% of a 16k-token train step
+  on a v5e: PERF.md section 6, PR 27).
+
+:func:`tile_census` counts the two over a shape's grids.  A ``scale`` that is
+a power of two (a 64-wide head's 0.125) is folded, exactly, into q before
+the kernels, which then multiply no score tile by it.
 
 ``window`` (causal sliding window: query ``t`` sees keys ``s`` with
 ``0 <= t - s < window``) does more than mask: the innermost grid dimension
@@ -52,14 +69,16 @@ from __future__ import annotations
 
 import functools
 import logging
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "tile_visible", "tile_census"]
 
 _logger = logging.getLogger(__name__)
 
@@ -128,17 +147,85 @@ def _tiles_in_window(block_a: int, block_b: int, window: int, n: int) -> int:
     return min(n, (block_a + window - 2) // block_b + 2)
 
 
+def _fdiv(x, c: int, b: int):
+    """floor((x + c) / b) for ``x >= 0`` (a grid index or a Python int) and
+    a static ``c`` of either sign, on a non-negative numerator so the
+    truncating integer division is the floor."""
+    w = max(0, -(c // b))
+    return (x + (c + w * b)) // b - w
+
+
 def _first_k_tile(iq, bq: int, bk: int, window: int):
     """First key tile the window of q block ``iq`` reaches:
-    max(0, floor((iq*bq - window + 1) / bk)), on a non-negative numerator
-    so the truncating integer division is the floor."""
-    w = -(-window // bk) + 1
-    return jnp.maximum((iq * bq + (w * bk - (window - 1))) // bk - w, 0)
+    max(0, floor((iq*bq - window + 1) / bk))."""
+    return jnp.maximum(_fdiv(iq * bq, 1 - window, bk), 0)
 
 
-def _first_q_tile(jk, bq: int, bk: int):
+def _last_k_tile(iq, bq: int, bk: int, seq_len: int, off: int = 0):
+    """Last key tile q block ``iq`` sees under the causal mask (``off`` =
+    q_off - kv_off, static): the one its last row falls in, kept to the
+    tiles that hold a valid key."""
+    return jnp.clip(_fdiv((iq + 1) * bq - 1, off, bk), 0,
+                    (seq_len - 1) // bk)
+
+
+def _first_q_tile(jk, bq: int, bk: int, off: int = 0):
     """First query tile that can see key block ``jk`` (causal)."""
-    return (jk * bk) // bq
+    return jnp.maximum(_fdiv(jk * bk, -off, bq), 0)
+
+
+def _last_q_tile(jk, bq: int, bk: int, window: int, seq_len: int, nq: int,
+                 off: int = 0):
+    """Last query tile whose window still holds key block ``jk``'s last
+    valid key, of the ``nq`` there are."""
+    last_key = jnp.minimum(jk * bk + bk - 1, seq_len - 1)
+    return jnp.clip(_fdiv(last_key + window - 1, -off, bq), 0, nq - 1)
+
+
+def _k_tile(iq, jk, bq: int, bk: int, window):
+    """The key tile cell ``(iq, jk)`` of a (bh, q block, k tile) grid reads:
+    under a window the grid's axis starts at the block's first tile."""
+    return jk if window is None else _first_k_tile(iq, bq, bk, window) + jk
+
+
+def _q_tile(jk, iq, bq: int, bk: int, window):
+    """The query tile cell ``(jk, iq)`` of the (bh, k block, q tile) grid
+    reads: under a window the axis starts at the block's first tile (a
+    window comes with offsets of 0: the standalone op's)."""
+    return iq if window is None else _first_q_tile(jk, bq, bk) + iq
+
+
+def tile_visible(i, j, block_q: int, block_k: int, seq_len: int,
+                 causal: bool = False, window: Optional[int] = None,
+                 q_off=0, kv_off=0, q_tiles: Optional[int] = None):
+    """Does the cell that pairs query tile ``i`` with key tile ``j`` hold
+    at least one unmasked (query, key) pair?  (Else the cell is **outside**.)
+    A pair (t, s) of global positions ``t = q_off + row``, ``s = kv_off +
+    key`` is unmasked iff the key is valid (``key < seq_len``) and, if
+    ``causal``, ``s <= t`` and, under a ``window``, ``t - s < window``; rows
+    count to the end of their block, padding included, as the kernels
+    compute them.  ``q_tiles``: query tiles past it do not exist (a windowed
+    dK/dV grid can name them).
+
+    Comparisons and ``&`` only, so ``i``, ``j`` and the offsets may be
+    Python ints, numpy arrays (the census, the tests) or the kernels'
+    traced scalars; the kernels' predicates, the clamped index maps' tests
+    and :func:`tile_census` all read this one function."""
+    c0 = j * block_k
+    visible = c0 < seq_len
+    if q_tiles is not None:
+        visible = visible & (i < q_tiles)
+    if causal:
+        r0 = q_off + i * block_q               # the block's first, last row
+        r1 = r0 + block_q - 1
+        k0 = kv_off + c0                       # the tile's first, last key
+        k1 = k0 + block_k - 1
+        visible = visible & (k0 <= r1)
+        if window is not None:
+            # the first row must still hold the tile's last VALID key
+            visible = visible & (r0 - k1 < window) \
+                & (r0 - (kv_off + seq_len - 1) < window)
+    return visible
 
 
 def _dots(dot_dtype):
@@ -147,6 +234,27 @@ def _dots(dot_dtype):
     if dot_dtype is None:
         return jnp.float32, True
     return dot_dtype, False
+
+
+def _scaled(x, scale: float):
+    """``x * scale``; nothing at all for the 1.0 the op passes once it has
+    folded a power-of-two scale into q (:func:`flash_attention`)."""
+    return x if scale == 1.0 else x * scale
+
+
+def _invalid(iq, jt, bq: int, bk: int, seq_len: int, causal: bool, window,
+             q_off, kv_off):
+    """The (bq, bk) mask of a tile: True where the pair is masked."""
+    k_loc = jt * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    invalid = k_loc >= seq_len
+    if causal:
+        q_pos = q_off + iq * bq + jax.lax.broadcasted_iota(
+            jnp.int32, (bq, bk), 0)
+        invalid = jnp.logical_or(invalid, kv_off + k_loc > q_pos)
+        if window is not None:
+            invalid = jnp.logical_or(
+                invalid, q_pos - (kv_off + k_loc) >= window)
+    return invalid
 
 
 # ---------------------------------------------------------------------------
@@ -178,18 +286,13 @@ def _fwd_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
 
     cd, scale_q = _dots(dot_dtype)
-    jt = jk                                    # the key tile this cell reads
-    if window is not None:
-        jt = _first_k_tile(iq, bq, bk, window) + jk
-    relevant = jt * bk < seq_len               # tile has ≥1 un-padded key
-    if causal:
-        last_q = q_off + (iq + 1) * bq - 1
-        relevant = jnp.logical_and(relevant, kv_off + jt * bk <= last_q)
+    jt = _k_tile(iq, jk, bq, bk, window)       # the key tile this cell reads
 
-    @pl.when(relevant)
+    @pl.when(tile_visible(iq, jt, bq, bk, seq_len, causal, window, q_off,
+                          kv_off))
     def _accumulate():
         if scale_q:
-            q = q_ref[0].astype(jnp.float32) * scale
+            q = _scaled(q_ref[0].astype(jnp.float32), scale)
         else:
             q = q_ref[0].astype(cd)
         k = k_ref[0].astype(cd)
@@ -197,16 +300,9 @@ def _fwd_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if not scale_q:
-            s = s * scale
-        k_loc = jt * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        invalid = k_loc >= seq_len
-        if causal:
-            q_pos = q_off + iq * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0)
-            invalid = jnp.logical_or(invalid, kv_off + k_loc > q_pos)
-            if window is not None:
-                invalid = jnp.logical_or(
-                    invalid, q_pos - (kv_off + k_loc) >= window)
+            s = _scaled(s, scale)
+        invalid = _invalid(iq, jt, bq, bk, seq_len, causal, window, q_off,
+                           kv_off)
         s = jnp.where(invalid, _NEG_INF, s)
 
         m_prev = m_ref[:, :1]                                  # (BQ, 1)
@@ -237,6 +333,14 @@ def _fwd_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                                       lse_ref.shape[1:])
 
 
+def _static_off(q_off, kv_off) -> Optional[int]:
+    """q_off - kv_off where an index map may use it: both plain ints (the
+    standalone op's 0s), not ring attention's traced positions."""
+    if isinstance(q_off, int) and isinstance(kv_off, int):
+        return q_off - kv_off
+    return None
+
+
 def _kv_map(bh_q: int, bh_kv: int, tile=None):
     """Index map of a key or value operand on a (bh, q block, k tile) grid:
     query head ``b`` reads head ``b // group``; ``tile(i, j)`` names the
@@ -248,14 +352,45 @@ def _kv_map(bh_q: int, bh_kv: int, tile=None):
     return lambda b, i, j: (b // g, tile(i, j), 0)
 
 
-def _k_tile_map(block_q, block_k, window, nk):
+def _k_tile_map(block_q, block_k, window, nk, causal=False, seq_len=None,
+                off=None):
     """(k tiles on the grid, tile(i, j)) of the (bh, q block, k tile) grids:
-    all of them, or under a window only those the block's window touches,
-    clamped into range (the kernel skips what the clamp repeats)."""
+    all of them, or under a window only those the block's window touches.
+    An outside cell repeats the block of its row's nearest visited cell, so
+    nothing is copied for it: with a static ``off`` the causal cells past
+    the block's last visible tile name that tile; otherwise a window's are
+    only kept in range (the kernel skips what a clamp repeats)."""
+    nkt = nk if window is None else _tiles_in_window(block_q, block_k,
+                                                     window, nk)
+    clamp = causal and off is not None
+    if window is None and not clamp:
+        return nkt, None
+
+    def tile(i, j):
+        last = _last_k_tile(i, block_q, block_k, seq_len, off) if clamp \
+            else nk - 1
+        return jnp.minimum(_k_tile(i, j, block_q, block_k, window), last)
+    return nkt, tile
+
+
+def _q_tile_map(block_q, block_k, window, nq, causal=False, seq_len=None,
+                off=None):
+    """(q tiles on the grid, tile(j, i)) of the (bh, k block, q tile) grid,
+    as :func:`_k_tile_map`: the causal cells before the block's first
+    visible tile name that tile, a window's cells past its last name the
+    last."""
+    clamp = causal and off is not None
     if window is None:
-        return nk, None
-    return _tiles_in_window(block_q, block_k, window, nk), lambda i, j: \
-        jnp.minimum(_first_k_tile(i, block_q, block_k, window) + j, nk - 1)
+        if not clamp:
+            return nq, None
+        return nq, lambda j, i: jnp.maximum(i, jnp.minimum(
+            _first_q_tile(j, block_q, block_k, off), nq - 1))
+
+    def tile(j, i):
+        last = _last_q_tile(j, block_q, block_k, window, seq_len, nq, off) \
+            if clamp else nq - 1
+        return jnp.minimum(_q_tile(j, i, block_q, block_k, window), last)
+    return _tiles_in_window(block_k, block_q, window, nq), tile
 
 
 def _kernel_kwargs(window, dot_dtype):
@@ -274,7 +409,8 @@ def _fwd(q, k, v, scale, block_q, block_k, causal, seq_len, interpret,
     (out (BH, Lq, Dv), lse)."""
     bh, lpq, d = q.shape
     lpk, dv = k.shape[1], v.shape[2]
-    nkt, tile = _k_tile_map(block_q, block_k, window, lpk // block_k)
+    nkt, tile = _k_tile_map(block_q, block_k, window, lpk // block_k, causal,
+                            seq_len, _static_off(q_off, kv_off))
     grid = (bh, lpq // block_q, nkt)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, seq_len=seq_len,
@@ -328,21 +464,11 @@ def _bwd_dkv_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     cd, scale_q = _dots(dot_dtype)
-    it = iq                                  # the query tile this cell reads
-    relevant = jk * bk < seq_len
-    if window is not None:
-        it = _first_q_tile(jk, bq, bk) + iq
-        # past the last query tile the clamped map repeats it; and the
-        # tile's first row must still be inside the block's last window
-        relevant = jnp.logical_and(relevant, it < q_tiles)
-        relevant = jnp.logical_and(
-            relevant, it * bq - (jk * bk + bk - 1) < window)
-    if causal:
-        # this q tile's last global row must reach the k block's first row
-        last_q = q_off + (it + 1) * bq - 1
-        relevant = jnp.logical_and(relevant, kv_off + jk * bk <= last_q)
+    it = _q_tile(jk, iq, bq, bk, window)     # the query tile this cell reads
 
-    @pl.when(relevant)
+    # window: past the last query tile the clamped map repeats it
+    @pl.when(tile_visible(it, jk, bq, bk, seq_len, causal, window, q_off,
+                          kv_off, q_tiles))
     def _accumulate():
         k = k_ref[0].astype(cd)
         v = v_ref[0].astype(cd)
@@ -350,27 +476,20 @@ def _bwd_dkv_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
         do = do_ref[0].astype(cd)
         lse = lse_ref[0, :, :1]                                 # (BQ, 1)
         delta = delta_ref[0, :, :1]
-        s = jax.lax.dot_general(q * scale if scale_q else q, k,
+        s = jax.lax.dot_general(_scaled(q, scale) if scale_q else q, k,
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if not scale_q:
-            s = s * scale
-        k_loc = jk * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        invalid = k_loc >= seq_len
-        if causal:
-            q_pos = q_off + it * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0)
-            invalid = jnp.logical_or(invalid, kv_off + k_loc > q_pos)
-            if window is not None:
-                invalid = jnp.logical_or(
-                    invalid, q_pos - (kv_off + k_loc) >= window)
+            s = _scaled(s, scale)
+        invalid = _invalid(it, jk, bq, bk, seq_len, causal, window, q_off,
+                           kv_off)
         p = jnp.where(invalid, 0.0, jnp.exp(s - lse))           # (BQ, BK)
         dv_acc[:] += jax.lax.dot_general(
             p.astype(cd), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
+        ds = _scaled(p * (dp - delta), scale)
         dk_acc[:] += jax.lax.dot_general(
             ds.astype(cd), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -398,15 +517,10 @@ def _bwd_dq_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     cd, scale_q = _dots(dot_dtype)
-    jt = jk
-    if window is not None:
-        jt = _first_k_tile(iq, bq, bk, window) + jk
-    relevant = jt * bk < seq_len
-    if causal:
-        last_q = q_off + (iq + 1) * bq - 1
-        relevant = jnp.logical_and(relevant, kv_off + jt * bk <= last_q)
+    jt = _k_tile(iq, jk, bq, bk, window)
 
-    @pl.when(relevant)
+    @pl.when(tile_visible(iq, jt, bq, bk, seq_len, causal, window, q_off,
+                          kv_off))
     def _accumulate():
         q = q_ref[0].astype(cd)
         k = k_ref[0].astype(cd)
@@ -414,24 +528,17 @@ def _bwd_dq_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
         do = do_ref[0].astype(cd)
         lse = lse_ref[0, :, :1]                                 # (BQ, 1)
         delta = delta_ref[0, :, :1]
-        s = jax.lax.dot_general(q * scale if scale_q else q, k,
+        s = jax.lax.dot_general(_scaled(q, scale) if scale_q else q, k,
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if not scale_q:
-            s = s * scale
-        k_loc = jt * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        invalid = k_loc >= seq_len
-        if causal:
-            q_pos = q_off + iq * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0)
-            invalid = jnp.logical_or(invalid, kv_off + k_loc > q_pos)
-            if window is not None:
-                invalid = jnp.logical_or(
-                    invalid, q_pos - (kv_off + k_loc) >= window)
+            s = _scaled(s, scale)
+        invalid = _invalid(iq, jt, bq, bk, seq_len, causal, window, q_off,
+                           kv_off)
         p = jnp.where(invalid, 0.0, jnp.exp(s - lse))
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
+        ds = _scaled(p * (dp - delta), scale)
         dq_acc[:] += jax.lax.dot_general(
             ds.astype(cd), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -451,13 +558,12 @@ def _bwd_dkv(q, k, v, do, lse, delta, scale, block_q, block_k, causal,
     lpk, dv = k.shape[1], v.shape[2]
     nq = lpq // block_q
     kw = _kernel_kwargs(window, dot_dtype)
-    if window is None:
-        nqt, q_map = nq, lambda b, j, i: (b, i, 0)
-    else:
-        nqt = _tiles_in_window(block_k, block_q, window, nq)
+    if window is not None:
         kw["q_tiles"] = nq
-        q_map = lambda b, j, i: (b, jnp.minimum(               # noqa: E731
-            _first_q_tile(j, block_q, block_k) + i, nq - 1), 0)
+    nqt, tile = _q_tile_map(block_q, block_k, window, nq, causal, seq_len,
+                            _static_off(q_off, kv_off))
+    q_map = (lambda b, j, i: (b, i, 0)) if tile is None else \
+        (lambda b, j, i: (b, tile(j, i), 0))
     gk, gv = bh // k.shape[0], bh // v.shape[0]
     k_map = (lambda b, j, i: (b, j, 0)) if gk == 1 else \
         (lambda b, j, i: (b // gk, j, 0))
@@ -500,7 +606,8 @@ def _bwd_dq(q, k, v, do, lse, delta, scale, block_q, block_k, causal,
     """dQ for this Q shard against one KV buffer, streaming K tiles."""
     bh, lpq, d = q.shape
     lpk, dv = k.shape[1], v.shape[2]
-    nkt, tile = _k_tile_map(block_q, block_k, window, lpk // block_k)
+    nkt, tile = _k_tile_map(block_q, block_k, window, lpk // block_k, causal,
+                            seq_len, _static_off(q_off, kv_off))
     kern = functools.partial(_bwd_dq_kernel, scale=scale, seq_len=seq_len,
                              causal=causal,
                              **_kernel_kwargs(window, dot_dtype))
@@ -555,6 +662,40 @@ def _bwd(scale, block_q, block_k, causal, interpret, seq_len, res, g,
 # public op
 # ---------------------------------------------------------------------------
 
+def _blocks(l: int, block_q: int, block_k: int):
+    """(block_q, block_k, padded Lq, padded Lk) the op runs a length at."""
+    block_q = min(block_q, _round_up(l, 128))
+    block_k = min(block_k, _round_up(l, 128))
+    return block_q, block_k, _round_up(l, block_q), _round_up(l, block_k)
+
+
+def tile_census(l: int, block_q: int = 128, block_k: int = 128,
+                causal: bool = False, window: Optional[int] = None) -> dict:
+    """How much of each grid is work at a shape: per head, for each kernel
+    of :func:`flash_attention` at these arguments, its grid's ``cells`` and
+    of them ``outside`` / ``visited`` (:func:`tile_visible` over the same
+    grid-to-tile functions the kernels use).  Static per compiled shape, so
+    a count and not a measurement: ``fwd`` and ``dq`` share a grid, ``dkv``
+    has the transposed one."""
+    bq, bk, lpq, lpk = _blocks(l, block_q, block_k)
+    nq, nk = lpq // bq, lpk // bk
+
+    def count(visible):
+        visited = int(np.asarray(visible).sum())
+        return {"cells": visible.size, "outside": visible.size - visited,
+                "visited": visited}
+
+    nkt, _ = _k_tile_map(bq, bk, window, nk)
+    i, j = np.meshgrid(np.arange(nq), np.arange(nkt), indexing="ij")
+    qk = count(tile_visible(i, np.asarray(_k_tile(i, j, bq, bk, window)),
+                            bq, bk, l, causal, window))
+    nqt, _ = _q_tile_map(bq, bk, window, nq)
+    j, i = np.meshgrid(np.arange(nk), np.arange(nqt), indexing="ij")
+    dkv = count(tile_visible(np.asarray(_q_tile(j, i, bq, bk, window)), j,
+                             bq, bk, l, causal, window, q_tiles=nq))
+    return {"fwd": qk, "dkv": dkv, "dq": dict(qk)}
+
+
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = False, scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
@@ -590,10 +731,13 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         "window needs causal=True and a positive size"
     interpret = resolve_interpret(interpret, "flash_attention")
     scale = scale if scale is not None else d ** -0.5
-    block_q = min(block_q, _round_up(l, 128))
-    block_k = min(block_k, _round_up(l, 128))
-    lpq = _round_up(l, block_q)
-    lpk = _round_up(l, block_k)
+    if math.frexp(scale)[0] == 0.5:
+        # a power of two commutes with every rounding on the way (the cast
+        # to the operands' dtype, the float32 accumulation, dQ's cast), so
+        # it goes into q once, here, and out of every score tile; dQ gets
+        # its factor back through this product's own gradient
+        q, scale = q * scale, 1.0
+    block_q, block_k, lpq, lpk = _blocks(l, block_q, block_k)
     kw = _kernel_kwargs(window, dot_dtype)
 
     def prep(x, lp):  # (B, L, H, D) -> (B*H, lp, Dp)

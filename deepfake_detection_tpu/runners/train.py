@@ -583,6 +583,8 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
             if output_dir and rank == 0 else None
         telemetry = TrainTelemetry(
             event_log=event_log, flops_per_sample=fwd_flops,
+            attn_tiles_per_sample=model.attn_tiles_visited(cfg.seq_len)
+            if sequence_task else 0,
             # throughput is measured on the GLOBAL batch (the loader
             # assembles the global sharded array), so the MFU denominator
             # is the whole MESH's peak — n_dev == mesh.size, which a

@@ -349,6 +349,37 @@ def test_telemetry_counts_the_tokens_of_the_steps_dispatched():
     assert "dfd_train_train_tokens_total" in t.render_prometheus()
 
 
+def test_telemetry_counts_the_attention_tiles_by_the_models_census():
+    """attn_tiles_visited_total advances by rows x the sequence model's
+    census each step, and stays 0 for an image model."""
+    from deepfake_detection_tpu.models import create_model
+    from deepfake_detection_tpu.obs import TrainTelemetry
+    from deepfake_detection_tpu.ops.flash_attention import tile_census
+    model = create_model("phi4_mini_flash_tiny")
+    # 2,100 tokens at the layers' own blocks.  Under the window of 16, blocks
+    # of 512: 5 q blocks by 3 tiles, each block's own tile and (but for the
+    # first block) the one before it visited.  The full and the cross layer,
+    # blocks of 1024: 3 by 3, the diagonal and the 3 tiles below it visited
+    window = tile_census(2100, 512, 512, True, 16)["fwd"]
+    assert (window["cells"], window["visited"]) == (15, 9)
+    full = tile_census(2100, 1024, 1024, True)["dkv"]
+    assert (full["cells"], full["visited"]) == (9, 6)
+    # 4 query heads, 3 kernels, one window, one full and one cross layer
+    visited = model.attn_tiles_visited(2100)
+    assert visited == 4 * 3 * (9 + 6 + 6)
+    assert model.attn_tiles_visited(300) == 4 * 3 * 3    # one tile a layer
+    assert model.clone(attn_impl="full").attn_tiles_visited(2100) == 0
+    t = TrainTelemetry(attn_tiles_per_sample=visited)
+    for _ in range(3):
+        t.on_step(2, 0.0, 0.1, tokens=2 * 2100)
+    assert t.snapshot()["counters"]["attn_tiles_visited_total"] == \
+        3 * 2 * visited
+    assert "dfd_train_attn_tiles_visited_total" in t.render_prometheus()
+    image = TrainTelemetry()
+    image.on_step(3, 0.0, 0.1)
+    assert image.snapshot()["counters"]["attn_tiles_visited_total"] == 0
+
+
 # ---- the normal runner ------------------------------------------------------
 
 def _run(out, epochs, *extra):

@@ -116,13 +116,16 @@ def test_flash_attention_vit_b16_compiles(one_chip, grad):
         _compile(attn, qkv, qkv, qkv)
 
 
-@pytest.mark.parametrize("window,block", [(512, 256), (None, 512)],
-                         ids=["window", "full"])
+@pytest.mark.parametrize("window,block", [
+    (512, 256), (None, 512), (512, 512), (None, 1024)],
+    ids=["window-256", "full-512", "window", "full"])
 def test_flash_attention_phi4flash_16k_compiles(one_chip, window, block):
     """Phi-4-mini-flash at 16,384 tokens, forward and both backward kernels:
     40 query heads and 20 key heads of 64 reading 10 value pairs of 128
-    through the index maps, bf16 operands, the window layer's shrunk grid
-    and the full layer's causal one, at the model's own block sizes."""
+    through the clamped index maps, bf16 operands, the window layer's shrunk
+    grid and the full layer's causal one, at the model's own block sizes
+    (512 under the window, 1024 without: a 4 MB float32 score tile) and at
+    the ones it had before PR 27."""
     spec = lambda h, d: jax.ShapeDtypeStruct(               # noqa: E731
         (1, 16384, h, d), jnp.bfloat16, sharding=one_chip)
     _compile(jax.grad(lambda q, k, v: flash_attention(
