@@ -1,8 +1,8 @@
 """Vision Transformer (Flax/NHWC, TPU-native).
 
 The reference has no transformer backbone (SURVEY.md §5: the temporal dim is
-channel-concat); ViT-B/16 and ViT-L/16 are the BASELINE.json stretch configs
-("stress the XLA attention path") and the customer for the sequence-parallel
+channel-concat); ViT-B/16 and ViT-L/16 are here to stress the attention
+path, and are the customer for the sequence-parallel
 machinery in ``parallel/ring_attention.py``.
 
 TPU notes:
@@ -306,7 +306,7 @@ def _register():
         fn.__name__ = name
         fn.__qualname__ = name
         fn.__module__ = __name__
-        fn.__doc__ = f"{name} (BASELINE.json stretch config)."
+        fn.__doc__ = f"{name} (attention-path stretch model)."
         register_model(fn)
 
 

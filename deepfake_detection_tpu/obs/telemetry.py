@@ -26,10 +26,10 @@ seconds jax spent tracing, lowering and in the backend's compiler): one
 whatever is built before a registry exists is still on its books.
 
 Throughput (img/s over the drain window) times the per-sample forward
-FLOP count from ``tools/flops_breakdown.py`` (× 3 for fwd+bwd, the
-standard training approximation) against the device's peak rate to give a
-**live MFU gauge** — the in-run counterpart of bench.py's offline MFU row
-and of the PERF.md §6 accept/revert criterion.
+FLOP count of ``obs/flops.py`` (× 3 for fwd+bwd, the standard training
+approximation) against the device's peak rate gives a **live MFU gauge**.
+The benchmark's ``step_mfu.train`` (``benchmark/``, PERF.md §2) is the
+measured number; this one is the run's own dial.
 
 Rendering goes through the shared :mod:`..utils.prometheus` text renderer
 (the serving subsystem's ``GET /metrics`` sibling); obs/server.py exposes
@@ -64,8 +64,7 @@ _PREFIX = "dfd_train"
 _STEP_BOUNDS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
                 1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
 
-# bf16 peak per chip by device_kind (bench.py's table; the MFU gauge and
-# the offline bench rows must agree on the denominator)
+# bf16 peak per chip by device_kind: the program's one peak table
 _PEAK_FLOPS = {
     "TPU v2": 22.5e12, "TPU v3": 61.5e12 / 2, "TPU v4": 137.5e12 * 2,
     "TPU v5 lite": 197e12, "TPU v5e": 197e12, "TPU v5": 229.5e12 * 2,
@@ -155,6 +154,14 @@ def _compile_collector() -> Dict[str, Dict[str, float]]:
     with _compile_lock:
         return {"counters": dict(_compile_totals)}
 
+
+def backend_compile_count() -> int:
+    """Programs the backend built in this process since this module was
+    imported: the serving side's zero-recompile probes read differences of
+    it (``serving/metrics.py`` re-exports it)."""
+    with _compile_lock:
+        return int(_compile_totals["compiles_total"])
+
 _GAUGE_CATALOG = (
     ("up", "1 while the trainer's telemetry is live"),
     ("epoch", "Current epoch"),
@@ -173,7 +180,7 @@ _GAUGE_CATALOG = (
     ("mfu", "Live model FLOPs utilization (0 when peak rate unknown, "
      "e.g. CPU)"),
     ("model_fwd_gflops_per_sample", "Per-sample forward GFLOPs feeding "
-     "the MFU gauge (tools/flops_breakdown.py)"),
+     "the MFU gauge (obs/flops.py)"),
     ("restart_count", "Restart-wrapper relaunches of this run "
      "(DFD_RESTART_COUNT)"),
     ("watchdog_beat_age_s", "Seconds since the last watchdog heartbeat"),
@@ -403,34 +410,26 @@ def peak_flops(device=None) -> float:
 
 
 def forward_flops_per_sample(model, variables, input_shape) -> float:
-    """Per-sample forward FLOPs via tools/flops_breakdown.py's jaxpr walk.
+    """Per-sample forward FLOPs via the jaxpr walk of :mod:`.flops`.
 
     ``input_shape`` is the (1, H, W, C) shape the LOADER feeds the model
-    (already pixel-shuffled under ``--stem-s2d``).  Returns 0.0 when the
-    tools/ directory is not present (installed-package layout) or the walk
-    fails — the MFU gauge then stays 0 instead of lying.
+    (already pixel-shuffled under ``--stem-s2d``); ``variables`` may be
+    abstract.  Returns 0.0 when the walk fails — the MFU gauge then stays
+    0 instead of lying.
     """
-    import importlib.util
-    tools_dir = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))), "tools")
-    path = os.path.join(tools_dir, "flops_breakdown.py")
-    if not os.path.isfile(path):
-        return 0.0
+    import jax
+    import jax.numpy as jnp
+
+    from .flops import analyze
+    x = jax.ShapeDtypeStruct(tuple(input_shape), jnp.float32)
     try:
-        import jax.numpy as jnp
-        spec = importlib.util.spec_from_file_location(
-            "_dfd_flops_breakdown", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        x = jnp.zeros(tuple(input_shape), jnp.float32)
-        buckets, _, _ = mod.analyze(model, variables, x,
-                                    in_chans=int(input_shape[-1]))
-        return float(sum(buckets.values()))
+        buckets, _, _ = analyze(model, variables, x,
+                                in_chans=int(input_shape[-1]))
     except Exception as e:              # noqa: BLE001 — telemetry is optional
         _logger.warning("forward-FLOPs analysis failed (%r); "
                         "MFU gauge disabled", e)
         return 0.0
+    return float(sum(buckets.values()))
 
 
 # ---------------------------------------------------------------------------

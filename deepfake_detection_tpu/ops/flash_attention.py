@@ -2,7 +2,7 @@
 
 The reference framework has no attention op at all (its temporal axis is a
 channel concat, SURVEY.md §2.7); attention enters this framework through the
-ViT stretch configs (BASELINE.json) and the sequence-parallel machinery in
+ViT families, the sequence models and the sequence-parallel machinery in
 ``parallel/ring_attention.py``.  XLA's dense softmax-attention materialises
 the (L, L) score matrix in HBM — O(L²) memory traffic, which caps sequence
 length and wastes HBM bandwidth (the usual TPU bottleneck).  This module

@@ -84,7 +84,7 @@ def max_pool2d_torch(x, window: Tuple[int, int], strides: Tuple[int, int],
         # NEGATIVE required pad (reachable when stride > kernel interacts
         # with the decrement rule) cannot be expressed as padding — clamp
         # to 0 and slice the surplus trailing window(s) off below instead
-        # of silently emitting one extra window (ADVICE.md)
+        # of silently emitting one extra window
         pads.append((padding, max(0, (out - 1) * s + k - dim - padding)))
     y = nn.max_pool(x, window, strides=strides, padding=pads)
     # both grids start windows at i*s - padding, so torch's output is

@@ -3,7 +3,7 @@
 The reference has no sequence models — its "temporal" axis is 4 frames
 channel-concatenated (SURVEY.md §5) — but long-context attention is a
 first-class requirement for the TPU framework (it backs the ViT/TimeSformer
-stretch configs in BASELINE.json).  Two standard schemes, both expressed over
+families).  Two standard schemes, both expressed over
 a mesh axis with XLA collectives riding ICI:
 
 * **Ring attention** (Liu et al. 2023, blockwise; PAPERS.md): each device

@@ -8,7 +8,10 @@ Re-design of ``/root/reference/dfd/runners/train.py`` (819 LoC) for TPU:
   per *host* drives all local devices through the mesh, and
   ``jax.distributed.initialize`` handles multi-host (parallel/mesh.py).
 * ``main`` (:256-592) — model/optimizer/scheduler/dataset construction,
-  resume, epoch loop — maps to :func:`main`.
+  resume, epoch loop — maps to :func:`build_program`, :func:`init_state`,
+  :func:`build_loaders`, :func:`build_steps`, :func:`build_telemetry` (each
+  callable alone: what a tool or the benchmark builds, it builds through
+  these) and :func:`main`, which is those calls and the loop.
 * apex AMP O1 (:353) → bfloat16 compute policy (``--compute-dtype``), no
   loss scaling needed on TPU.
 * apex DDP (:402) → the jitted train step over the mesh (train/steps.py).
@@ -30,6 +33,7 @@ Usage::
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 import os
 import sys
@@ -50,16 +54,16 @@ from ..models import (create_deepfake_model, create_deepfake_model_v3,
 from ..optim import create_optimizer
 from ..parallel import (batch_sharding, data_axis_name,
                         initialize_distributed, make_mesh, make_train_mesh,
-                        place_train_state, replicated_sharding,
-                        train_state_shardings, transformer_tp_sharding)
+                        own_and_place, place_train_state,
+                        replicated_sharding, train_state_shardings,
+                        transformer_tp_sharding)
 from ..scheduler import create_scheduler
-from ..train import (EXIT_PREEMPTED, CheckpointCorrupt, CheckpointSaver,
-                     Preempted, Resilience, RewindRequested,
-                     ShardedCheckpointSaver, create_train_state,
-                     find_resume_candidates, make_eval_step,
-                     make_train_step, replicate_for_save, restore_resharded,
-                     set_learning_rate, train_one_epoch, validate,
-                     wait_pending_saves)
+from ..train import (EXIT_PREEMPTED, CheckpointSaver, Preempted, Resilience,
+                     RewindRequested, ShardedCheckpointSaver,
+                     create_train_state, make_eval_step, make_train_step,
+                     replicate_for_save, restore_any, restore_with_fallback,
+                     resume_position, set_learning_rate, train_one_epoch,
+                     validate, wait_pending_saves)
 from ..utils import get_outdir, setup_default_logging, update_summary
 from ..utils.compile_cache import setup_compile_cache
 
@@ -185,33 +189,48 @@ def build_datasets(cfg: TrainConfig, input_size, pack_dir=None,
     raise ValueError(f"unknown dataset {cfg.dataset!r}")
 
 
-def main(cfg: TrainConfig) -> Dict[str, float]:
-    """Train to completion; returns the best eval metrics."""
-    # restarted runs skip the XLA compile wall — before the first compile
-    setup_compile_cache(cfg.compile_cache_dir)
-    rank = jax.process_index()
+@dataclasses.dataclass
+class Program:
+    """What a :class:`TrainConfig` determines before any array exists or a
+    file is touched; :func:`init_state` and the ``build_*`` functions take
+    it."""
+    cfg: TrainConfig
+    mesh: Any
+    n_dev: int
+    batch_axis: str
+    dp: int                     # data-parallel degree (a tp group is ONE)
+    data_config: Dict[str, Any]
+    input_size: Tuple[int, ...]
+    model: Any
+    sequence_task: bool         # the model takes (batch, L) ids
+    lr: float
+    tx: Any
+    lr_scheduler: Any
+    num_epochs: int
+    loss_fn: Any
+    global_batch: int           # rows per optimizer step, all processes
+    bn_mode: str
+
+
+def _choose_mesh(cfg: TrainConfig):
     if cfg.tp_size > 1:
         if cfg.mesh_shape is not None or cfg.fsdp:
             raise ValueError(
                 "--tp-size conflicts with an explicit --mesh-shape/--fsdp; "
                 "configure one parallelism layout at a time")
         # dp×tp on the unified mesh; parameter shardings applied after
-        # init below (transformer_tp_sharding names the 'model' axis)
-        mesh = make_train_mesh(batch=-1, model=cfg.tp_size)
-    elif cfg.mesh_shape is not None or tuple(cfg.mesh_axes) != ("data",):
+        # init (transformer_tp_sharding names the 'model' axis)
+        return make_train_mesh(batch=-1, model=cfg.tp_size)
+    if cfg.mesh_shape is not None or tuple(cfg.mesh_axes) != ("data",):
         # explicit legacy layout: honored verbatim (tests / sp meshes)
-        mesh = make_mesh(cfg.mesh_shape, cfg.mesh_axes)
-    else:
-        # the default: ONE 2-D ('batch', 'model') mesh — the same program
-        # compiles for 1 chip and a pod (ISSUE 12)
-        mesh = make_train_mesh()
-    n_dev = int(mesh.size)
-    batch_axis = data_axis_name(mesh)
-    # the data-parallel degree: batch and linear-LR scaling follow it, not
-    # the raw device count (a tp group is ONE model replica)
-    dp_size = int(mesh.shape.get(batch_axis, n_dev))
-    _logger.info("Training with %d devices, mesh %s, process %d/%d",
-                 n_dev, dict(mesh.shape), rank, jax.process_count())
+        return make_mesh(cfg.mesh_shape, cfg.mesh_axes)
+    # the default: ONE 2-D ('batch', 'model') mesh — the same program
+    # compiles for 1 chip and a pod (ISSUE 12)
+    return make_train_mesh()
+
+
+def _validate(cfg: TrainConfig, n_dev: int, dp_size: int) -> None:
+    """Flag combinations this mesh or backend cannot run."""
     if (cfg.fused_depthwise == "pallas" or cfg.attn_impl == "flash") and \
             jax.default_backend() != "tpu" and \
             "cpu" not in (jax.config.jax_platforms or ""):
@@ -248,90 +267,107 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
             "(dp=1); an interleaved per-device batch layout is needed "
             "for dp>1 and is not implemented")
 
-    # ONE seed for every host: params are logically replicated, so init must
-    # be identical everywhere (the reference's per-rank seed, train.py:299,
-    # was safe only because DDP broadcast rank-0's weights; SPMD has no such
-    # broadcast).  The unified step draws dropout noise over the GLOBAL
-    # batch from one mesh-replicated key (the key is pinned replicated
-    # before the loop below) — do NOT re-add a per-device fold; it would
-    # break the replicated-key in_shardings contract.
-    rng = jax.random.PRNGKey(cfg.seed)
-    data_config = resolve_data_config(cfg.to_dict(), verbose=rank == 0)
-    input_size = data_config["input_size"]
-    in_chans = input_size[0]
-    img_num = max(1, in_chans // 3)
 
-    model = build_model(cfg, in_chans)
-    # a model of the sequence task takes (batch, L) ids; no parameter's
-    # shape depends on L, so a short row initializes it
-    sequence_task = bool(getattr(model, "sequence_task", False))
-    init_rng, rng = jax.random.split(rng)
-    variables = init_model(model, init_rng, (1, 8), training=True,
-                           dtype=jnp.int32) if sequence_task else \
-        init_model(model, init_rng,
-                   (1, input_size[1], input_size[2], in_chans),
-                   training=True)
-    n_params = sum(x.size for x in jax.tree.leaves(variables["params"]))
-    _logger.info("Model %s created, param count: %d", cfg.model, n_params)
+def build_program(cfg: TrainConfig, mesh=None) -> Program:
+    """Everything of the program that needs no arrays and no file system.
 
-    # per-sample forward FLOPs for the live MFU gauge (obs/telemetry.py):
-    # an abstract jaxpr walk, so it must run while ``variables`` is alive
-    # (create_train_state donates the buffers).  The shape is what the
-    # LOADER feeds the model — pixel-shuffled under --stem-s2d.
-    fwd_flops = 0.0
-    if not cfg.no_telemetry and not sequence_task:
-        from ..obs import forward_flops_per_sample
-        flop_shape = (1, input_size[1] // 2, input_size[2] // 2,
-                      4 * in_chans) if cfg.stem_s2d else \
-            (1, input_size[1], input_size[2], in_chans)
-        fwd_flops = forward_flops_per_sample(model, variables, flop_shape)
-
-    if cfg.initial_checkpoint:
-        # pretrained weights into the fresh tree (reference train.py:316 /
-        # helpers.py:31-44): non-strict — head/in_chans mismatches drop,
-        # but loudly, and a checkpoint matching NOTHING is an error (a
-        # silent from-scratch "fine-tune" is worse than failing)
-        from ..models.helpers import (_flatten, expand_split_bn,
-                                      filter_shape_mismatch,
-                                      load_state_dict)
-        loaded = load_state_dict(cfg.initial_checkpoint)
-        if cfg.split_bn:
-            # plain-BN checkpoints fan out into main + aux BNs, like the
-            # reference's load-then-convert order (split_batchnorm.py:41)
-            loaded = expand_split_bn(loaded, variables)
-        n_init = len(_flatten(variables))
-        n_hit = len(set(_flatten(variables)) & set(_flatten(loaded)))
-        variables, dropped = filter_shape_mismatch(variables, loaded)
-        applied = n_hit - dropped
-        if applied == 0:
-            raise ValueError(
-                f"--initial-checkpoint {cfg.initial_checkpoint} matches no "
-                f"parameter of model {cfg.model!r} — wrong architecture?")
-        _logger.info(
-            "Loaded initial checkpoint %s: %d/%d leaves applied "
-            "(%d shape-mismatched, %d missing keep their fresh init)",
-            cfg.initial_checkpoint, applied, n_init, dropped,
-            n_init - n_hit)
-
-    def apply_tp(params):
-        # place params under the Megatron-paired TP shardings; non-matching
-        # leaves (and non-transformer models) stay replicated
-        shardings = transformer_tp_sharding(params, mesh, axis="model")
-        return jax.device_put(params, shardings)
-
-    if cfg.tp_size > 1:
-        variables = dict(variables)
-        variables["params"] = apply_tp(variables["params"])
-        _logger.info("Tensor parallelism: params sharded over 'model' "
-                     "axis (tp_size=%d)", cfg.tp_size)
-
+    ``mesh`` overrides the configuration's choice (an abstract topology for
+    an AOT compile, a sub-mesh of the visible chips)."""
+    if mesh is None:
+        mesh = _choose_mesh(cfg)
+    n_dev = int(mesh.size)
+    batch_axis = data_axis_name(mesh)
+    # the data-parallel degree: batch and linear-LR scaling follow it, not
+    # the raw device count (a tp group is ONE model replica)
+    dp_size = int(mesh.shape.get(batch_axis, n_dev))
+    _logger.info("Training with %d devices, mesh %s, process %d/%d",
+                 n_dev, dict(mesh.shape), jax.process_index(),
+                 jax.process_count())
+    _validate(cfg, n_dev, dp_size)
+    data_config = resolve_data_config(cfg.to_dict(),
+                                      verbose=jax.process_index() == 0)
+    input_size = tuple(data_config["input_size"])
+    model = build_model(cfg, input_size[0])
     # linear LR scaling: per-device batch × total devices (train.py:814)
     # effective batch per optimizer step includes the accumulated
     # microbatches — the linear rule must see it, or the flagship config
     # trains with an LR grad_accum-times below the reference's
     lr = cfg.resolved_lr(world_size=dp_size * cfg.grad_accum)
-    tx = create_optimizer(cfg, learning_rate=lr)
-    state = create_train_state(variables, tx, with_ema=cfg.model_ema)
+    lr_scheduler, num_epochs = create_scheduler(cfg, base_lr=lr)
+    if cfg.dist_bn:
+        _logger.info("--dist-bn %s accepted for flag parity; BN stats are "
+                     "pmean-reduced inside every train step here, which "
+                     "supersedes the reference's per-epoch distribute_bn",
+                     cfg.dist_bn)
+    return Program(
+        cfg=cfg, mesh=mesh, n_dev=n_dev, batch_axis=batch_axis, dp=dp_size,
+        data_config=data_config, input_size=input_size, model=model,
+        sequence_task=bool(getattr(model, "sequence_task", False)),
+        lr=lr, tx=create_optimizer(cfg, learning_rate=lr),
+        lr_scheduler=lr_scheduler, num_epochs=num_epochs,
+        loss_fn=create_loss_fn(cfg),
+        # grad_accum microbatches ride inside one compiled step: the loader
+        # assembles the full effective batch per step
+        global_batch=cfg.batch_size * dp_size * cfg.grad_accum,
+        # tp runs use global-BN semantics: the transformer families carry
+        # no BN, so local-stat grouping would only add layout churn
+        bn_mode="global" if (cfg.sync_bn or cfg.tp_size > 1) else "local")
+
+
+def _load_initial_checkpoint(cfg: TrainConfig, variables):
+    """Pretrained weights into the fresh tree (reference train.py:316 /
+    helpers.py:31-44): non-strict — head/in_chans mismatches drop, but
+    loudly, and a checkpoint matching NOTHING is an error (a silent
+    from-scratch "fine-tune" is worse than failing)."""
+    from ..models.helpers import (_flatten, expand_split_bn,
+                                  filter_shape_mismatch, load_state_dict)
+    loaded = load_state_dict(cfg.initial_checkpoint)
+    if cfg.split_bn:
+        # plain-BN checkpoints fan out into main + aux BNs, like the
+        # reference's load-then-convert order (split_batchnorm.py:41)
+        loaded = expand_split_bn(loaded, variables)
+    n_init = len(_flatten(variables))
+    n_hit = len(set(_flatten(variables)) & set(_flatten(loaded)))
+    variables, dropped = filter_shape_mismatch(variables, loaded)
+    applied = n_hit - dropped
+    if applied == 0:
+        raise ValueError(
+            f"--initial-checkpoint {cfg.initial_checkpoint} matches no "
+            f"parameter of model {cfg.model!r} — wrong architecture?")
+    _logger.info(
+        "Loaded initial checkpoint %s: %d/%d leaves applied "
+        "(%d shape-mismatched, %d missing keep their fresh init)",
+        cfg.initial_checkpoint, applied, n_init, dropped, n_init - n_hit)
+    return variables
+
+
+def init_state(program: Program, rng, variables=None):
+    """(placed TrainState, its sharding table) from a fresh init under
+    ``rng``, or from the caller's ``variables`` (consumed)."""
+    cfg, mesh = program.cfg, program.mesh
+    if variables is None and program.sequence_task:
+        # no parameter's shape depends on L, so a short row initializes it
+        variables = init_model(program.model, rng, (1, 8), training=True,
+                               dtype=jnp.int32)
+    elif variables is None:
+        c, h, w = program.input_size
+        variables = init_model(program.model, rng, (1, h, w, c),
+                               training=True)
+    n_params = sum(x.size for x in jax.tree.leaves(variables["params"]))
+    _logger.info("Model %s created, param count: %d", cfg.model, n_params)
+    if cfg.initial_checkpoint:
+        variables = _load_initial_checkpoint(cfg, variables)
+    if cfg.tp_size > 1:
+        # place params under the Megatron-paired TP shardings; non-matching
+        # leaves (and non-transformer models) stay replicated
+        variables = dict(variables)
+        variables["params"] = jax.device_put(
+            variables["params"], transformer_tp_sharding(
+                variables["params"], mesh, axis="model"))
+        _logger.info("Tensor parallelism: params sharded over 'model' "
+                     "axis (tp_size=%d)", cfg.tp_size)
+    state = create_train_state(variables, program.tx,
+                               with_ema=cfg.model_ema)
     # the sharding-rule table (parallel/sharding.py): every TrainState leaf
     # gets its NamedSharding — params replicated/FSDP/TP per rule, opt
     # moments and EMA following their params, BN stats and step replicated
@@ -339,190 +375,17 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
     # downstream (the jitted step's in/out_shardings, checkpoint restore
     # re-layout, the guard's rewind template) reads layout from this one
     # table.
-    state_shardings = train_state_shardings(state, mesh, fsdp=cfg.fsdp,
-                                            axis=batch_axis)
-    state = place_train_state(state, state_shardings)
+    state_shardings = train_state_shardings(
+        state, mesh, fsdp=cfg.fsdp, axis=program.batch_axis)
+    return place_train_state(state, state_shardings), state_shardings
 
-    lr_scheduler, num_epochs = create_scheduler(cfg, base_lr=lr)
-    start_epoch = cfg.start_epoch or 0
 
-    # output dir + config dump (reference :785-808, :527-532) — built
-    # BEFORE resume handling so --auto-resume can consult the run
-    # directory's recovery snapshots at startup
-    output_dir, saver = "", None
-    if rank == 0 or cfg.ckpt_sharded or cfg.auto_resume:
-        exp_name = cfg.experiment or "-".join(
-            [cfg.model_version or cfg.model,
-             os.path.basename(cfg.data.split(":")[0]) or cfg.dataset])
-        # the sharded saver is COLLECTIVE: every rank drives it and all
-        # must agree on the directory, so multi-process sharded runs skip
-        # the auto-increment (a per-rank race) — name runs via --experiment.
-        # --auto-resume equally needs a STABLE directory across relaunches
-        # (the -N increment would "resume" into a fresh empty dir).
-        multiproc_sharded = cfg.ckpt_sharded and jax.process_count() > 1
-        output_dir = get_outdir(cfg.output, exp_name,
-                                inc=not (multiproc_sharded or
-                                         cfg.auto_resume))
-        if multiproc_sharded and rank == 0 and not cfg.resume and \
-                not cfg.auto_resume and \
-                os.path.exists(os.path.join(output_dir, "args.yaml")):
-            # inc=False means a rerun would silently overwrite the
-            # previous run's checkpoints and records.  Rank 0 ONLY: other
-            # ranks would race against rank 0's own args.yaml write of
-            # THIS run; rank 0's failure propagates through the
-            # coordination service
-            raise ValueError(
-                f"{output_dir} already holds a run; multi-process "
-                "--ckpt-sharded disables output-dir auto-increment — "
-                "name this run with --experiment, or --resume it")
-        if rank == 0:
-            with open(os.path.join(output_dir, "args.yaml"), "w") as f:
-                f.write(cfg.to_yaml())
-        if rank == 0 or cfg.ckpt_sharded:
-            decreasing = cfg.eval_metric == "loss"
-            saver_cls = ShardedCheckpointSaver if cfg.ckpt_sharded \
-                else CheckpointSaver
-            saver = saver_cls(
-                checkpoint_dir=output_dir, bak_dir=os.path.join(
-                    output_dir, "_bak"), decreasing=decreasing)
-
-    def _restore_any(path: str, template, load_opt: Optional[bool] = None):
-        if load_opt is None:
-            load_opt = not cfg.no_resume_opt
-        if os.path.isdir(path):
-            # sharded (Orbax) checkpoint directory: collective restore
-            # directly into the template's shardings — re-layout
-            # (incl. a different tp_size) happens inside the read
-            from ..train import restore_sharded_checkpoint
-            st, meta_r = restore_sharded_checkpoint(
-                path, template, load_opt=load_opt)
-            # re-own every restored leaf before it reaches the donating
-            # step: with the sharding table pinning ALL template leaves,
-            # the restore no longer demotes anything to host numpy, and
-            # orbax/tensorstore-backed buffers donated by the step
-            # corrupt the heap (observed: glibc abort on --ckpt-sharded
-            # resume).  jnp.copy preserves each leaf's sharding.
-            st = jax.tree.map(
-                lambda x: jnp.copy(x)
-                if isinstance(x, (jax.Array, np.ndarray)) else x, st)
-            return st, meta_r
-        # msgpack: host arrays re-laid onto the template's sharding-table
-        # annotations (train/checkpoint.py) — a (1,1)-mesh checkpoint
-        # restores onto this run's mesh and vice versa
-        return restore_resharded(path, template, load_opt=load_opt)
-
-    def _restore_with_fallback(template, load_opt: Optional[bool] = None):
-        """Walk the resume ladder (recovery snapshots newest-first, then
-        the _bak best-copy, then model_best), skipping torn/corrupt files
-        instead of crashing on them.  Returns (state, meta, path) or
-        None."""
-        # an in-flight async recovery write hasn't renamed into place yet
-        # — join it BEFORE listing, or a guard rewind a step or two after
-        # the snapshot finds an empty ladder (loads already join; the
-        # listing must too)
-        wait_pending_saves()
-        cands = find_resume_candidates(
-            output_dir, bak_dir=os.path.join(output_dir, "_bak"),
-            sharded=cfg.ckpt_sharded)
-        for path in cands:
-            try:
-                st, meta_r = _restore_any(path, template, load_opt)
-                return st, meta_r, path
-            except (CheckpointCorrupt, FileNotFoundError) as e:
-                _logger.warning("auto-resume: skipping unusable "
-                                "checkpoint %s (%s)", path, e)
-        return None
-
-    resume_batch = 0
-    resumed_from = ""
-    if cfg.resume:
-        state, meta = _restore_any(cfg.resume, state)
-        start_epoch = cfg.start_epoch if cfg.start_epoch is not None \
-            else int(meta.get("epoch", -1)) + 1   # helpers.py:47-73
-        _logger.info("Resumed from %s (epoch %d)", cfg.resume, start_epoch)
-    if cfg.auto_resume:
-        # newer than any --resume argument when present: a relaunch after
-        # preemption continues from its own recovery snapshot, not the
-        # checkpoint the run was originally seeded from
-        restored = _restore_with_fallback(state)
-        if restored is not None:
-            state, meta_r, path = restored
-            resumed_from = path
-            if "batch_idx" in meta_r:
-                # recovery snapshot: exact mid-epoch loop position
-                start_epoch = int(meta_r["epoch"])
-                resume_batch = int(meta_r["batch_idx"]) + 1
-            else:                       # epoch-boundary checkpoint
-                start_epoch = int(meta_r.get("epoch", -1)) + 1
-            _logger.info("Auto-resumed from %s (epoch %d, batch %d)",
-                         path, start_epoch, resume_batch)
-        else:
-            _logger.info("--auto-resume: nothing to resume in %s; "
-                         "starting fresh", output_dir)
-    train_ds, eval_ds = build_datasets(
-        cfg, input_size, pack_dir=data_config.get("pack_dir"),
-        pack_image_size=data_config.get("pack_image_size"),
-        vocab_rows=getattr(model, "vocab_rows", 0))
-    sharding = batch_sharding(mesh)
-    # loaders produce the *per-process* slice of the global batch; the device
-    # prologue assembles the global sharded array
-    # grad_accum microbatches ride inside one compiled step: the loader
-    # assembles the full effective batch per step (train only — eval is a
-    # single forward, so it must NOT inherit the accumulation factor)
-    global_batch = cfg.batch_size * dp_size * cfg.grad_accum
-    local_batch = global_batch // jax.process_count()
-    eval_local_batch = cfg.batch_size * dp_size * 2 // jax.process_count()
-    loader_kwargs = dict(
-        mean=data_config["mean"], std=data_config["std"],
-        num_workers=cfg.workers, seed=cfg.seed,
-        dtype=_dtype(cfg.compute_dtype), sharding=sharding,
-        distributed=jax.process_count() > 1,
-        num_shards=jax.process_count(), shard_index=rank,
-        prefetch_depth=cfg.prefetch_depth,
-        loader_backend=cfg.loader_backend, ring_depth=cfg.ring_depth,
-        worker_heartbeat=cfg.worker_heartbeat, stem_s2d=cfg.stem_s2d)
-    collate_mixup = FastCollateMixup(cfg.mixup, cfg.smoothing,
-                                     cfg.num_classes) if cfg.mixup > 0 \
-        else None
-    if sequence_task:
-        token_kwargs = dict(
-            num_workers=cfg.workers, seed=cfg.seed, sharding=sharding,
-            distributed=jax.process_count() > 1,
-            num_shards=jax.process_count(), shard_index=rank,
-            prefetch_depth=cfg.prefetch_depth)
-        train_loader = create_token_loader(train_ds, local_batch,
-                                           is_training=True, **token_kwargs)
-        eval_loader = create_token_loader(eval_ds, eval_local_batch,
-                                          is_training=False, **token_kwargs)
-    else:
-        train_loader = create_deepfake_loader_v3(
-            train_ds, input_size, local_batch, is_training=True,
-            re_prob=cfg.reprob, re_mode=cfg.remode, re_count=cfg.recount,
-            re_split=cfg.resplit, re_max=cfg.remax,
-            color_jitter=cfg.color_jitter,
-            num_aug_splits=cfg.aug_splits, collate_mixup=collate_mixup,
-            flicker=cfg.flicker, rotate_range=cfg.rotate_range,
-            blur_radius=1, blur_prob=cfg.blur_prob,
-            device_color_jitter=not cfg.host_color_jitter,
-            fused_geom=not cfg.host_geom,
-            augment_device=cfg.augment_device == "on", **loader_kwargs)
-        eval_loader = create_deepfake_loader_v3(
-            eval_ds, input_size, eval_local_batch, is_training=False,
-            eval_crop=cfg.eval_crop,
-            **loader_kwargs)                      # eval bs ×2 (train.py:492)
-
-    train_loss_fn = create_loss_fn(cfg)
-    # tp runs use global-BN semantics: the transformer families carry no
-    # BN, so local-stat grouping would only add layout churn for nothing
-    bn_mode = "global" if (cfg.sync_bn or cfg.tp_size > 1) else "local"
-    if cfg.dist_bn:
-        _logger.info("--dist-bn %s accepted for flag parity; BN stats are "
-                     "pmean-reduced inside every train step here, which "
-                     "supersedes the reference's per-epoch distribute_bn",
-                     cfg.dist_bn)
+def build_steps(program: Program, state_shardings):
+    """(train_step, eval_step, eval_step_ema or None)."""
+    cfg, model = program.cfg, program.model
     train_step = make_train_step(
-        model, tx, train_loss_fn, mesh=mesh, axis=batch_axis,
-        bn_mode=bn_mode,
+        model, program.tx, program.loss_fn, mesh=program.mesh,
+        axis=program.batch_axis, bn_mode=program.bn_mode,
         ema_decay=cfg.model_ema_decay if cfg.model_ema else 0.0,
         clip_grad=cfg.clip_grad, grad_accum=cfg.grad_accum,
         nonfinite_guard=cfg.guard_nonfinite == "skip",
@@ -530,21 +393,301 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
     eval_step = make_eval_step(model, cross_entropy)
     eval_step_ema = make_eval_step(model, cross_entropy, use_ema=True) \
         if cfg.model_ema else None
+    return train_step, eval_step, eval_step_ema
 
-    # a recovery snapshot taken at the LAST batch of an epoch resumes at
-    # the next epoch's first batch
-    if resume_batch >= len(train_loader) > 0:
-        start_epoch += 1
-        resume_batch = 0
-    if lr_scheduler is not None and start_epoch > 0 and resume_batch == 0:
-        # mid-epoch resume keeps the snapshot's injected LR exactly (it
-        # already carries any per-update scheduling); epoch-boundary
-        # resume re-derives it like the reference (train.py:416-417).
-        # Must run AFTER the last-batch normalization above: a snapshot
-        # taken at the final batch of epoch E resumes as (E+1, batch 0)
-        # and needs E+1's LR, not the snapshot's epoch-E value.
-        state = set_learning_rate(
-            state, lr_scheduler.step(start_epoch))
+
+def build_loaders(program: Program, train_ds, eval_ds=None,
+                  seed: Optional[int] = None):
+    """(train_loader, eval_loader or None) over the caller's datasets.
+
+    Loaders produce the *per-process* slice of the global batch; the device
+    prologue assembles the global sharded array.  Eval is a single forward,
+    so it does NOT inherit the accumulation factor: its batch is twice the
+    per-step rows (reference train.py:492)."""
+    cfg = program.cfg
+    n_proc = jax.process_count()
+    local_batch = program.global_batch // n_proc
+    eval_local_batch = cfg.batch_size * program.dp * 2 // n_proc
+    common = dict(
+        num_workers=cfg.workers, seed=cfg.seed if seed is None else seed,
+        sharding=batch_sharding(program.mesh), distributed=n_proc > 1,
+        num_shards=n_proc, shard_index=jax.process_index(),
+        prefetch_depth=cfg.prefetch_depth)
+    if program.sequence_task:
+        train_loader = create_token_loader(train_ds, local_batch,
+                                           is_training=True, **common)
+        eval_loader = create_token_loader(
+            eval_ds, eval_local_batch, is_training=False, **common) \
+            if eval_ds is not None else None
+        return train_loader, eval_loader
+    common.update(
+        mean=program.data_config["mean"], std=program.data_config["std"],
+        dtype=_dtype(cfg.compute_dtype), loader_backend=cfg.loader_backend,
+        ring_depth=cfg.ring_depth, worker_heartbeat=cfg.worker_heartbeat,
+        stem_s2d=cfg.stem_s2d)
+    collate_mixup = FastCollateMixup(cfg.mixup, cfg.smoothing,
+                                     cfg.num_classes) if cfg.mixup > 0 \
+        else None
+    train_loader = create_deepfake_loader_v3(
+        train_ds, program.input_size, local_batch, is_training=True,
+        re_prob=cfg.reprob, re_mode=cfg.remode, re_count=cfg.recount,
+        re_split=cfg.resplit, re_max=cfg.remax,
+        color_jitter=cfg.color_jitter,
+        num_aug_splits=cfg.aug_splits, collate_mixup=collate_mixup,
+        flicker=cfg.flicker, rotate_range=cfg.rotate_range,
+        blur_radius=1, blur_prob=cfg.blur_prob,
+        device_color_jitter=not cfg.host_color_jitter,
+        fused_geom=not cfg.host_geom,
+        augment_device=cfg.augment_device == "on", **common)
+    eval_loader = create_deepfake_loader_v3(
+        eval_ds, program.input_size, eval_local_batch, is_training=False,
+        eval_crop=cfg.eval_crop, **common) if eval_ds is not None else None
+    return train_loader, eval_loader
+
+
+def build_telemetry(program: Program, state, train_loader,
+                    output_dir: str = "", resilience=None):
+    """The run's observability (obs/): the telemetry tracker with its JSONL
+    event log (rank 0 — one coherent stream per run dir) and collectors,
+    the optional ``--metrics-port`` Prometheus endpoint and the on-demand
+    profiler capture.  Returns (telemetry, metrics server or None,
+    profiler or None)."""
+    from ..obs import (EventLog, ProfilerCapture, TrainTelemetry,
+                       forward_flops_per_sample, loader_collector,
+                       native_warp_collector, peak_flops,
+                       resilience_collector, start_metrics_server)
+    cfg, mesh, model = program.cfg, program.mesh, program.model
+    # per-sample forward FLOPs for the live MFU gauge: an abstract jaxpr
+    # walk over the shape the LOADER feeds the model — pixel-shuffled under
+    # --stem-s2d (0 for sequence models: ROADMAP D13)
+    fwd_flops = 0.0
+    if not program.sequence_task:
+        c, h, w = program.input_size
+        fwd_flops = forward_flops_per_sample(
+            model, {"params": state.params,
+                    "batch_stats": state.batch_stats},
+            (1, h // 2, w // 2, 4 * c) if cfg.stem_s2d else (1, h, w, c))
+    event_log = EventLog(os.path.join(output_dir, "telemetry.jsonl")) \
+        if output_dir and jax.process_index() == 0 else None
+    telemetry = TrainTelemetry(
+        event_log=event_log, flops_per_sample=fwd_flops,
+        attn_tiles_per_sample=model.attn_tiles_visited(cfg.seq_len)
+        if program.sequence_task else 0,
+        # throughput is measured on the GLOBAL batch (the loader
+        # assembles the global sharded array), so the MFU denominator
+        # is the whole MESH's peak — n_dev == mesh.size, which a
+        # sub-mesh run may set below the visible device count
+        peak_flops=peak_flops() * program.n_dev,
+        meta=dict(model=cfg.model, global_batch=program.global_batch,
+                  mesh_shape=[int(s) for s in mesh.shape.values()],
+                  axis_names=list(mesh.axis_names)))
+    telemetry.register_collector(loader_collector(train_loader))
+    telemetry.register_collector(native_warp_collector())
+    if resilience is not None:
+        telemetry.register_collector(resilience_collector(resilience))
+    obs_server = start_metrics_server(telemetry, port=cfg.metrics_port) \
+        if cfg.metrics_port else None
+    profiler = None
+    if output_dir and cfg.profile_capture > 0:
+        profiler = ProfilerCapture(output_dir, num_steps=cfg.profile_capture,
+                                   telemetry=telemetry)
+        telemetry.profiler = profiler
+    return telemetry, obs_server, profiler
+
+
+def open_run_dir(cfg: TrainConfig):
+    """(output_dir, saver): the run directory with its config dump
+    (reference :785-808, :527-532) and the checkpoint saver of the ranks
+    that write; ("", None) on a rank that has neither."""
+    rank = jax.process_index()
+    if not (rank == 0 or cfg.ckpt_sharded or cfg.auto_resume):
+        return "", None
+    exp_name = cfg.experiment or "-".join(
+        [cfg.model_version or cfg.model,
+         os.path.basename(cfg.data.split(":")[0]) or cfg.dataset])
+    # the sharded saver is COLLECTIVE: every rank drives it and all
+    # must agree on the directory, so multi-process sharded runs skip
+    # the auto-increment (a per-rank race) — name runs via --experiment.
+    # --auto-resume equally needs a STABLE directory across relaunches
+    # (the -N increment would "resume" into a fresh empty dir).
+    multiproc_sharded = cfg.ckpt_sharded and jax.process_count() > 1
+    output_dir = get_outdir(cfg.output, exp_name,
+                            inc=not (multiproc_sharded or cfg.auto_resume))
+    if multiproc_sharded and rank == 0 and not cfg.resume and \
+            not cfg.auto_resume and \
+            os.path.exists(os.path.join(output_dir, "args.yaml")):
+        # inc=False means a rerun would silently overwrite the
+        # previous run's checkpoints and records.  Rank 0 ONLY: other
+        # ranks would race against rank 0's own args.yaml write of
+        # THIS run; rank 0's failure propagates through the
+        # coordination service
+        raise ValueError(
+            f"{output_dir} already holds a run; multi-process "
+            "--ckpt-sharded disables output-dir auto-increment — "
+            "name this run with --experiment, or --resume it")
+    if rank == 0:
+        with open(os.path.join(output_dir, "args.yaml"), "w") as f:
+            f.write(cfg.to_yaml())
+    saver = None
+    if rank == 0 or cfg.ckpt_sharded:
+        saver_cls = ShardedCheckpointSaver if cfg.ckpt_sharded \
+            else CheckpointSaver
+        saver = saver_cls(
+            checkpoint_dir=output_dir,
+            bak_dir=os.path.join(output_dir, "_bak"),
+            decreasing=cfg.eval_metric == "loss")
+    return output_dir, saver
+
+
+def _resume(cfg: TrainConfig, state, output_dir: str):
+    """``--resume`` and ``--auto-resume``: (state, start epoch, the meta of
+    the run's own snapshot or None, that snapshot's path or "")."""
+    start_epoch, auto_meta, resumed_from = cfg.start_epoch or 0, None, ""
+    if cfg.resume:
+        state, meta = restore_any(cfg.resume, state,
+                                  load_opt=not cfg.no_resume_opt)
+        start_epoch = cfg.start_epoch if cfg.start_epoch is not None \
+            else int(meta.get("epoch", -1)) + 1   # helpers.py:47-73
+        _logger.info("Resumed from %s (epoch %d)", cfg.resume, start_epoch)
+    if cfg.auto_resume:
+        # newer than any --resume argument when present: a relaunch after
+        # preemption continues from its own recovery snapshot, not the
+        # checkpoint the run was originally seeded from
+        restored = restore_with_fallback(
+            output_dir, state, load_opt=not cfg.no_resume_opt,
+            sharded=cfg.ckpt_sharded)
+        if restored is not None:
+            state, auto_meta, resumed_from = restored
+        else:
+            _logger.info("--auto-resume: nothing to resume in %s; "
+                         "starting fresh", output_dir)
+    return state, start_epoch, auto_meta, resumed_from
+
+
+def _enter_at(state, lr_scheduler, epoch: int, batch: int):
+    """The state as the loop takes it at (epoch, batch), at start-up and
+    after a rewind alike: a mid-epoch entry keeps the snapshot's injected
+    LR exactly (it already carries any per-update scheduling); an
+    epoch-boundary entry re-derives it like the reference
+    (train.py:416-417).  Call it with the position ``resume_position``
+    gave: a snapshot taken at the final batch of epoch E enters as
+    (E+1, batch 0) and needs E+1's LR, not the snapshot's epoch-E value."""
+    if lr_scheduler is not None and epoch > 0 and batch == 0:
+        state = set_learning_rate(state, lr_scheduler.step(epoch))
+    return state
+
+
+def _rewind(cfg: TrainConfig, cause: RewindRequested, state, output_dir: str,
+            resilience, telemetry, lr_scheduler, batches_per_epoch: int):
+    """K consecutive bad steps: continuing would train on (or EMA-blend in)
+    corrupted state — reload the last good snapshot and give the position
+    to fast-forward back to.  Multi-process, the verdict was max-reduced
+    in-band (Resilience.sync_verdicts at the drain cadence), so every host
+    raises at the SAME boundary and the collective restore stays in
+    lockstep.  Returns (state, epoch, batch)."""
+    if jax.process_count() > 1 and not (cfg.ckpt_sharded or cfg.auto_resume):
+        # rank != 0 has no output_dir on this layout (inc=True names are
+        # rank-0-local), so a per-rank restore would diverge — one rank
+        # reloading while others error is a guaranteed collective hang.
+        # The config-derived condition is identical on every host: ALL
+        # ranks abort in lockstep instead.
+        raise RuntimeError(
+            "guard rewind on a multi-process run needs a rank-agnostic "
+            "run dir: relaunch with --auto-resume (+--experiment) or "
+            "--ckpt-sharded") from cause
+    resilience.start_rewind(str(cause))      # raises budget-spent
+    # load_opt=True always: a rewind restores the run's OWN snapshot
+    # (--no-resume-opt governs seeding from a foreign checkpoint), and the
+    # --no-resume-opt substitution would copy opt/step leaves out of the
+    # template — here the epoch-entry state, whose buffers the donating
+    # train step already deleted
+    restored = restore_with_fallback(output_dir, state, load_opt=True,
+                                     sharded=cfg.ckpt_sharded)
+    if restored is None:
+        raise RuntimeError(
+            "rewind requested but no loadable recovery snapshot exists — "
+            "enable --recovery-interval so the guard has somewhere to "
+            "rewind to") from cause
+    state, meta, path = restored
+    _logger.warning("rewound to %s", path)
+    if telemetry is not None:
+        telemetry.event("rewind", reason=str(cause), restored_from=path)
+    epoch, batch = resume_position(meta, batches_per_epoch)
+    return _enter_at(state, lr_scheduler, epoch, batch), epoch, batch
+
+
+def _evaluate(cfg: TrainConfig, state, eval_step, eval_step_ema, eval_loader,
+              resilience) -> Dict[str, float]:
+    eval_metrics = validate(eval_step, state, eval_loader, cfg,
+                            resilience=resilience)
+    if eval_step_ema is not None:
+        # EMA eval *replaces* the metrics (reference :563-569)
+        eval_metrics = validate(eval_step_ema, state, eval_loader, cfg,
+                                log_suffix=" (EMA)", resilience=resilience)
+    return eval_metrics
+
+
+def _record_epoch(cfg: TrainConfig, epoch: int, state, saver, meta,
+                  train_metrics, eval_metrics, output_dir: str):
+    """The epoch's row of summary.csv and its checkpoint.  Returns the
+    saver's (best metric, best epoch), (None, None) without a saver."""
+    if output_dir and jax.process_index() == 0:
+        csv_path = os.path.join(output_dir, "summary.csv")
+        # header iff the file doesn't exist yet: an epoch counter (the old
+        # rule) or a process-local flag would append a second header
+        # mid-file on every auto-resume relaunch, corrupting the CSV for
+        # plot_csv/pandas
+        update_summary(epoch, train_metrics, eval_metrics, csv_path,
+                       os.path.join(output_dir, "plots"),
+                       write_header=not os.path.exists(csv_path))
+    # sharded saver: the collective save IS the cross-host path — no
+    # gather. Otherwise multi-host TP/EP: every rank gathers model-sharded
+    # leaves so rank 0 can serialize; no-op else
+    collective = saver is not None and saver.collective
+    save_state = replicate_for_save(state) \
+        if jax.process_count() > 1 and not collective else state
+    if saver is None:
+        return None, None
+    return saver.save_checkpoint(save_state, meta, epoch,
+                                 metric=eval_metrics[cfg.eval_metric])
+
+
+def main(cfg: TrainConfig) -> Dict[str, float]:
+    """Train to completion; returns the best eval metrics."""
+    # restarted runs skip the XLA compile wall — before the first compile
+    setup_compile_cache(cfg.compile_cache_dir)
+    program = build_program(cfg)
+    mesh, lr_scheduler = program.mesh, program.lr_scheduler
+    # ONE seed for every host: params are logically replicated, so init must
+    # be identical everywhere (the reference's per-rank seed, train.py:299,
+    # was safe only because DDP broadcast rank-0's weights; SPMD has no such
+    # broadcast).  The unified step draws dropout noise over the GLOBAL
+    # batch from one mesh-replicated key (the key is pinned replicated
+    # before the loop below) — do NOT re-add a per-device fold; it would
+    # break the replicated-key in_shardings contract.
+    init_rng, rng = jax.random.split(jax.random.PRNGKey(cfg.seed))
+    state, state_shardings = init_state(program, init_rng)
+
+    # the run directory comes BEFORE resume handling so --auto-resume can
+    # consult its recovery snapshots at startup
+    output_dir, saver = open_run_dir(cfg)
+    state, start_epoch, auto_meta, resumed_from = _resume(cfg, state,
+                                                          output_dir)
+
+    train_ds, eval_ds = build_datasets(
+        cfg, program.input_size, pack_dir=program.data_config.get("pack_dir"),
+        pack_image_size=program.data_config.get("pack_image_size"),
+        vocab_rows=getattr(program.model, "vocab_rows", 0))
+    train_loader, eval_loader = build_loaders(program, train_ds, eval_ds)
+    train_step, eval_step, eval_step_ema = build_steps(program,
+                                                       state_shardings)
+    resume_batch = 0
+    if auto_meta is not None:
+        start_epoch, resume_batch = resume_position(auto_meta,
+                                                    len(train_loader))
+        _logger.info("Auto-resumed from %s (epoch %d, batch %d)",
+                     resumed_from, start_epoch, resume_batch)
+    state = _enter_at(state, lr_scheduler, start_epoch, resume_batch)
 
     if jax.process_count() > 1:
         # all host-side setup (datasets, eager init, output dir) is done —
@@ -561,7 +704,6 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
     # run (a committed single-device key would be an in_shardings
     # mismatch).  own_and_place owns the bytes and covers multi-host,
     # where every process holds the same host key.
-    from ..parallel import own_and_place
     rng = own_and_place(np.asarray(rng), replicated_sharding(mesh))
 
     meta = {"arch": cfg.model, "version": 2}
@@ -569,44 +711,14 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
     eval_metrics: Dict[str, float] = {}
     exit_code: Optional[int] = None
     resilience = Resilience.from_config(cfg, output_dir=output_dir)
-
-    # observability (obs/): default-on telemetry tracker + JSONL event log
-    # (rank 0 — one coherent stream per run dir), optional --metrics-port
-    # Prometheus endpoint, on-demand profiler capture triggers
     telemetry, obs_server, profiler = None, None, None
-    if not cfg.no_telemetry:
-        from ..obs import (EventLog, ProfilerCapture, TrainTelemetry,
-                           loader_collector, native_warp_collector,
-                           peak_flops, resilience_collector,
-                           start_metrics_server)
-        event_log = EventLog(os.path.join(output_dir, "telemetry.jsonl")) \
-            if output_dir and rank == 0 else None
-        telemetry = TrainTelemetry(
-            event_log=event_log, flops_per_sample=fwd_flops,
-            attn_tiles_per_sample=model.attn_tiles_visited(cfg.seq_len)
-            if sequence_task else 0,
-            # throughput is measured on the GLOBAL batch (the loader
-            # assembles the global sharded array), so the MFU denominator
-            # is the whole MESH's peak — n_dev == mesh.size, which a
-            # sub-mesh run may set below the visible device count
-            peak_flops=peak_flops() * n_dev,
-            meta=dict(model=cfg.model, global_batch=global_batch,
-                      mesh_shape=[int(s) for s in mesh.shape.values()],
-                      axis_names=list(mesh.axis_names)))
-        telemetry.register_collector(loader_collector(train_loader))
-        telemetry.register_collector(native_warp_collector())
-        telemetry.register_collector(resilience_collector(resilience))
-        if cfg.metrics_port:
-            obs_server = start_metrics_server(telemetry,
-                                              port=cfg.metrics_port)
-        if output_dir and cfg.profile_capture > 0:
-            profiler = ProfilerCapture(output_dir,
-                                       num_steps=cfg.profile_capture,
-                                       telemetry=telemetry)
-            telemetry.profiler = profiler
-        telemetry.event("run_start", model=cfg.model, epochs=num_epochs,
-                        start_epoch=start_epoch, global_batch=global_batch,
-                        world_size=n_dev,
+    if not cfg.no_telemetry:                       # default-on
+        telemetry, obs_server, profiler = build_telemetry(
+            program, state, train_loader, output_dir, resilience)
+        telemetry.event("run_start", model=cfg.model,
+                        epochs=program.num_epochs, start_epoch=start_epoch,
+                        global_batch=program.global_batch,
+                        world_size=program.n_dev,
                         mesh_shape=[int(s) for s in mesh.shape.values()],
                         axis_names=list(mesh.axis_names))
         if resumed_from:
@@ -619,7 +731,7 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
                                 "trigger not installed (the PROFILE file "
                                 "trigger still works)")
             epoch = start_epoch
-            while epoch < num_epochs:
+            while epoch < program.num_epochs:
                 train_loader.set_epoch(epoch)      # reference :549
                 if resume_batch:
                     train_loader.fast_forward(resume_batch)
@@ -632,102 +744,25 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
                     state, train_metrics = train_one_epoch(
                         epoch, train_step, state, train_loader, cfg,
                         epoch_rng, lr_scheduler=lr_scheduler, saver=saver,
-                        output_dir=output_dir, meta=meta, world_size=n_dev,
-                        start_batch=resume_batch, resilience=resilience,
-                        telemetry=telemetry)
+                        output_dir=output_dir, meta=meta,
+                        world_size=program.n_dev, start_batch=resume_batch,
+                        resilience=resilience, telemetry=telemetry)
                 except RewindRequested as e:
-                    # K consecutive bad steps: continuing would train on
-                    # (or EMA-blend in) corrupted state — reload the last
-                    # good snapshot and fast-forward back to position.
-                    # Multi-process, the verdict was max-reduced in-band
-                    # (Resilience.sync_verdicts at the drain cadence), so
-                    # every host raises at the SAME boundary and the
-                    # collective restore stays in lockstep.
-                    if jax.process_count() > 1 and not (
-                            cfg.ckpt_sharded or cfg.auto_resume):
-                        # rank != 0 has no output_dir on this layout
-                        # (inc=True names are rank-0-local), so a per-rank
-                        # restore would diverge — one rank reloading while
-                        # others error is a guaranteed collective hang.
-                        # The config-derived condition is identical on
-                        # every host: ALL ranks abort in lockstep instead.
-                        raise RuntimeError(
-                            "guard rewind on a multi-process run needs a "
-                            "rank-agnostic run dir: relaunch with "
-                            "--auto-resume (+--experiment) or "
-                            "--ckpt-sharded") from e
-                    resilience.start_rewind(str(e))  # raises budget-spent
-                    # load_opt=True always: a rewind restores the run's OWN
-                    # snapshot (--no-resume-opt governs seeding from a
-                    # foreign checkpoint), and the --no-resume-opt
-                    # substitution would copy opt/step leaves out of the
-                    # template — here the epoch-entry state, whose buffers
-                    # the donating train step already deleted
-                    restored = _restore_with_fallback(state, load_opt=True)
-                    if restored is None:
-                        raise RuntimeError(
-                            "rewind requested but no loadable recovery "
-                            "snapshot exists — enable --recovery-interval "
-                            "so the guard has somewhere to rewind to"
-                        ) from e
-                    state, meta_r, path = restored
-                    _logger.warning("rewound to %s", path)
-                    if telemetry is not None:
-                        telemetry.event("rewind", reason=str(e),
-                                        restored_from=path)
-                    if "batch_idx" in meta_r:
-                        epoch = int(meta_r["epoch"])
-                        resume_batch = int(meta_r["batch_idx"]) + 1
-                        if resume_batch >= len(train_loader):
-                            epoch += 1
-                            resume_batch = 0
-                    else:
-                        epoch = int(meta_r.get("epoch", -1)) + 1
-                        resume_batch = 0
-                    if lr_scheduler is not None and resume_batch == 0 \
-                            and epoch > 0:
-                        # same rule as the startup resume path: an
-                        # epoch-boundary re-entry re-derives the LR
-                        state = set_learning_rate(
-                            state, lr_scheduler.step(epoch))
+                    state, epoch, resume_batch = _rewind(
+                        cfg, e, state, output_dir, resilience, telemetry,
+                        lr_scheduler, len(train_loader))
                     continue
                 resume_batch = 0
 
-                eval_metrics = validate(eval_step, state, eval_loader, cfg,
-                                        resilience=resilience)
-                if eval_step_ema is not None:
-                    # EMA eval *replaces* the metrics (reference :563-569)
-                    eval_metrics = validate(eval_step_ema, state,
-                                            eval_loader, cfg,
-                                            log_suffix=" (EMA)",
-                                            resilience=resilience)
-
+                eval_metrics = _evaluate(cfg, state, eval_step, eval_step_ema,
+                                         eval_loader, resilience)
                 if lr_scheduler is not None:
                     new_lr = lr_scheduler.step(
                         epoch + 1, eval_metrics[cfg.eval_metric])  # :571-573
                     state = set_learning_rate(state, new_lr)
-
-                if output_dir and rank == 0:
-                    csv_path = os.path.join(output_dir, "summary.csv")
-                    # header iff the file doesn't exist yet: an epoch
-                    # counter (the old rule) or a process-local flag would
-                    # append a second header mid-file on every auto-resume
-                    # relaunch, corrupting the CSV for plot_csv/pandas
-                    update_summary(epoch, train_metrics, eval_metrics,
-                                   csv_path,
-                                   os.path.join(output_dir, "plots"),
-                                   write_header=not os.path.exists(csv_path))
-                # sharded saver: the collective save IS the cross-host path
-                # — no gather. Otherwise multi-host TP/EP: every rank
-                # gathers model-sharded leaves so rank 0 can serialize;
-                # no-op else
-                collective = saver is not None and saver.collective
-                save_state = replicate_for_save(state) \
-                    if jax.process_count() > 1 and not collective else state
-                if saver is not None:
-                    best_metric, best_epoch = saver.save_checkpoint(
-                        save_state, meta, epoch,
-                        metric=eval_metrics[cfg.eval_metric])
+                best_metric, best_epoch = _record_epoch(
+                    cfg, epoch, state, saver, meta, train_metrics,
+                    eval_metrics, output_dir)
                 if telemetry is not None:
                     telemetry.event("epoch_end", epoch=epoch,
                                     train=dict(train_metrics),

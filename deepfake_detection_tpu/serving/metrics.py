@@ -21,53 +21,29 @@ import threading
 import time
 from typing import Deque, Dict, Tuple
 
+from ..obs.telemetry import backend_compile_count
 from ..utils.metrics import LatencyHistogram
 from ..utils.prometheus import Counter as _Counter
 from ..utils.prometheus import PromText
 
-__all__ = ["ServingMetrics"]
+__all__ = ["ServingMetrics", "backend_compile_count",
+           "install_backend_compile_listener"]
 
 _PREFIX = "dfd_serving"
 
 # ---------------------------------------------------------------------------
 # Process-wide backend-compile observer.  The engine's own compiles_total
 # counts its AOT bucket builds, but only a signal from INSIDE jax can
-# catch a silent recompile some other code path triggers — this listener
-# increments on every real backend compile in the process, and the bench's
-# zero-recompile probe asserts the DELTA across the load phase is zero.
+# catch a silent recompile some other code path triggers.  The process has
+# ONE listener for that event, obs/telemetry.py's (in with that module's
+# import, so with this one's); the zero-recompile probes assert that the
+# count's DELTA across the load phase is zero.
 # ---------------------------------------------------------------------------
 
-_backend_compiles = 0
-_backend_lock = threading.Lock()
-_listener_installed = False
-
-
-def _on_event_duration(name: str, *_args, **_kw) -> None:
-    if name == "/jax/core/compile/backend_compile_duration":
-        global _backend_compiles
-        with _backend_lock:
-            _backend_compiles += 1
-
-
 def install_backend_compile_listener() -> bool:
-    """Idempotent; returns True if the jax monitoring hook is available."""
-    global _listener_installed
-    if _listener_installed:
-        return True
-    try:
-        from jax._src import monitoring
-        monitoring.register_event_duration_secs_listener(_on_event_duration)
-    except Exception:                              # noqa: BLE001 — optional
-        return False
-    _listener_installed = True
+    """True: the listener went in with the import of this module."""
     return True
 
-
-def backend_compile_count() -> int:
-    """Backend compiles observed process-wide since the listener went in
-    (0 until then)."""
-    with _backend_lock:
-        return _backend_compiles
 
 #: serving latencies cluster well under the train-loop default bounds —
 #: extend down to 100 µs so queue-wait under light load still resolves

@@ -4,9 +4,11 @@ fault tolerance."""
 from .checkpoint import (CheckpointCorrupt, CheckpointSaver,
                          ShardedCheckpointSaver, find_resume_candidates,
                          load_checkpoint_file, replicate_for_save,
-                         restore_resharded, restore_sharded_checkpoint,
-                         restore_train_state, save_checkpoint_file,
-                         save_sharded_checkpoint, wait_pending_saves)
+                         restore_any, restore_resharded,
+                         restore_sharded_checkpoint, restore_train_state,
+                         restore_with_fallback, resume_position,
+                         save_checkpoint_file, save_sharded_checkpoint,
+                         wait_pending_saves)
 from .resilience import (EXIT_PREEMPTED, EXIT_WATCHDOG, AnomalyGuard,
                          Preempted, Resilience, RewindRequested,
                          StallWatchdog, allreduce_flags)

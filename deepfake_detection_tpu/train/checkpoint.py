@@ -28,6 +28,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from flax import serialization
 
@@ -38,7 +39,8 @@ __all__ = ["CheckpointSaver", "ShardedCheckpointSaver", "CheckpointCorrupt",
            "replicate_for_save", "restore_train_state",
            "restore_resharded", "wait_pending_saves",
            "save_sharded_checkpoint", "restore_sharded_checkpoint",
-           "load_sharded_for_eval", "find_resume_candidates"]
+           "load_sharded_for_eval", "find_resume_candidates", "restore_any",
+           "restore_with_fallback", "resume_position"]
 
 _EXT = ".ckpt"
 
@@ -537,6 +539,73 @@ def restore_resharded(path: str, target_state: Any,
     # donating step could free — the PR 2 SIGSEGV class) laid onto the
     # template's sharding, cross-host via per-shard assembly
     return jax.tree.map(own_and_place, restored, shard_tree), meta
+
+
+def restore_any(path: str, template: Any, load_opt: bool = True
+                ) -> Tuple[Any, Dict[str, Any]]:
+    """Restore ``path`` (msgpack file or sharded Orbax directory) into the
+    template's structure and layout: what ``--resume``, ``--auto-resume``
+    and the guard's rewind all read through."""
+    if os.path.isdir(path):
+        # sharded (Orbax) checkpoint directory: collective restore
+        # directly into the template's shardings — re-layout
+        # (incl. a different tp_size) happens inside the read
+        st, meta = restore_sharded_checkpoint(path, template,
+                                              load_opt=load_opt)
+        # re-own every restored leaf before it reaches the donating
+        # step: with the sharding table pinning ALL template leaves,
+        # the restore no longer demotes anything to host numpy, and
+        # orbax/tensorstore-backed buffers donated by the step
+        # corrupt the heap (observed: glibc abort on --ckpt-sharded
+        # resume).  jnp.copy preserves each leaf's sharding.
+        st = jax.tree.map(
+            lambda x: jnp.copy(x)
+            if isinstance(x, (jax.Array, np.ndarray)) else x, st)
+        return st, meta
+    # msgpack: host arrays re-laid onto the template's sharding-table
+    # annotations — a (1,1)-mesh checkpoint restores onto this run's mesh
+    # and vice versa
+    return restore_resharded(path, template, load_opt=load_opt)
+
+
+def restore_with_fallback(checkpoint_dir: str, template: Any,
+                          load_opt: bool = True, sharded: bool = False
+                          ) -> Optional[Tuple[Any, Dict[str, Any], str]]:
+    """Walk the resume ladder of a run directory (recovery snapshots
+    newest-first, then the ``_bak`` best-copy, then model_best), skipping
+    torn/corrupt files instead of crashing on them.  Returns
+    (state, meta, path) or None."""
+    # an in-flight async recovery write hasn't renamed into place yet
+    # — join it BEFORE listing, or a guard rewind a step or two after
+    # the snapshot finds an empty ladder (loads already join; the
+    # listing must too)
+    wait_pending_saves()
+    for path in find_resume_candidates(
+            checkpoint_dir, bak_dir=os.path.join(checkpoint_dir, "_bak"),
+            sharded=sharded):
+        try:
+            state, meta = restore_any(path, template, load_opt)
+            return state, meta, path
+        except (CheckpointCorrupt, FileNotFoundError) as e:
+            _logger.warning("auto-resume: skipping unusable "
+                            "checkpoint %s (%s)", path, e)
+    return None
+
+
+def resume_position(meta: Dict[str, Any], batches_per_epoch: int
+                    ) -> Tuple[int, int]:
+    """(epoch, batch) at which the loop re-enters after restoring a
+    checkpoint whose meta is ``meta`` — at start-up and after a rewind
+    alike.  A recovery snapshot carries its exact mid-epoch position
+    (``batch_idx`` is the last batch it holds); one taken at the LAST batch
+    of an epoch resumes at the next epoch's first batch.  An epoch-boundary
+    checkpoint resumes at the epoch after its own."""
+    if "batch_idx" not in meta:
+        return int(meta.get("epoch", -1)) + 1, 0
+    epoch, batch = int(meta["epoch"]), int(meta["batch_idx"]) + 1
+    if batch >= batches_per_epoch > 0:
+        return epoch + 1, 0
+    return epoch, batch
 
 
 class CheckpointSaver:

@@ -399,7 +399,7 @@ def validate(eval_step: Callable, state: TrainState, loader, cfg,
         logits = metrics.get("logits")
         if logits is not None and logits.shape[-1] == 2:
             # P(real): labels are 0=fake / 1=real, so AUC ranks real above
-            # fake (the released-checkpoint quality gate, BASELINE.md).
+            # fake (the released-checkpoint quality gate).
             # Accumulate only this process's rows here; the cross-process
             # gather happens ONCE after the loop (a per-batch allgather
             # would force a host sync every eval batch).

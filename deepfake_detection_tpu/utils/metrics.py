@@ -74,7 +74,7 @@ def auc(scores: jnp.ndarray, labels: jnp.ndarray,
 
     The reference never computes AUC in code, but its released checkpoint is
     evaluated by AUC (README.md:35-40) and the north-star quality gate is
-    "AUC ≥ the released GPU checkpoint" (BASELINE.md) — so the framework
+    "AUC ≥ the released GPU checkpoint" — so the framework
     ships the metric.  Pure jnp, O(n log n), static-shaped (ties get the
     usual midrank treatment), so it can run inside a jitted eval epoch.
 

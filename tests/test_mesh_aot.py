@@ -8,9 +8,9 @@ compiled executable's input/output shardings.
 
 One fresh subprocess (tools/bench_multichip.py parent mode) forces 256
 virtual CPU devices and runs the whole matrix; this test consumes its
-JSON verdict.  The tool is the same thing the verify recipe smokes and
-the chip battery records MULTICHIP rows with — CI and bench share one
-code path.
+JSON verdict.  The step it compiles is the runner's own
+(``runners/train.py:build_program`` → ``build_steps`` on each topology's
+mesh), and the tool is the same thing the verify recipe smokes.
 """
 
 import json

@@ -59,7 +59,7 @@ class TestSequenceParallel:
         "ulysses"])
     def test_sp_attention_matches_full(self, devices, impl):
         """128 tokens sharded 8-ways through the SP kernels must match the
-        dense forward (BASELINE.json: 'ViT … stress XLA attention path')."""
+        dense forward (the ViT families exist to stress the attention path)."""
         m_full, m_sp = self._models(devices, impl)
         # 128×128/16 → 64 tokens per side isn't enough for 8-way ulysses
         # heads split (3 heads) — ring shards the SEQUENCE so 64 works; for

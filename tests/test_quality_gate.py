@@ -1,7 +1,7 @@
 """Quality-gate machinery end-to-end (VERDICT r3 item 6).
 
 The north-star gate is "DeeperForensics AUC ≥ the released GPU checkpoint"
-(BASELINE.md; reference README.md:35-40).  The released ``model_half.pth.tar``
+(reference README.md:35-40).  The released ``model_half.pth.tar``
 lives behind BaiduYun and the dataset is unavailable here, so this proves the
 *machinery* instead: train the REFERENCE torch stack (vendored at
 /root/reference, loaded standalone) on deterministic synthetic 4-frame data

@@ -281,7 +281,7 @@ class TestMsgpackMeshContinuity:
     sharding-table annotations, so a checkpoint written on a (1,1) mesh
     restores onto an (8,1) layout — including FSDP resharding — and vice
     versa, with values bit-identical either way.  The PR 3 resume ladder
-    routes through this exact function (runners/train.py::_restore_any).
+    routes through this exact function (train/checkpoint.py:restore_any).
     """
 
     def _unified_state(self, devices, n_batch, fsdp=False):

@@ -110,17 +110,22 @@ def test_gil_pause_methodology():
         pytest.skip("native lib unavailable")
     import json
     import subprocess
-    r = subprocess.run(
-        [sys.executable, os.path.join(os.path.dirname(__file__), os.pardir,
-                                      "tools", "bench_gil.py"),
-         "--src", "2200", "--reps", "2"],
-        capture_output=True, text=True, timeout=240)
-    assert r.returncode == 0, r.stderr[-500:]
-    parsed = [json.loads(l) for l in r.stdout.splitlines()
-              if l.startswith("{")]
-    errors = [j for j in parsed if "error" in j]
-    assert not errors, (errors, r.stderr[-300:])
-    rows = {j["stage"]: j for j in parsed if "stage" in j}
+    for _ in range(3):
+        r = subprocess.run(
+            [sys.executable, os.path.join(os.path.dirname(__file__),
+                                          os.pardir, "tools", "bench_gil.py"),
+             "--src", "2200", "--reps", "2"],
+            capture_output=True, text=True, timeout=240)
+        assert r.returncode == 0, r.stderr[-500:]
+        parsed = [json.loads(l) for l in r.stdout.splitlines()
+                  if l.startswith("{")]
+        errors = [j for j in parsed if "error" in j]
+        assert not errors, (errors, r.stderr[-300:])
+        rows = {j["stage"]: j for j in parsed if "stage" in j}
+        # None = the tool could not tell (its spinner was starved by the
+        # other workers of a loaded suite): measure again, never guess
+        if all(j["gil_held"] is not None for j in rows.values()):
+            break
     assert rows["control_warp_PyDLL_gil_held"]["gil_held"] is True
     assert rows["decode_native_CDLL"]["gil_held"] is False
     assert rows["warp_native_CDLL"]["gil_held"] is False

@@ -59,3 +59,30 @@ def test_ceilings_band_and_s2d_reclassification():
     # measured, not modeled — PERF.md post-fusion roofline)
     assert s2d["ceilings"]["mfu_ceiling_post_fusion"] \
         <= c["mfu_ceiling_post_fusion"]
+
+
+def test_gauge_count_needs_no_tools_directory(tmp_path):
+    """The MFU gauge's count comes from the package (obs/flops.py): with the
+    package alone on the path — no tools/ beside it — it reads what the
+    CLI prints, not 0."""
+    os.symlink(os.path.join(_REPO, "deepfake_detection_tpu"),
+               tmp_path / "deepfake_detection_tpu")
+    code = (
+        "import jax, sys\n"
+        "from deepfake_detection_tpu.models import create_model, init_model\n"
+        "from deepfake_detection_tpu.obs import forward_flops_per_sample\n"
+        "import deepfake_detection_tpu.obs.telemetry as t\n"
+        f"assert t.__file__.startswith({str(tmp_path)!r}), t.__file__\n"
+        "m = create_model('mnasnet_small', num_classes=2, in_chans=3)\n"
+        "v = jax.eval_shape(lambda: init_model(m, jax.random.PRNGKey(0),"
+        " (1, 64, 64, 3)))\n"
+        "print(forward_flops_per_sample(m, v, (1, 64, 64, 3)))\n"
+        "assert not any('flops_breakdown' in k for k in sys.modules)\n")
+    env = dict(os.environ, PYTHONPATH=str(tmp_path), JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", code], cwd=tmp_path,
+                         capture_output=True, text=True, env=env,
+                         timeout=300, check=True)
+    flops = float(out.stdout.strip().splitlines()[-1])
+    assert flops > 0
+    assert round(flops / 1e9, 2) == \
+        _run("mnasnet_small", "--size", "64")["total_gflops_fwd"]

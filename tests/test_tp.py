@@ -106,8 +106,8 @@ def _launch_cli(args):
 
     A fresh interpreter IS the artifact a CLI test should exercise — and
     process isolation means a native crash in the runner (the class of
-    bug that donated-alias resume used to hit, see runners/train.py's
-    resume ``_own`` note) can at worst fail this one test instead of
+    bug that donated-alias resume used to hit, see
+    train/checkpoint.py:restore_resharded) can at worst fail this one test instead of
     killing the whole pytest process and every test after it."""
     import os
     import subprocess

@@ -275,7 +275,7 @@ def run_packed(root: str, args) -> list:
     rows = []
     # the one-time pack is the longest stage of a cold run — it rides
     # under the SAME gate as the rows (a stage that starts runs to
-    # completion, bench.py semantics, but never starts with <60s left)
+    # completion, but never starts with <60s left)
     if budget_left() < 60.0:
         row = {"kind": "packed_matrix", "row": "pack", "backend": "packed",
                "crop_size": args.size, "host_cpus": os.cpu_count(),
@@ -311,7 +311,6 @@ def run_packed(root: str, args) -> list:
                    "host_cpus": os.cpu_count()}
             if budget_left() < 60.0:
                 # the <60s skip: never start a row the budget cannot fit
-                # (mirrors bench.py's retry-budget gate)
                 row["skipped"] = f"budget {budget:.0f}s: <60s remain"
                 print(f"| {name}/{source} | skipped ({row['skipped']}) |")
                 rows.append(row)

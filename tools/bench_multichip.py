@@ -6,9 +6,11 @@ carves sub-meshes for each requested ``(batch, model)`` topology —
 (1,1) one chip, (8,1) a v5e-8 host, (16,4)/(64,4) v5e-64/-256 pod
 slices — and for each:
 
-* builds the tiny probe model + TrainState + the sharding-rule table
-  (``parallel/sharding.py:train_state_shardings``);
-* AOT-lowers and compiles the unified ``jax.jit`` train step against
+* builds the runner's program for the tiny probe model's flags on that
+  mesh (``runners/train.py:build_program`` → ``build_steps``: the step is
+  the one ``main`` trains with, not a copy), a TrainState and the
+  sharding-rule table (``parallel/sharding.py:train_state_shardings``);
+* AOT-lowers and compiles that unified ``jax.jit`` train step against
   abstract ``ShapeDtypeStruct`` inputs carrying the table's
   ``NamedSharding`` annotations;
 * asserts, from the compiled executable, that every TrainState leaf's
@@ -17,10 +19,9 @@ slices — and for each:
   (``input_output_alias`` in the post-optimization HLO);
 * records lowering / compile wall-time and HLO size per topology.
 
-Rows land in ``MULTICHIP_AOT.json`` (repo root) — the MULTICHIP row
-family the chip battery's dryrun produces, extended with the abstract
-matrix.  ``tests/test_mesh_aot.py`` runs the same child with the
-acceptance shapes; the verify recipe runs ``--smoke``.
+The verdict is printed (``--out FILE`` also writes it as JSON).
+``tests/test_mesh_aot.py`` runs the same child with the acceptance shapes;
+the verify recipe runs ``--smoke``.
 
 Usage::
 
@@ -58,20 +59,19 @@ def parse_shapes(spec: str):
 
 def run_matrix(shapes, model_name: str, size: int, batch_per_dp: int,
                log=lambda m: print(m, file=sys.stderr, flush=True)):
+    import dataclasses
+
     import jax
     import jax.numpy as jnp
-    import numpy as np
-    from types import SimpleNamespace
 
-    from deepfake_detection_tpu.losses import cross_entropy
-    from deepfake_detection_tpu.models import create_model, init_model
-    from deepfake_detection_tpu.optim import create_optimizer
+    from deepfake_detection_tpu.config import TrainConfig
+    from deepfake_detection_tpu.models import init_model
     from deepfake_detection_tpu.parallel import (batch_sharding,
                                                  make_train_mesh,
                                                  replicated_sharding,
                                                  train_state_shardings)
-    from deepfake_detection_tpu.train import (create_train_state,
-                                              make_train_step)
+    from deepfake_detection_tpu.runners import train as T
+    from deepfake_detection_tpu.train import create_train_state
 
     n_needed = max(b * m for b, m in shapes)
     devs = jax.devices()
@@ -80,16 +80,13 @@ def run_matrix(shapes, model_name: str, size: int, batch_per_dp: int,
             f"need {n_needed} devices, have {len(devs)} — run through the "
             "parent mode (it forces the virtual device count)")
 
-    model = create_model(model_name, num_classes=2, in_chans=3,
-                         drop_rate=0.0)
-    variables = init_model(model, jax.random.PRNGKey(0),
-                           (2, size, size, 3), training=True)
-    tx = create_optimizer(SimpleNamespace(
-        opt="sgd", opt_eps=1e-8, momentum=0.9, weight_decay=0.0, lr=1e-3),
-        inject=True)
-    # donate=False: the SAME eager state seeds every topology's table
-    state = create_train_state(variables, tx, donate=False)
-    n_params = sum(x.size for x in jax.tree.leaves(state.params))
+    # the step is the runner's for these flags, on each topology's mesh
+    cfg = TrainConfig.from_args([
+        "--model", model_name, "--model-version", "", "--dataset",
+        "synthetic", "--input-size-v2", f"3,{size},{size}", "-b",
+        str(batch_per_dp), "--opt", "sgd", "--lr", "1e-3", "--drop", "0.0",
+        "--compute-dtype", "float32"])
+    state = None
 
     # production-default rows (replicated params) for every topology, plus
     # ONE fsdp row on the first multi-device shape: without it every
@@ -106,16 +103,24 @@ def run_matrix(shapes, model_name: str, size: int, batch_per_dp: int,
         n = b_ax * m_ax
         mesh = make_train_mesh(batch=b_ax, model=m_ax,
                                devices=devs[:n])
-        shardings = train_state_shardings(state, mesh, fsdp=fsdp)
+        program = T.build_program(dataclasses.replace(cfg, fsdp=fsdp),
+                                  mesh=mesh)
+        if state is None:
+            # donate=False: the SAME eager state seeds every topology's
+            # table (each program's optimizer has the one structure)
+            variables = init_model(program.model, jax.random.PRNGKey(0),
+                                   (2, size, size, 3), training=True)
+            state = create_train_state(variables, program.tx, donate=False)
+            n_params = sum(x.size for x in jax.tree.leaves(state.params))
+        shardings = train_state_shardings(state, mesh, fsdp=fsdp,
+                                          axis=program.batch_axis)
         batch_sh = batch_sharding(mesh)
         rep = replicated_sharding(mesh)
-        step = make_train_step(model, tx, cross_entropy, mesh=mesh,
-                               bn_mode="local", nonfinite_guard=True,
-                               donate=True, state_shardings=shardings)
+        step, _, _ = T.build_steps(program, shardings)
         st_abs = jax.tree.map(
             lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
             state, shardings)
-        B = batch_per_dp * b_ax
+        B = program.global_batch
         x_abs = jax.ShapeDtypeStruct((B, size, size, 3), jnp.float32,
                                      sharding=batch_sh)
         y_abs = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=batch_sh)
@@ -217,17 +222,11 @@ def parent_main(args) -> int:
         return r.returncode or 1
     doc = json.loads(r.stdout.strip().splitlines()[-1])
     doc["host"] = os.uname().nodename
-    out = args.out or os.path.join(REPO, "MULTICHIP_AOT.json")
-    with open(out + ".tmp", "w") as f:
-        json.dump(doc, f, indent=2)
-        f.write("\n")
-    os.replace(out + ".tmp", out)
-    for row in doc["rows"]:
-        print(f"mesh {tuple(row['mesh_shape'])}: "
-              f"lower {row['lower_s']}s compile {row['compile_s']}s "
-              f"hlo {row['hlo_bytes']}B specs_ok={row['specs_ok']} "
-              f"donation={row['donation_preserved']}")
-    print(f"wrote {out} (ok={doc['ok']})")
+    if args.out:
+        with open(args.out, "w") as f:
+            json.dump(doc, f, indent=2)
+    # the child's stderr, relayed above, has a line for each row
+    print(f"ok={doc['ok']}" + (f", wrote {args.out}" if args.out else ""))
     return 0 if doc["ok"] else 1
 
 
@@ -244,7 +243,8 @@ def main(argv=None) -> int:
     ap.add_argument("--size", type=int, default=32)
     ap.add_argument("--batch-per-dp", type=int, default=2)
     ap.add_argument("--timeout", type=int, default=480)
-    ap.add_argument("--out", default=None)
+    ap.add_argument("--out", default=None,
+                    help="also write the JSON verdict to this file")
     ap.add_argument("--child", action="store_true",
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)

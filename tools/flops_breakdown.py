@@ -1,19 +1,7 @@
 """MXU/VPU FLOPs breakdown of a model's forward pass (PERF.md input).
 
-Walks the jaxpr of a single-sample forward and classifies every
-``conv_general_dilated`` / ``dot_general`` by where it executes on TPU:
-
-* dense convs and matmuls tile onto the MXU (the 128×128 systolic array);
-* depthwise convs (``feature_group_count == in_channels``) cannot use the
-  MXU — each output element is a k²-tap dot over ONE channel, so they run
-  on the VPU at roughly 1-2% of MXU throughput;
-* grouped-but-not-depthwise convs tile partially (classified separately);
-* the network STEM (the conv consuming the raw ``in_chans``-channel input)
-  is split out with its contraction depth ``K = kh·kw·cin`` and MXU lane
-  occupancy ``K/128``: a 3-channel stem feeds 27 of 128 lanes, and the
-  space-to-depth rewrite (``--stem-s2d``, ops/conv.py) is reclassified
-  from the flag-built model's OWN jaxpr (2×2 kernel over 4C channels),
-  not from assumptions.
+CLI and roofline over the package's walk (``obs/flops.py``: the count under
+the live MFU gauge, whose docstring says how each op is classified).
 
 ``--ceilings`` turns the placement split into the PERF.md §2 roofline.
 The headline ``mfu_ceiling_post_fusion`` is §2's compute-only arithmetic
@@ -38,87 +26,16 @@ import argparse
 import json
 import os
 import sys
-from collections import defaultdict
-
-import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from deepfake_detection_tpu.obs.flops import analyze  # noqa: E402
 
 # v5e rates used by PERF.md §2 (bf16 MXU; VPU at 2-way bf16 packing; HBM)
 R_MXU = 197e12
 R_VPU = 15.4e12
 BW_HBM = 819e9
 BYTES = 2          # bf16 end-to-end on the hot path
-
-
-def conv_flops(eqn) -> float:
-    out = eqn.outvars[0].aval
-    rhs = eqn.invars[1].aval          # kernel (H, W, Cin/g, Cout)
-    # 2 * output elements * taps per output element
-    kh, kw, cin_per_group, _ = rhs.shape
-    return 2.0 * float(np.prod(out.shape)) * kh * kw * cin_per_group
-
-
-def dot_flops(eqn) -> float:
-    lhs = eqn.invars[0].aval
-    out = eqn.outvars[0].aval
-    ((lc, _), _) = eqn.params["dimension_numbers"]
-    k = float(np.prod([lhs.shape[i] for i in lc]))
-    return 2.0 * float(np.prod(out.shape)) * k
-
-
-def analyze(model, variables, x, in_chans: int):
-    """Placement buckets + the quantities the roofline needs.
-
-    Returns ``(buckets, stem, dw_out_elems)``: FLOPs per class; stem
-    diagnostics (kernel, contraction depth K, lane occupancy, flops) for
-    the conv(s) consuming the raw ``in_chans``-channel input (4·in_chans
-    when the model was built with ``stem_s2d``); and the total output
-    element count of the depthwise convs (operand of the unfused-epilogue
-    HBM term).
-    """
-    import jax
-
-    jaxpr = jax.make_jaxpr(
-        lambda v, x: model.apply(v, x, training=False))(variables, x)
-    buckets = defaultdict(float)
-    stem = {"flops": 0.0, "convs": []}
-    stem_chans = (in_chans, 4 * in_chans)   # raw or space-to-depth input
-    dw_out_elems = 0.0
-
-    def walk(jx):
-        nonlocal dw_out_elems
-        for eqn in jx.eqns:
-            for sub in (v for v in eqn.params.values()
-                        if hasattr(v, "jaxpr")):
-                walk(sub.jaxpr)
-            if eqn.primitive.name == "conv_general_dilated":
-                g = eqn.params["feature_group_count"]
-                cin = eqn.invars[0].aval.shape[-1]
-                f = conv_flops(eqn)
-                if g == 1 and cin in stem_chans and not stem["convs"]:
-                    kh, kw, _, _ = eqn.invars[1].aval.shape
-                    k_depth = kh * kw * cin
-                    buckets["conv_stem_mxu"] += f
-                    stem["flops"] += f
-                    stem["convs"].append({
-                        "kernel": f"{kh}x{kw}x{cin}",
-                        "contraction_depth": k_depth,
-                        "mxu_lane_occupancy": round(min(1.0, k_depth / 128.0),
-                                                    4),
-                    })
-                elif g == 1:
-                    buckets["conv_dense_mxu"] += f
-                elif g == cin:
-                    buckets["conv_depthwise_vpu"] += f
-                    dw_out_elems += float(np.prod(eqn.outvars[0].aval.shape))
-                else:
-                    buckets["conv_grouped_partial"] += f
-            elif eqn.primitive.name == "dot_general":
-                buckets["dot_mxu"] += dot_flops(eqn)
-
-    walk(jaxpr.jaxpr)
-    return dict(buckets), stem, dw_out_elems
 
 
 def mfu_ceilings(buckets, dw_out_elems: float,

@@ -2,7 +2,7 @@
 """Summarize a run's telemetry JSONL into the INPUT_BENCH/PERF table shape.
 
 The live telemetry (obs/) and the offline bench docs (INPUT_BENCH.md,
-PERF.md, bench.py rows) should speak one vocabulary — imgs/s, ms/step,
+PERF.md) should speak one vocabulary — imgs/s, ms/step,
 MFU, wait fractions — so a run's in-flight numbers drop straight into the
 same tables the chip-gated verification items use.  Usage::
 
