@@ -20,9 +20,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.activations import get_act_fn
-from ..ops.conv import (CondConv2d, Conv2d, MixedConv2d,
-                        conv_kernel_init_goog, create_conv2d,
-                        space_to_depth_stem_kernel)
+from ..ops.conv import (CondConv2d, Conv2d, MixedConv2d, _Kernel,
+                        create_conv2d, space_to_depth_stem_kernel)
 from ..ops.drop import DropPath
 from ..ops.norm import BatchNorm2d, GroupNorm, Identity
 
@@ -69,15 +68,6 @@ def _norm(norm_layer: str, momentum, eps, axis_name, dtype, name):
 # declare the same nested params the stock Conv2d / BatchNorm2d modules
 # would, while the compute happens outside them.
 # ---------------------------------------------------------------------------
-
-class _Kernel(nn.Module):
-    """Declares ``kernel`` exactly like ``nn.Conv`` (goog init, f32)."""
-    shape: Tuple[int, ...]
-
-    @nn.compact
-    def __call__(self):
-        return self.param("kernel", conv_kernel_init_goog, self.shape)
-
 
 class _DwConvParams(nn.Module):
     """Param mirror of ``Conv2d(name='conv_dw')``: path conv_dw/conv/kernel

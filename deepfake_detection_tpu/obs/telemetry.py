@@ -44,7 +44,7 @@ import os
 import threading
 import time
 from collections import OrderedDict
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from jax import monitoring
 
@@ -181,6 +181,12 @@ _GAUGE_CATALOG = (
      "e.g. CPU)"),
     ("model_fwd_gflops_per_sample", "Per-sample forward GFLOPs feeding "
      "the MFU gauge (obs/flops.py)"),
+    ("dw_grad_kernel_stages", "Depthwise stages of the train step whose "
+     "filter gradient is the reduction kernel (ops/conv.py: "
+     "dw_grad_impl decides from shapes, dtype and device count; a census "
+     "fixed by the shapes)"),
+    ("dw_grad_xla_stages", "Depthwise stages of the train step that keep "
+     "XLA's own filter gradient"),
     ("restart_count", "Restart-wrapper relaunches of this run "
      "(DFD_RESTART_COUNT)"),
     ("watchdog_beat_age_s", "Seconds since the last watchdog heartbeat"),
@@ -200,7 +206,8 @@ class TrainTelemetry:
                  flops_per_sample: float = 0.0,
                  peak_flops: float = 0.0,
                  meta: Optional[Dict[str, Any]] = None,
-                 attn_tiles_per_sample: int = 0):
+                 attn_tiles_per_sample: int = 0,
+                 dw_grad_stages: Tuple[int, int] = (0, 0)):
         self.event_log = event_log
         self.flops_per_sample = float(flops_per_sample)
         # attention-kernel cells a step visits per row: a sequence model's
@@ -222,6 +229,9 @@ class TrainTelemetry:
         self._g["up"] = 1.0
         self._g["model_fwd_gflops_per_sample"] = round(
             self.flops_per_sample / 1e9, 3)
+        # (kernel, xla): the program's census of its depthwise stages
+        self._g["dw_grad_kernel_stages"] = float(dw_grad_stages[0])
+        self._g["dw_grad_xla_stages"] = float(dw_grad_stages[1])
         self._g["restart_count"] = float(
             os.environ.get("DFD_RESTART_COUNT", 0) or 0)
         self.h_step = LatencyHistogram(_STEP_BOUNDS)

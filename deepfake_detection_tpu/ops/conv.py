@@ -19,7 +19,9 @@ Layout is NHWC, kernels HWIO (XLA/TPU native).
 
 from __future__ import annotations
 
+import contextlib
 import math
+import threading
 from typing import Any, Callable, Optional, Sequence, Tuple, Union
 
 import flax.linen as nn
@@ -76,8 +78,131 @@ def dense_init_goog(key, shape, dtype=jnp.float32):
     return jax.random.uniform(key, shape, dtype, -init_range, init_range)
 
 
+# ---------------------------------------------------------------------------
+# who computes a depthwise stage's filter gradient (ops/depthwise_pallas.py
+# has the kernel; the stage that asks is Conv2d below)
+# ---------------------------------------------------------------------------
+
+class _GradScope(threading.local):
+    devices = 1         # a program nobody described runs on one device
+    platform = None     # None: jax.default_backend()
+    census = None
+
+
+_grad_scope = _GradScope()
+
+
+@contextlib.contextmanager
+def dw_grad_scope(devices: int = 1, platform: Optional[str] = None,
+                  census: Optional[dict] = None):
+    """TRACE-time description of the program a depthwise stage is traced
+    into (``ops/norm.py:local_stats_scope``'s idiom): how many devices
+    compile it and, where the process's own backend is not the target (a
+    described chip, a test), for which platform.  ``make_train_step``
+    enters it with its mesh's size.  ``census``, a dict, collects the stages
+    traced inside by the implementation each got (:func:`count_stage`)."""
+    was = _grad_scope.devices, _grad_scope.platform, _grad_scope.census
+    _grad_scope.devices = int(devices)
+    # what an inner scope does not say, the enclosing one still does
+    _grad_scope.platform = platform or was[1]
+    _grad_scope.census = census if census is not None else was[2]
+    try:
+        yield
+    finally:
+        (_grad_scope.devices, _grad_scope.platform,
+         _grad_scope.census) = was
+
+
+#: from this batch on XLA's own filter gradient is one fusion (see
+#: :func:`dw_grad_impl`)
+_XLA_SOUND_BATCH = 8
+
+
+def dw_grad_impl(x_shape, kernel_size: int, stride: int, dtype,
+                 devices: Optional[int] = None,
+                 platform: Optional[str] = None) -> str:
+    """``'kernel'`` or ``'xla'``: who computes the filter gradient of one
+    depthwise stage, from what the trace can observe: the static shapes,
+    the operand dtype, the devices that compile the program and their
+    platform (default: the enclosing :func:`dw_grad_scope`).
+
+    At small batch XLA's TPU compiler rewrites the stage space-to-batch and
+    feeds the filter gradient a k-fold copy of its input, built by whole
+    passes over that copy; from batch 8 on it keeps the gradient one sound
+    fusion.  The table behind the rule is PERF.md section 6 (PR 29's
+    probe).  The kernel has no partitioning rule, so a program over several
+    devices keeps XLA's gradient; off the TPU the kernel could only be
+    interpreted, which is a test's business (``dw_grad_scope(platform=)``)
+    and never a path."""
+    devices = _grad_scope.devices if devices is None else devices
+    platform = platform or _grad_scope.platform or jax.default_backend()
+    if platform != "tpu" or devices > 1:
+        return "xla"
+    b = int(x_shape[0])
+    if int(stride) not in (1, 2) or b >= _XLA_SOUND_BATCH:
+        return "xla"
+    return "kernel"
+
+
+def count_stage(impl: str, x_shape, kernel_size: int, stride: int) -> None:
+    """One depthwise stage traced with ``impl``, into the enclosing scope's
+    census where it keeps one: ``{impl: [(x_shape, k, stride), ...]}``."""
+    if _grad_scope.census is not None:
+        _grad_scope.census.setdefault(impl, []).append(
+            (tuple(int(d) for d in x_shape), int(kernel_size), int(stride)))
+
+
+def dw_grad_census(model, x_shape, dtype, devices: int = 1,
+                   platform: Optional[str] = None) -> dict:
+    """The depthwise stages of one training trace of ``model`` over an
+    ``x_shape`` batch, by implementation (:func:`count_stage`'s dict):
+    fixed by the shapes, so one abstract trace finds it."""
+    census: dict = {}
+    key = jax.random.PRNGKey(0)
+    with dw_grad_scope(devices, platform, census):
+        jax.eval_shape(lambda: model.init(
+            {"params": key, "dropout": key}, jnp.zeros(x_shape, dtype),
+            training=True))
+    return census
+
+
+class _Kernel(nn.Module):
+    """Declares ``kernel`` exactly like ``nn.Conv`` (same name, init, f32),
+    for the paths that compute the convolution themselves."""
+    shape: Tuple[int, ...]
+    kernel_init: Callable = conv_kernel_init_goog
+
+    @nn.compact
+    def __call__(self):
+        return self.param("kernel", self.kernel_init, self.shape)
+
+
+def _kernel_grad_stage(conv: "Conv2d", x, ks, strides) -> bool:
+    """Whether this call of ``conv`` is a depthwise stage that takes the
+    kernel's filter gradient; counts the stage either way.  (A function,
+    not a method: flax would record a method's result among the module's
+    intermediates.)"""
+    if not (conv.depthwise and x.ndim == 4 and not conv.use_bias
+            and ks[0] == ks[1] and strides[0] == strides[1]
+            and _to_tuple(conv.dilation) == (1, 1)
+            and conv.groups == conv.out_chs == x.shape[-1]):
+        return False
+    dtype = conv.dtype or jnp.promote_types(x.dtype, jnp.float32)
+    impl = dw_grad_impl(x.shape, ks[0], strides[0], dtype)
+    count_stage(impl, x.shape, ks[0], strides[0])
+    return impl == "kernel"
+
+
 class Conv2d(nn.Module):
-    """NHWC conv; depthwise via ``groups == in_chs`` like the reference factory."""
+    """NHWC conv; depthwise via ``groups == in_chs`` like the reference factory.
+
+    ``depthwise`` (set by :func:`create_conv2d` for a single square
+    depthwise kernel) lets the stage ask :func:`dw_grad_impl` who computes
+    its filter gradient.  Where the answer is
+    XLA the stage is the plain ``nn.Conv`` call below with nothing around
+    it; where it is the kernel, the same parameter (``conv/kernel``), the
+    same forward and the same input gradient, and ``dW`` by one reduction
+    (``depthwise_conv``)."""
     out_chs: int
     kernel_size: Union[int, Tuple[int, int]] = 3
     stride: Union[int, Tuple[int, int]] = 1
@@ -87,18 +212,27 @@ class Conv2d(nn.Module):
     use_bias: bool = False
     kernel_init: Callable = conv_kernel_init_goog
     dtype: Any = None
+    depthwise: bool = False
 
     @nn.compact
     def __call__(self, x):
         ks = _to_tuple(self.kernel_size)
+        strides = _to_tuple(self.stride)
+        padding = resolve_padding(self.padding, ks, self.dilation,
+                                  self.stride)
+        if _kernel_grad_stage(self, x, ks, strides):
+            from .depthwise_pallas import depthwise_conv
+            kernel = _Kernel(ks + (1, self.out_chs), self.kernel_init,
+                             name="conv")()
+            return depthwise_conv(x, kernel, stride=strides[0],
+                                  padding=padding, dtype=self.dtype)
         return nn.Conv(
             features=self.out_chs,
             kernel_size=ks,
-            strides=_to_tuple(self.stride),
+            strides=strides,
             kernel_dilation=_to_tuple(self.dilation),
             feature_group_count=self.groups,
-            padding=resolve_padding(self.padding, ks, self.dilation,
-                                    self.stride),
+            padding=padding,
             use_bias=self.use_bias,
             kernel_init=self.kernel_init,
             dtype=self.dtype,
@@ -278,8 +412,7 @@ def create_conv2d(out_chs: int, kernel_size, **kwargs) -> nn.Module:
         return MixedConv2d(out_chs, kernel_size, depthwise=depthwise, **kwargs)
     if isinstance(kernel_size, (list, tuple)):
         kernel_size = kernel_size[0]
-    depthwise = kwargs.pop("depthwise", False)
-    if depthwise:
+    if kwargs.get("depthwise", False):
         kwargs["groups"] = out_chs
     if kwargs.pop("num_experts", 0):
         raise ValueError("use CondConv2d directly; it needs routing weights")

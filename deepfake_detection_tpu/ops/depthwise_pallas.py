@@ -31,6 +31,13 @@ Kernel structure (same conventions as ``ops/flash_attention.py``):
   ``dscale``/``dbias`` reductions stay in XLA where they fuse with the
   activation-gradient elementwise pass.
 
+The ``dw`` reduction also serves the DEFAULT path (PR 29): where
+``ops/conv.py:dw_grad_impl`` says so (small batch on one TPU device, where
+XLA would feed its own filter gradient a k-fold copy of the input),
+``Conv2d`` computes a depthwise stage through :func:`depthwise_conv` —
+XLA's convolution and input gradient untouched, ``dW`` by this kernel from
+bf16 operands read once.
+
 On non-TPU backends the kernels run under the Pallas interpreter
 (``interpret=True``), which is how the CPU suite checks forward AND
 gradient parity against the XLA lowering (tests/test_depthwise_pallas.py).
@@ -53,7 +60,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .conv import resolve_padding
 from .flash_attention import _out_struct, _scratch, resolve_interpret
 
-__all__ = ["fused_depthwise", "FUSED_DW_ACTS"]
+__all__ = ["fused_depthwise", "FUSED_DW_ACTS", "depthwise_conv"]
 
 #: epilogue activations the kernel fuses; anything else runs act in XLA
 FUSED_DW_ACTS = ("none", "silu", "relu")
@@ -94,6 +101,19 @@ def _to_tuple(v) -> Tuple[int, int]:
     return (v, v) if isinstance(v, int) else tuple(v)
 
 
+def _explicit_pads(pad, x_shape, ks, stride: int):
+    """``((lo, hi), (lo, hi))`` of ints from a :func:`resolve_padding`
+    result (``'SAME'`` is TF's: the odd pixel goes to the end)."""
+    if pad == "SAME":
+        def _same(n, k):
+            need = max((-(-n // stride) - 1) * stride + k - n, 0)
+            return (need // 2, need - need // 2)
+        return (_same(x_shape[1], ks[0]), _same(x_shape[2], ks[1]))
+    if pad == "VALID":
+        return ((0, 0), (0, 0))
+    return tuple(tuple(int(p) for p in pr) for pr in pad)
+
+
 def _pick_block_h(wph: int, ct: int, kh: int, stride: int,
                   ho: int, budget: int = 2 * 1024 * 1024) -> int:
     """Largest output-rows-per-tile whose f32 input halo block (all stride²
@@ -114,20 +134,23 @@ def _channel_tile(c: int) -> int:
     return c
 
 
-def _halo_tiles(xp, kh: int, kw: int, stride: int, ho: int, wo: int):
-    """Phase-split, tile-padded input + its halo BlockSpec factory.
+def _halo_tiles(x, pads, kh: int, kw: int, stride: int, ho: int, wo: int):
+    """Phase-split, conv- and tile-padded input + its halo BlockSpec factory.
 
     Mosaic has neither a strided slice of a loaded value nor a strided load
     of packed (bf16) data, so a stride-``s`` conv is fed as its ``s²``
     polyphase components — ``xph[b, p·s+q, i, j] = xp[b, i·s+p, j·s+q]`` —
     and tap ``(r, c)`` becomes a unit-stride window of phase ``(r%s, c%s)``
     at offset ``(r//s, c//s)``.  Stride 1 is the single-phase case (a free
-    reshape).  Rows are padded so every H tile's halo block is in-bounds.
+    reshape).  ``pads`` is the conv's ``((lo, hi), (lo, hi))``; it and the
+    rows that keep every H tile's halo block in-bounds go on in ONE pad, in
+    the operand's own dtype.
 
     Returns ``(xph, th_out, n_h, spec)`` with ``spec(b_of, c_of, h_of)``
     building the element-indexed input BlockSpec from grid-index pickers.
     """
-    b, hp, wp, c = xp.shape
+    b, h, w, c = x.shape
+    (ph0, _), (pw0, _) = pads
     ct = _channel_tile(c)
     wph = wo + (kw - 1) // stride
     th_out = _pick_block_h(wph, ct, kh, stride, ho)
@@ -135,9 +158,9 @@ def _halo_tiles(xp, kh: int, kw: int, stride: int, ho: int, wo: int):
     th_in = th_out + (kh - 1) // stride
     hph = (n_h - 1) * th_out + th_in
     # lax.pad: negative high padding crops rows/cols no tap window reaches
-    xp = lax.pad(xp, jnp.zeros((), xp.dtype),
-                 ((0, 0, 0), (0, hph * stride - hp, 0),
-                  (0, wph * stride - wp, 0), (0, 0, 0)))
+    xp = lax.pad(x, jnp.zeros((), x.dtype),
+                 ((0, 0, 0), (ph0, hph * stride - h - ph0, 0),
+                  (pw0, wph * stride - w - pw0, 0), (0, 0, 0)))
     xph = xp.reshape(b, hph, stride, wph, stride, c)
     xph = xph.transpose(0, 2, 4, 1, 3, 5).reshape(
         b, stride * stride, hph, wph, c)
@@ -189,15 +212,15 @@ def _fwd_kernel(x_ref, w_ref, s_ref, b_ref, y_ref, *z_ref, stride, kh, kw,
     y_ref[0] = _act_f32(act)(u).astype(y_ref.dtype)
 
 
-def _dw_call(xp, w, scale, bias, *, stride, act, ho, wo, out_dtype,
+def _dw_call(x, pads, w, scale, bias, *, stride, act, ho, wo, out_dtype,
              want_z, interpret):
-    """Padded-layout forward: ``xp (B, Hp, Wp, C)`` carries the conv
-    padding; returns ``y (B, Ho, Wo, C)`` and (when ``want_z``) the f32
-    pre-affine conv output for the backward."""
-    b, c = xp.shape[0], xp.shape[-1]
+    """Forward over ``x (B, H, W, C)`` under the conv padding ``pads``;
+    returns ``y (B, Ho, Wo, C)`` and (when ``want_z``) the f32 pre-affine
+    conv output for the backward."""
+    b, c = x.shape[0], x.shape[-1]
     kh, kw = w.shape[0], w.shape[1]
     ct = _channel_tile(c)
-    xph, th_out, n_h, x_spec = _halo_tiles(xp, kh, kw, stride, ho, wo)
+    xph, th_out, n_h, x_spec = _halo_tiles(x, pads, kh, kw, stride, ho, wo)
     # tiling may overshoot Ho (last tile); the overshoot rows are sliced off
     ho_p = n_h * th_out
 
@@ -212,12 +235,12 @@ def _dw_call(xp, w, scale, bias, *, stride, act, ho, wo, out_dtype,
     out_spec = _vmem_spec((1, th_out, wo, ct),
                           lambda bi, ci, hi: (bi, hi, 0, ci))
     out_specs = [out_spec]
-    out_shape = [_out_struct((b, ho_p, wo, c), out_dtype, xp)]
+    out_shape = [_out_struct((b, ho_p, wo, c), out_dtype, x)]
     if want_z:
         # f32 pre-affine conv output, saved as the backward's residual —
         # only the residual-saving forward pays for this buffer
         out_specs.append(out_spec)
-        out_shape.append(_out_struct((b, ho_p, wo, c), jnp.float32, xp))
+        out_shape.append(_out_struct((b, ho_p, wo, c), jnp.float32, x))
     kern = functools.partial(_fwd_kernel, stride=stride, kh=kh, kw=kw,
                              th_out=th_out, wo=wo, act=act)
     out = pl.pallas_call(
@@ -235,10 +258,13 @@ def _dw_call(xp, w, scale, bias, *, stride, act, ho, wo, out_dtype,
 # ---------------------------------------------------------------------------
 
 def _dwgrad_kernel(x_ref, dz_ref, dw_ref, acc_ref, *, stride, kh, kw, th_out,
-                   wo):
+                   wo, ho):
     """One (c-tile, b, h-tile) grid cell accumulating ``dw[r·kw+s, c] +=
     Σ_{rows,cols} dz ⊙ x_shift(r,s)`` into VMEM scratch; written once at the
-    last (b, h) step."""
+    last (b, h) step.  Operands arrive in their own dtype (bf16 on the hot
+    path) and are widened here, once a block.  A column offset is a sublane
+    shift, a row offset only picks other rows: each of the ``kw`` shifted
+    slabs is made once and serves its ``kh`` taps."""
     bi = pl.program_id(1)
     hi = pl.program_id(2)
     nb = pl.num_programs(1)
@@ -248,30 +274,37 @@ def _dwgrad_kernel(x_ref, dz_ref, dw_ref, acc_ref, *, stride, kh, kw, th_out,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    tap = _tap_reader(x_ref, stride, th_out, wo)
     dzv = dz_ref[0].astype(jnp.float32)
-    for r in range(kh):
-        for s in range(kw):
-            acc_ref[r * kw + s, :] += jnp.sum(tap(r, s) * dzv, axis=(0, 1))
+    if ho % th_out:
+        # the last H tile overshoots dz: what lies past its end is not
+        # zeros (whatever the buffer held), so it is masked, not multiplied
+        row = lax.broadcasted_iota(jnp.int32, dzv.shape, 0)
+        dzv = jnp.where(row < ho - hi * th_out, dzv, 0.0)
+    xv = x_ref[0].astype(jnp.float32)
+    for s in range(kw):
+        c0 = s // stride
+        slabs = [xv[p * stride + s % stride, :, c0:c0 + wo, :]
+                 for p in range(stride)]
+        for r in range(kh):
+            r0 = r // stride
+            prod = slabs[r % stride][r0:r0 + th_out] * dzv
+            acc_ref[r * kw + s, :] += jnp.sum(jnp.sum(prod, axis=0), axis=0)
 
     @pl.when(jnp.logical_and(bi == nb - 1, hi == nh - 1))
     def _finalize():
         dw_ref[:] = acc_ref[:]
 
 
-def _dwgrad_call(xp, dz, kh, kw, *, stride, ho, wo, interpret):
-    """dw (kh, kw, C) from the padded input and the (zero-padded to the tile
-    grid) upstream conv-output gradient."""
-    b, c = xp.shape[0], xp.shape[-1]
+def _dwgrad_call(x, dz, pads, kh, kw, *, stride, ho, wo, interpret):
+    """dw ``(kh, kw, C)`` float32 from the conv's input and the upstream
+    conv-output gradient, each read from HBM once in its own dtype (the
+    input through one pad, and for stride 2 the polyphase split, in XLA)."""
+    b, c = x.shape[0], x.shape[-1]
     ct = _channel_tile(c)
-    xph, th_out, n_h, x_spec = _halo_tiles(xp, kh, kw, stride, ho, wo)
-    ho_p = n_h * th_out
-    if ho_p > ho:
-        # zero rows contribute nothing to the correlation
-        dz = jnp.pad(dz, ((0, 0), (0, ho_p - ho), (0, 0), (0, 0)))
+    xph, th_out, n_h, x_spec = _halo_tiles(x, pads, kh, kw, stride, ho, wo)
 
     kern = functools.partial(_dwgrad_kernel, stride=stride, kh=kh, kw=kw,
-                             th_out=th_out, wo=wo)
+                             th_out=th_out, wo=wo, ho=ho)
     dw = pl.pallas_call(
         kern,
         grid=(c // ct, b, n_h),
@@ -282,7 +315,7 @@ def _dwgrad_call(xp, dz, kh, kw, *, stride, ho, wo, interpret):
                        lambda ci, bi, hi: (bi, hi, 0, ci)),
         ],
         out_specs=_vmem_spec((kh * kw, ct), lambda ci, bi, hi: (0, ci)),
-        out_shape=_out_struct((kh * kw, c), jnp.float32, xp),
+        out_shape=_out_struct((kh * kw, c), jnp.float32, x),
         scratch_shapes=[_scratch((kh * kw, ct))],
         interpret=interpret,
     )(xph, dz)
@@ -327,15 +360,9 @@ def fused_depthwise(x: jnp.ndarray, w: jnp.ndarray,
     kh, kw = int(w.shape[0]), int(w.shape[1])
     interpret = resolve_interpret(interpret, "fused_depthwise")
 
-    pad = resolve_padding(padding, (kh, kw), 1, stride)
-    if pad == "SAME":
-        def _same(n, k):
-            need = max((-(-n // stride) - 1) * stride + k - n, 0)
-            return (need // 2, need - need // 2)
-        pad = [_same(x.shape[1], kh), _same(x.shape[2], kw)]
-    elif pad == "VALID":
-        pad = [(0, 0), (0, 0)]
-    (ph0, ph1), (pw0, pw1) = [tuple(int(p) for p in pr) for pr in pad]
+    (ph0, ph1), (pw0, pw1) = _explicit_pads(
+        resolve_padding(padding, (kh, kw), 1, stride), x.shape, (kh, kw),
+        stride)
 
     b, h, wdim, c = x.shape
     hp, wp = h + ph0 + ph1, wdim + pw0 + pw1
@@ -357,19 +384,18 @@ def fused_depthwise(x: jnp.ndarray, w: jnp.ndarray,
     # re-add the full-size f32 HBM write the fusion exists to remove
     needs_z = has_affine or act != "none"
 
-    def _pad_x(xv):
-        return jnp.pad(xv, ((0, 0), (ph0, ph1), (pw0, pw1), (0, 0)))
+    pads = ((ph0, ph1), (pw0, pw1))
 
     @jax.custom_vjp
     def _op(xv, wv, sv, bv):
-        y, _ = _dw_call(_pad_x(xv), wv, sv.reshape(1, c), bv.reshape(1, c),
+        y, _ = _dw_call(xv, pads, wv, sv.reshape(1, c), bv.reshape(1, c),
                         stride=stride, act=act, ho=ho, wo=wo,
                         out_dtype=out_dtype, want_z=False,
                         interpret=interpret)
         return y
 
     def _op_fwd(xv, wv, sv, bv):
-        y, z = _dw_call(_pad_x(xv), wv, sv.reshape(1, c), bv.reshape(1, c),
+        y, z = _dw_call(xv, pads, wv, sv.reshape(1, c), bv.reshape(1, c),
                         stride=stride, act=act, ho=ho, wo=wo,
                         out_dtype=out_dtype, want_z=needs_z,
                         interpret=interpret)
@@ -403,17 +429,83 @@ def fused_depthwise(x: jnp.ndarray, w: jnp.ndarray,
         zeros = jnp.zeros((1, c), jnp.float32)
         dxh = (ho - 1) * stride + kh      # rows of xp that received taps
         dxw = (wo - 1) * stride + kw
-        dx_p, _ = _dw_call(dzd, wf, ones, zeros, stride=1, act="none",
-                           ho=dxh, wo=dxw, out_dtype=jnp.float32,
-                           want_z=False, interpret=interpret)
+        dx_p, _ = _dw_call(dzd, ((0, 0), (0, 0)), wf, ones, zeros, stride=1,
+                           act="none", ho=dxh, wo=dxw,
+                           out_dtype=jnp.float32, want_z=False,
+                           interpret=interpret)
         # rows/cols of the padded input beyond the last tap window got no
         # gradient; re-inflate to (Hp, Wp) then strip the conv padding
         dx_p = jnp.pad(dx_p, ((0, 0), (0, hp - dxh), (0, wp - dxw), (0, 0)))
         dx = dx_p[:, ph0:ph0 + h, pw0:pw0 + wdim]
-        dw = _dwgrad_call(_pad_x(xv.astype(jnp.float32)), dz, kh, kw,
+        # identity epilogue: the kernel reads the upstream gradient as it
+        # came (bf16 on the hot path), not a float32 copy of it
+        dw = _dwgrad_call(xv, dz if needs_z else g, pads, kh, kw,
                           stride=stride, ho=ho, wo=wo, interpret=interpret)
         return (dx.astype(xv.dtype), dw.astype(wv.dtype),
                 dscale.astype(sv.dtype), dbias.astype(bv.dtype))
 
     _op.defvjp(_op_fwd, _op_bwd)
     return _op(x, w32, scale32, bias32)
+
+
+# ---------------------------------------------------------------------------
+# the default path's depthwise stage: XLA's convolution, the kernel's dW
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.jit, static_argnames=("pads", "k", "stride",
+                                             "interpret"))
+def dw_filter_grad(x, g, *, pads, k, stride, interpret):
+    """One trace and one lowering a distinct stage shape, however many
+    blocks of the model share it."""
+    return _dwgrad_call(x, g, pads, k, k, stride=stride, ho=g.shape[1],
+                        wo=g.shape[2], interpret=interpret
+                        ).reshape(k, k, 1, x.shape[-1])
+
+
+def depthwise_conv(x: jnp.ndarray, kernel: jnp.ndarray, *, stride: int,
+                   padding, dtype=None,
+                   interpret: Optional[bool] = None) -> jnp.ndarray:
+    """``flax.linen.Conv``'s depthwise convolution (``kernel`` HWIO
+    ``(k, k, 1, C)``, ``padding`` what :func:`ops.conv.resolve_padding`
+    gave) whose filter gradient is the reduction kernel.
+
+    The forward and the input gradient are XLA's own convolution and its
+    transpose, the same primitives on the same operands as ``nn.Conv``
+    traces, so their values are that path's bit for bit.  ``dW`` reads
+    ``x`` and the upstream gradient once, accumulates the k² taps in
+    float32 and is rounded once, to the parameter's dtype."""
+    k, c = int(kernel.shape[0]), int(x.shape[-1])
+    assert kernel.shape == (k, k, 1, c), (kernel.shape, x.shape)
+    if dtype is not None:
+        x = x.astype(dtype)
+    else:
+        x = x.astype(jnp.promote_types(x.dtype, kernel.dtype))
+    pads = _explicit_pads(padding, x.shape, (k, k), stride)
+    interpret = resolve_interpret(interpret, "depthwise_conv filter gradient")
+
+    def conv(xv, wv):
+        # nn.Conv's call, argument for argument (flax/linen/linear.py)
+        return lax.conv_general_dilated(
+            xv, wv.astype(xv.dtype), (stride, stride), padding,
+            lhs_dilation=(1, 1), rhs_dilation=(1, 1),
+            dimension_numbers=lax.ConvDimensionNumbers(
+                (0, 3, 1, 2), (3, 2, 0, 1), (0, 3, 1, 2)),
+            feature_group_count=c, precision=None)
+
+    @jax.custom_vjp
+    def op(xv, wv):
+        return conv(xv, wv)
+
+    def op_fwd(xv, wv):
+        return conv(xv, wv), (xv, wv)
+
+    def op_bwd(res, g):
+        xv, wv = res
+        # linear in xv: the transpose is the one nn.Conv's gradient takes
+        dx, = jax.vjp(lambda v: conv(v, wv), xv)[1](g)
+        dw = dw_filter_grad(xv, g, pads=pads, k=k, stride=stride,
+                            interpret=interpret)
+        return dx, dw.astype(wv.dtype)
+
+    op.defvjp(op_fwd, op_bwd)
+    return op(x, kernel)

@@ -210,6 +210,9 @@ class Program:
     loss_fn: Any
     global_batch: int           # rows per optimizer step, all processes
     bn_mode: str
+    # depthwise stages of the train step by who computes their filter
+    # gradient, (kernel, xla): ops/conv.py:dw_grad_impl
+    dw_grad_stages: Tuple[int, int] = (0, 0)
 
 
 def _choose_mesh(cfg: TrainConfig):
@@ -268,6 +271,14 @@ def _validate(cfg: TrainConfig, n_dev: int, dp_size: int) -> None:
             "for dp>1 and is not implemented")
 
 
+def _model_input_shape(cfg: TrainConfig, input_size, rows: int):
+    """NHWC shape of ``rows`` rows as the LOADER feeds the model:
+    pixel-shuffled under ``--stem-s2d``."""
+    c, h, w = input_size
+    return (rows, h // 2, w // 2, 4 * c) if cfg.stem_s2d \
+        else (rows, h, w, c)
+
+
 def build_program(cfg: TrainConfig, mesh=None) -> Program:
     """Everything of the program that needs no arrays and no file system.
 
@@ -299,10 +310,24 @@ def build_program(cfg: TrainConfig, mesh=None) -> Program:
                      "pmean-reduced inside every train step here, which "
                      "supersedes the reference's per-epoch distribute_bn",
                      cfg.dist_bn)
+    sequence_task = bool(getattr(model, "sequence_task", False))
+    dw_grad_stages = (0, 0)
+    if not sequence_task:
+        # what make_train_step's trace will decide, stage by stage, for the
+        # rows one step takes (the microbatch under --grad-accum)
+        from ..ops.conv import dw_grad_census
+        census = dw_grad_census(
+            model, _model_input_shape(cfg, input_size,
+                                      cfg.batch_size * dp_size),
+            jnp.float32, devices=n_dev)
+        dw_grad_stages = (len(census.get("kernel", ())),
+                          len(census.get("xla", ())))
+        _logger.info("Depthwise filter gradients: dw_grad_kernel_stages=%d "
+                     "dw_grad_xla_stages=%d", *dw_grad_stages)
     return Program(
         cfg=cfg, mesh=mesh, n_dev=n_dev, batch_axis=batch_axis, dp=dp_size,
         data_config=data_config, input_size=input_size, model=model,
-        sequence_task=bool(getattr(model, "sequence_task", False)),
+        sequence_task=sequence_task, dw_grad_stages=dw_grad_stages,
         lr=lr, tx=create_optimizer(cfg, learning_rate=lr),
         lr_scheduler=lr_scheduler, num_epochs=num_epochs,
         loss_fn=create_loss_fn(cfg),
@@ -462,17 +487,17 @@ def build_telemetry(program: Program, state, train_loader,
     # --stem-s2d (0 for sequence models: ROADMAP D13)
     fwd_flops = 0.0
     if not program.sequence_task:
-        c, h, w = program.input_size
         fwd_flops = forward_flops_per_sample(
             model, {"params": state.params,
                     "batch_stats": state.batch_stats},
-            (1, h // 2, w // 2, 4 * c) if cfg.stem_s2d else (1, h, w, c))
+            _model_input_shape(cfg, program.input_size, 1))
     event_log = EventLog(os.path.join(output_dir, "telemetry.jsonl")) \
         if output_dir and jax.process_index() == 0 else None
     telemetry = TrainTelemetry(
         event_log=event_log, flops_per_sample=fwd_flops,
         attn_tiles_per_sample=model.attn_tiles_visited(cfg.seq_len)
         if program.sequence_task else 0,
+        dw_grad_stages=program.dw_grad_stages,
         # throughput is measured on the GLOBAL batch (the loader
         # assembles the global sharded array), so the MFU denominator
         # is the whole MESH's peak — n_dev == mesh.size, which a
@@ -720,7 +745,9 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
                         global_batch=program.global_batch,
                         world_size=program.n_dev,
                         mesh_shape=[int(s) for s in mesh.shape.values()],
-                        axis_names=list(mesh.axis_names))
+                        axis_names=list(mesh.axis_names),
+                        dw_grad_kernel_stages=program.dw_grad_stages[0],
+                        dw_grad_xla_stages=program.dw_grad_stages[1])
         if resumed_from:
             telemetry.event("resume", path=resumed_from,
                             epoch=start_epoch, batch=resume_batch)

@@ -214,6 +214,10 @@ def make_train_step(model, tx: optax.GradientTransformation,
             return local_stats_scope(dp, batch_sh)
     else:
         bn_scope = contextlib.nullcontext
+    # how many devices compile this step: a depthwise stage asks it when it
+    # decides who computes its filter gradient (ops/conv.py:dw_grad_impl)
+    from ..ops.conv import dw_grad_scope
+    n_dev = int(mesh.size)
 
     def step(state: TrainState, x, y, rng):
         # pin the batch to the batch axis: with inferred in_shardings this
@@ -222,7 +226,8 @@ def make_train_step(model, tx: optax.GradientTransformation,
         # local-stats layout
         x = lax.with_sharding_constraint(x, batch_sh)
         y = lax.with_sharding_constraint(y, batch_sh)
-        with bn_scope():        # entered at TRACE time (ops/norm.py)
+        # both entered at TRACE time (ops/norm.py's idiom)
+        with bn_scope(), dw_grad_scope(n_dev):
             loss, grads, new_stats, prec1 = forward_backward(
                 state.params, state.batch_stats, x, y, rng)
         return apply_updates(state, grads, new_stats, loss, prec1)

@@ -18,6 +18,7 @@ measurement-day regression net.
 
 import os
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,7 +28,9 @@ from jax import lax
 from deepfake_detection_tpu.models import create_model, init_model
 from deepfake_detection_tpu.models.efficientnet_blocks import (
     fused_dw_eligible)
-from deepfake_detection_tpu.ops.conv import (resolve_padding, space_to_depth,
+from deepfake_detection_tpu.ops.conv import (dw_grad_census, dw_grad_impl,
+                                             dw_grad_scope, resolve_padding,
+                                             space_to_depth,
                                              space_to_depth_stem_kernel)
 from deepfake_detection_tpu.ops.depthwise_pallas import (FUSED_DW_ACTS,
                                                          fused_depthwise)
@@ -577,3 +580,198 @@ def test_fused_step_under_local_bn_mesh():
         losses[label] = float(metrics["loss"])
     np.testing.assert_allclose(losses["fused"], losses["stock"],
                                rtol=5e-5, atol=5e-5)
+
+
+# ---------------------------------------------------------------------------
+# the default path's depthwise stage: XLA's conv and dx, the kernel's dW
+# (ops/conv.py:Conv2d -> ops/depthwise_pallas.py:depthwise_conv, PR 29)
+# ---------------------------------------------------------------------------
+
+# (k, stride, C, H=W): every kernel x stride at each channel class (a
+# fraction of the lanes, lanes and a half, two and a quarter), odd sizes
+# that leave a last H tile short (19 of the flagship's, 13 and 11 for its
+# 38 and 75 cut to test size)
+_DEFAULT_STAGES = [(k, s, c, hw)
+                   for k in (3, 5) for s in (1, 2)
+                   for c, hw in ((32, 19), (192, 13), (288, 11))]
+
+
+def _stage_vjp(conv, variables, x, g, impl):
+    """(y, dW, dx) of one depthwise stage under the kernel's filter
+    gradient (interpreted here) or under the parent's nn.Conv path."""
+    platform = "tpu" if impl == "kernel" else "cpu"
+    with dw_grad_scope(1, platform=platform):
+        y, vjp = jax.vjp(lambda v, x: conv.apply(v, x), variables, x)
+        dv, dx = vjp(g.astype(y.dtype))
+    return y, dv["params"]["conv"]["kernel"], dx
+
+
+@pytest.mark.parametrize("pad", ["", "same"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("k,stride,c,hw", _DEFAULT_STAGES)
+def test_default_path_filter_grad(k, stride, c, hw, dtype, pad):
+    """Same parameter tree and init; forward and dx the parent's bit for
+    bit; dW against the float32 / ``highest`` gradient at reassociation
+    tolerance, and no further from it than the parent's own."""
+    from deepfake_detection_tpu.ops.conv import create_conv2d
+    conv = create_conv2d(c, k, stride=stride, padding=pad, depthwise=True,
+                         dtype=dtype, name="conv_dw")
+    rng = np.random.default_rng(k * 100 + stride * 10 + c)
+    x = jnp.asarray(rng.standard_normal((3, hw, hw, c)), dtype)
+    v = conv.init(jax.random.PRNGKey(0), x)
+    with dw_grad_scope(1, platform="tpu"):
+        v_kernel = conv.init(jax.random.PRNGKey(0), x)
+    assert jax.tree.structure(v) == jax.tree.structure(v_kernel)
+    for a, b in zip(jax.tree.leaves(v), jax.tree.leaves(v_kernel)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    ho = conv.apply(v, x).shape[1]
+    g = jnp.asarray(rng.standard_normal((3, ho, ho, c)), dtype)
+    y0, dw0, dx0 = _stage_vjp(conv, v, x, g, "xla")
+    y1, dw1, dx1 = _stage_vjp(conv, v, x, g, "kernel")
+    np.testing.assert_array_equal(np.asarray(y0, np.float32),
+                                  np.asarray(y1, np.float32))
+    np.testing.assert_array_equal(np.asarray(dx0, np.float32),
+                                  np.asarray(dx1, np.float32))
+    assert dw1.dtype == dw0.dtype == jnp.float32 and dw1.shape == dw0.shape
+
+    padv = _resolve(pad, k, stride, hw, hw)
+    ref = jax.vjp(lambda w: lax.conv_general_dilated(
+        x.astype(jnp.float32), w, (stride, stride), padv,
+        feature_group_count=c, dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=lax.Precision.HIGHEST),
+        v["params"]["conv"]["kernel"])[1](g.astype(jnp.float32))[0]
+    scale = max(1.0, float(jnp.abs(ref).max()))
+    np.testing.assert_allclose(np.asarray(dw1), np.asarray(ref), rtol=2e-5,
+                               atol=2e-5 * scale)
+    assert float(jnp.abs(dw1 - ref).max()) <= \
+        float(jnp.abs(dw0 - ref).max()) + 2e-5 * scale
+
+
+# every depthwise stage of the two benchmark configurations:
+# (batch, H=W, C, k, stride); benchmark/configs/*.json build exactly these
+# (test_dw_grad_census_of_the_benchmark_configurations)
+_FLAGSHIP_STAGES = [
+    (3, 300, 256, 3, 1), (3, 300, 32, 3, 1), (3, 300, 192, 3, 2),
+    (3, 150, 288, 3, 1), (3, 150, 288, 5, 2), (3, 75, 480, 5, 1),
+    (3, 75, 480, 3, 2), (3, 38, 960, 3, 1), (3, 38, 960, 5, 1),
+    (3, 38, 1344, 5, 1), (3, 38, 1344, 5, 2), (3, 19, 2304, 5, 1),
+    (3, 19, 2304, 3, 1), (3, 19, 3840, 3, 1)]
+_B4_STAGES = [
+    (80, 190, 48, 3, 1), (80, 190, 24, 3, 1), (80, 190, 144, 3, 2),
+    (80, 95, 192, 3, 1), (80, 95, 192, 5, 2), (80, 48, 336, 5, 1),
+    (80, 48, 336, 3, 2), (80, 24, 672, 3, 1), (80, 24, 672, 5, 1),
+    (80, 24, 960, 5, 1), (80, 24, 960, 5, 2), (80, 12, 1632, 5, 1),
+    (80, 12, 1632, 3, 1), (80, 12, 2688, 3, 1)]
+
+
+@pytest.mark.parametrize(
+    "stage,expected",
+    [(s, "kernel") for s in _FLAGSHIP_STAGES]
+    + [(s, "xla") for s in _B4_STAGES],
+    ids=lambda v: v if isinstance(v, str) else "x".join(map(str, v)))
+def test_dw_grad_impl_follows_the_probe(stage, expected):
+    """PERF.md section 6 (PR 29): the kernel at every flagship stage, XLA's
+    own gradient at every B4 stage; XLA's wherever more than one device
+    compiles the step, and wherever the kernel could only be interpreted."""
+    b, hw, c, k, stride = stage
+    shape = (b, hw, hw, c)
+    assert dw_grad_impl(shape, k, stride, jnp.bfloat16, devices=1,
+                        platform="tpu") == expected
+    for devices in (2, 4, 256):
+        assert dw_grad_impl(shape, k, stride, jnp.bfloat16, devices=devices,
+                            platform="tpu") == "xla"
+    assert dw_grad_impl(shape, k, stride, jnp.bfloat16, devices=1,
+                        platform="cpu") == "xla"
+    # with nothing said: this process's backend, which is the CPU's
+    assert dw_grad_impl(shape, k, stride, jnp.bfloat16) == "xla"
+
+
+@pytest.mark.parametrize("config,stages,count", [
+    ("flagship_v4_600", _FLAGSHIP_STAGES, 55),
+    ("effnet_b4_380", _B4_STAGES, 32)])
+def test_dw_grad_census_of_the_benchmark_configurations(config, stages,
+                                                        count):
+    """One abstract trace of the model a cell trains finds its depthwise
+    stages: the shapes above, 55 / 0 and 0 / 32 on one chip, and XLA's
+    gradient everywhere on four."""
+    import json
+    from deepfake_detection_tpu.config import TrainConfig
+    from deepfake_detection_tpu.runners import train as T
+    repo = os.path.join(os.path.dirname(__file__), os.pardir)
+    with open(os.path.join(repo, "benchmark", "configs",
+                           config + ".json")) as f:
+        conf = json.load(f)
+    cfg = TrainConfig.from_args(list(conf["train_flags"]))
+    c, h, w = conf["input_size"]
+    model = T.build_model(cfg, c)
+    shape = (cfg.batch_size, h, w, c)
+    one = dw_grad_census(model, shape, jnp.bfloat16, 1, "tpu")
+    impl = "kernel" if stages is _FLAGSHIP_STAGES else "xla"
+    assert {k: len(v) for k, v in one.items()} == {impl: count}
+    assert {(s[0], s[1], s[3], k, st) for s, k, st in one[impl]} == \
+        set(stages)
+    four = dw_grad_census(model, shape, jnp.bfloat16, 4, "tpu")
+    assert {k: len(v) for k, v in four.items()} == {"xla": count}
+
+
+class _OneBlock(nn.Module):
+    """A flagship-shaped MBConv (k5, expansion 6, SE, swish) and a head."""
+
+    @nn.compact
+    def __call__(self, x, training: bool = False):
+        from deepfake_detection_tpu.models.efficientnet_blocks import \
+            InvertedResidual
+        x = InvertedResidual(8, dw_kernel_size=5, exp_ratio=6.0,
+                             se_ratio=0.25, act="swish",
+                             name="blocks_0_0")(x, training=training)
+        return nn.Dense(2, name="classifier")(x.mean(axis=(1, 2)))
+
+
+def test_default_path_train_step_takes_the_kernel_on_one_device():
+    """``make_train_step`` tells the stage how many devices compile it: on
+    a one-device mesh the block trains through the kernel (interpreted
+    here) to the parent's loss, parameter tree and, at reassociation
+    tolerance, parameters; over eight devices it keeps XLA's gradient."""
+    import optax
+    from deepfake_detection_tpu.losses import cross_entropy
+    from deepfake_detection_tpu.parallel import make_train_mesh
+    from deepfake_detection_tpu.train import (create_train_state,
+                                              make_train_step)
+    model = _OneBlock()
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.standard_normal((3, 11, 11, 8)), jnp.float32)
+    y = jnp.asarray([0, 1, 1])
+
+    def fresh_state():      # create_train_state consumes its variables
+        return create_train_state(
+            model.init(jax.random.PRNGKey(0), x, training=True),
+            optax.sgd(0.1))
+    out = {}
+    for impl, platform in (("xla", "cpu"), ("kernel", "tpu")):
+        census = {}
+        step = make_train_step(
+            model, optax.sgd(0.1), cross_entropy,
+            mesh=make_train_mesh(devices=jax.devices()[:1]), donate=False)
+        state = fresh_state()
+        with dw_grad_scope(1, platform=platform, census=census):
+            state, metrics = step(state, x, y, jax.random.PRNGKey(1))
+        assert {k: len(s) for k, s in census.items()} == {impl: 1}
+        out[impl] = (float(metrics["loss"]), state.params)
+    assert out["kernel"][0] == out["xla"][0]
+    flat = {impl: jax.tree_util.tree_leaves_with_path(p)
+            for impl, (_, p) in out.items()}
+    assert [k for k, _ in flat["kernel"]] == [k for k, _ in flat["xla"]]
+    for (path, a), (_, b) in zip(flat["kernel"], flat["xla"]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                                   atol=2e-5, err_msg=str(path))
+    # the runner's default mesh here: eight devices compile the step
+    census = {}
+    step = make_train_step(model, optax.sgd(0.1), cross_entropy,
+                           mesh=make_train_mesh(), bn_mode="global",
+                           donate=False)
+    x8, state = jnp.concatenate([x, x, x])[:8], fresh_state()
+    with dw_grad_scope(1, platform="tpu", census=census):
+        step(state, x8, jnp.asarray([0, 1] * 4), jax.random.PRNGKey(1))
+    assert {k: len(s) for k, s in census.items()} == {"xla": 1}

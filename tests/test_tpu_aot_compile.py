@@ -20,7 +20,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from deepfake_detection_tpu.ops.depthwise_pallas import fused_depthwise
+from deepfake_detection_tpu.ops import depthwise_pallas
+from deepfake_detection_tpu.ops.conv import dw_grad_scope
+from deepfake_detection_tpu.ops.depthwise_pallas import (dw_filter_grad,
+                                                         fused_depthwise)
 from deepfake_detection_tpu.ops.flash_attention import flash_attention
 from deepfake_detection_tpu.ops.selective_scan import selective_scan
 
@@ -90,6 +93,75 @@ def test_depthwise_train_grad_compiles(one_chip, hw, c, k, stride):
     _compile(jax.grad(lambda x, w: fused_depthwise(
         x, w, None, None, stride=stride, act="none",
         interpret=False).astype(jnp.float32).sum(), argnums=(0, 1)), x, w)
+
+
+@pytest.mark.parametrize("hw,c,k,stride", _DW_STAGES)
+def test_depthwise_filter_grad_bf16_compiles(one_chip, hw, c, k, stride):
+    """The default path's filter gradient at the flagship's batch: both
+    operands bf16 as the step hands them over, padded once in bf16, the
+    last H tile masked inside the kernel."""
+    pad = (stride - 1 + k - 1) // 2
+    ho = (hw + 2 * pad - k) // stride + 1
+    x = jax.ShapeDtypeStruct((3, hw, hw, c), jnp.bfloat16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((3, ho, ho, c), jnp.bfloat16, sharding=one_chip)
+    compiled = _compile(lambda x, g: dw_filter_grad(
+        x, g, pads=((pad, pad),) * 2, k=k, stride=stride, interpret=False),
+        x, g)
+    assert "f32[3,%d,%d,%d]" % (hw, hw, c) not in compiled.as_text(), \
+        "a float32 copy of the operand"
+
+
+def test_flagship_block_grad_takes_the_filter_grad_kernel(one_chip,
+                                                          monkeypatch):
+    """The mechanism's tripwire (PR 29): ``jax.grad`` of one flagship
+    MBConv, ``InvertedResidual(80 -> 80, k5)`` at ``(3, 75, 75, 80)`` bf16,
+    through the default path.  At this batch XLA rewrites the stage
+    space-to-batch and, left to itself, feeds the depthwise filter gradient
+    a k-fold copy of the expanded activation (``bf16[75,24,10,480,5]``, the
+    step's largest temporary: 153.9 MB for this block at the parent); with
+    the reduction kernel in its place that array is gone, the kernel is
+    filed under ``conv_dw`` and the block needs less scratch.  A later jax
+    that changes either compiler's choice shows up here, not in a chip
+    run."""
+    import re
+    from deepfake_detection_tpu.models.efficientnet_blocks import \
+        InvertedResidual
+    # this process's backend is the CPU: say what the program is for, and
+    # have the kernel compiled as on the chip, not interpreted
+    monkeypatch.setattr(depthwise_pallas, "resolve_interpret",
+                        lambda interpret, kernel: False)
+    blk = InvertedResidual(80, dw_kernel_size=5, exp_ratio=6.0,
+                           se_ratio=0.25, act="swish", dtype=jnp.bfloat16)
+    shape = (3, 75, 75, 80)
+    variables = jax.eval_shape(lambda: blk.init(
+        jax.random.PRNGKey(0), jnp.zeros(shape, jnp.bfloat16),
+        training=True))
+    variables, x = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (variables, jax.ShapeDtypeStruct(shape, jnp.bfloat16)))
+
+    def loss(params, stats, x):
+        y, _ = blk.apply({"params": params, "batch_stats": stats}, x,
+                         training=True, mutable=["batch_stats"])
+        return y.astype(jnp.float32).sum()
+
+    def compiled_for(platform):
+        with dw_grad_scope(1, platform=platform):
+            return jax.jit(jax.grad(loss, argnums=(0, 2))).lower(
+                variables["params"], variables["batch_stats"], x).compile()
+
+    kernel, xla = compiled_for("tpu"), compiled_for("cpu")
+    text = kernel.as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
+                       r'op_name="([^"]*)"', text)
+    assert calls and all("conv_dw" in c for c in calls), calls
+    k_fold = re.compile(r"\[[\d,]*\b480,5\]")
+    assert not k_fold.search(text)
+    assert "tpu_custom_call" not in xla.as_text()
+    # what XLA does with this gradient on its own, while it still does
+    assert k_fold.search(xla.as_text())
+    assert kernel.memory_analysis().temp_size_in_bytes < \
+        xla.memory_analysis().temp_size_in_bytes <= 153.9e6 * 1.05
 
 
 def test_depthwise_residual_forward_compiles(one_chip):

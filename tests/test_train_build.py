@@ -196,6 +196,13 @@ def test_telemetry_is_the_runners(built, tmp_path):
     else:
         assert gflops > 0.0
         assert telemetry.attn_tiles_per_sample == 0
+    # the depthwise census (PR 29): build_program counted the stages; on
+    # the CPU, over the test's eight devices, every one keeps XLA's gradient
+    kernel, xla = p.dw_grad_stages
+    assert (snap["gauges"]["dw_grad_kernel_stages"],
+            snap["gauges"]["dw_grad_xla_stages"]) == (kernel, xla)
+    assert kernel == 0 and (xla == 0) == p.sequence_task
+    assert "dfd_train_dw_grad_xla_stages" in telemetry.render_prometheus()
     assert os.path.isfile(tmp_path / "telemetry.jsonl")
 
 
