@@ -90,14 +90,19 @@ def jsd_cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray,
 
 def next_token_loss(hidden: jnp.ndarray, embedding: jnp.ndarray,
                     targets: jnp.ndarray, chunk: int = 2048,
-                    weight: Optional[jnp.ndarray] = None):
-    """Mean cross-entropy of ``hidden @ embedding.T`` against integer
-    ``targets`` (batch, L), and the token accuracy in percent, over the
-    positions whose target is not negative (and whose row ``weight`` is not
-    zero).  The logits are made ``chunk`` positions at a time and made again
-    in the backward pass, so the (L, rows) float32 matrix is never whole:
-    16,384 x 25,008 of it would be 1.6 GB, its gradient as much again."""
+                    weight: Optional[jnp.ndarray] = None,
+                    logit_scale: float = 1.0):
+    """Mean cross-entropy of ``logit_scale * hidden @ embedding.T`` against
+    integer ``targets`` (batch, L), and the token accuracy in percent, over
+    the positions whose target is not negative (and whose row ``weight`` is
+    not zero).  The logits are made ``chunk`` positions at a time and made
+    again in the backward pass, so the (L, rows) float32 matrix is never
+    whole: 16,384 x 25,008 of it would be 1.6 GB, its gradient as much
+    again.  ``logit_scale`` (a model's 1 / logits_scaling) goes into the
+    hidden states once, not into every chunk of logits."""
     b, l, d = hidden.shape
+    if logit_scale != 1.0:
+        hidden = hidden * jnp.asarray(logit_scale, hidden.dtype)
     chunk = min(chunk, l)
     pad = -l % chunk
     if pad:

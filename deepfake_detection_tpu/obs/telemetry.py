@@ -83,6 +83,11 @@ _COUNTER_CATALOG = (
      "Attention's work goes with the square of --seq-len and with the "
      "kernels' blocks, train_tokens_total with neither: read the two rates "
      "together when either changes between runs"),
+    ("ssd_chunks_total", "Chunks the state-space dual scan walked in "
+     "sequence over the train steps dispatched: rows x the model's per-row "
+     "census (its Mamba-2 layers x the chunks of a row; 0 for models "
+     "without the scan).  The scan's sequential depth goes with --seq-len "
+     "over the chunk, its work with --seq-len times the chunk"),
     ("drains_total", "Metric drain boundaries (telemetry records)"),
     ("step_seconds_total", "Wall seconds spent in the train loop"),
     ("data_wait_seconds_total", "Seconds the loop blocked on next(loader)"),
@@ -207,12 +212,15 @@ class TrainTelemetry:
                  peak_flops: float = 0.0,
                  meta: Optional[Dict[str, Any]] = None,
                  attn_tiles_per_sample: int = 0,
+                 ssd_chunks_per_sample: int = 0,
                  dw_grad_stages: Tuple[int, int] = (0, 0)):
         self.event_log = event_log
         self.flops_per_sample = float(flops_per_sample)
         # attention-kernel cells a step visits per row: a sequence model's
         # attn_tiles_visited(seq_len); 0 for images
         self.attn_tiles_per_sample = int(attn_tiles_per_sample)
+        # chunks the scan walks per row: a model's ssd_chunks(seq_len)
+        self.ssd_chunks_per_sample = int(ssd_chunks_per_sample)
         self.peak = float(peak_flops)
         self.meta = dict(meta or {})
         self.profiler = None          # optional obs.profiler.ProfilerCapture
@@ -297,6 +305,8 @@ class TrainTelemetry:
             self._c["train_tokens_total"] += tokens
             self._c["attn_tiles_visited_total"] += \
                 n_samples * self.attn_tiles_per_sample
+            self._c["ssd_chunks_total"] += \
+                n_samples * self.ssd_chunks_per_sample
             self._c["step_seconds_total"] += step_wall_s
             self._c["data_wait_seconds_total"] += data_wait_s
 

@@ -497,6 +497,8 @@ def build_telemetry(program: Program, state, train_loader,
         event_log=event_log, flops_per_sample=fwd_flops,
         attn_tiles_per_sample=model.attn_tiles_visited(cfg.seq_len)
         if program.sequence_task else 0,
+        ssd_chunks_per_sample=model.ssd_chunks(cfg.seq_len)
+        if hasattr(model, "ssd_chunks") else 0,
         dw_grad_stages=program.dw_grad_stages,
         # throughput is measured on the GLOBAL batch (the loader
         # assembles the global sharded array), so the MFU denominator

@@ -3,8 +3,8 @@
 libtpu's compiler is installed in the CPU sandbox and compiles for a
 *described* (not attached) ``v5e:2x2`` topology, so Mosaic's verdict on
 each kernel at published widths (flagship / B4 depthwise stages, ViT-B/16
-attention, Phi-4-mini-flash's attention and selective scan at 16,384
-tokens) is a two-second test instead of a chip call.  Interpret mode
+attention, Phi-4-mini-flash's attention and selective scan and
+granite-4.0-h-micro's state-space dual scan at 16,384 tokens) is a two-second test instead of a chip call.  Interpret mode
 cannot see what this sees: unaligned tiles, VMEM overflow, unsupported
 strided accesses.  Nothing runs, so nothing here is a measurement.
 
@@ -26,6 +26,7 @@ from deepfake_detection_tpu.ops.depthwise_pallas import (dw_filter_grad,
                                                          fused_depthwise)
 from deepfake_detection_tpu.ops.flash_attention import flash_attention
 from deepfake_detection_tpu.ops.selective_scan import selective_scan
+from deepfake_detection_tpu.ops.ssd import ssd_scan
 
 
 @pytest.fixture(scope="module")
@@ -219,6 +220,27 @@ def test_selective_scan_phi4flash_16k_compiles(one_chip, grad):
 
     def scan(*a):
         return selective_scan(*a, chunk=128, impl="pallas", interpret=False)
+    if grad:
+        _compile(jax.grad(lambda *a: scan(*a).astype(jnp.float32).sum(),
+                          argnums=range(6)), *args)
+    else:
+        _compile(scan, *args)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_ssd_scan_granite4h_16k_compiles(one_chip, grad):
+    """granite-4.0-h-micro's recurrence at 16,384 tokens: 64 heads of 64
+    channels (eight heads a grid cell, 512 lanes), state 128, the published
+    chunks of 256; each cell's padded transposes and 64-lane head slices are
+    what interpret mode cannot judge."""
+    spec = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dt, sharding=one_chip)
+    args = (spec(1, 16384, 64, 64, dt=jnp.bfloat16), spec(1, 16384, 64),
+            spec(64), spec(1, 16384, 128, dt=jnp.bfloat16),
+            spec(1, 16384, 128, dt=jnp.bfloat16), spec(64))
+
+    def scan(*a):
+        return ssd_scan(*a, chunk=256, impl="pallas", interpret=False)
     if grad:
         _compile(jax.grad(lambda *a: scan(*a).astype(jnp.float32).sum(),
                           argnums=range(6)), *args)

@@ -2,8 +2,8 @@
 
 ``main`` is ``build_program`` → ``init_state`` → ``build_loaders`` →
 ``build_steps`` → ``build_telemetry`` and the epoch loop.  The benchmark's
-drivers (``benchmark/drivers/train.py:Built``, ``train_tokens.py:TokenBuilt``)
-still spell that set-up out themselves; until they call the builder (ROADMAP
+drivers (``benchmark/drivers/train.py:Built``, ``train_tokens.py:TokenBuilt``,
+which ``train_seq.py`` shares) still spell that set-up out themselves; until they call the builder (ROADMAP
 D11) these tests hold the two to the same lowered step and the same batches,
 for each benchmark configuration's own ``train_flags`` cut to a CPU size.
 """
@@ -38,6 +38,9 @@ CUTS = {
                       {"input_size": [3, 64, 64]}),
     "phi4_mini_flash_6l": (["--model", "phi4_mini_flash_tiny", "--seq-len",
                             "64"], {"train": {"seq_len": 64}}),
+    "granite4_h_micro_10l": (["--model", "granite4_h_micro_tiny",
+                              "--seq-len", "64"],
+                             {"train": {"seq_len": 64}}),
 }
 
 
