@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from ..losses import next_token_loss
+from ..ops.causal_conv import causal_conv1d, causal_conv_census
 from ..ops.flash_attention import flash_attention, tile_census
 from ..ops.ssd import ssd_scan
 from ..registry import register_model
@@ -114,6 +115,11 @@ class _Layer(nn.Module):
         return x + self.residual_multiplier * y
 
     def _mamba(self, x):
+        """The Mamba-2 mixer.  The convolution, its bias and the silu are
+        one op with its own backward, ops/causal_conv.py:causal_conv1d: two
+        TPU kernels where whole tiles hold the row (a TPU backend, channels
+        and tokens multiples of 128), the same passes as array
+        operations elsewhere."""
         b, l, _ = x.shape
         h, p, n = self.ssm_heads, self.ssm_head_dim, self.d_state
         inner, conv = h * p, h * p + 2 * n
@@ -126,10 +132,7 @@ class _Layer(nn.Module):
             w = self.param("conv_kernel", nn.initializers.lecun_normal(),
                            (self.d_conv, conv))
             bias = self.param("conv_bias", nn.initializers.zeros, (conv,))
-            pad = jnp.pad(xbc, ((0, 0), (self.d_conv - 1, 0), (0, 0)))
-            xbc = sum(pad[:, k:k + l] * w[k].astype(xbc.dtype)
-                      for k in range(self.d_conv)) + bias.astype(xbc.dtype)
-            xbc = nn.silu(xbc)
+            xbc = causal_conv1d(xbc, w, bias)
             u, bm, cm = jnp.split(xbc, [inner, inner + n], axis=-1)
         a_log = self.param("A_log", _a_log_init, (h,))
         skip = self.param("D", nn.initializers.ones, (h,))
@@ -235,6 +238,15 @@ class Granite4H(nn.Module):
         shape: a census."""
         return self.layer_types.count(MAMBA) * \
             -(-seq_len // min(self.chunk, seq_len))
+
+    def causal_conv_layers(self, seq_len: int) -> Tuple[int, int]:
+        """The Mamba-2 layers by the form their causal convolution takes
+        over rows of ``seq_len`` tokens, (kernels, array form):
+        ops/causal_conv.py:causal_conv_impl.  Static per shape and backend:
+        a census."""
+        return causal_conv_census(
+            self.layer_types.count(MAMBA), seq_len,
+            self.ssm_heads * self.ssm_head_dim + 2 * self.d_state)
 
     def __call__(self, ids, training: bool = False):
         """Logits over the rows held, (batch, L, vocab_rows), float32."""

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from ..losses import next_token_loss
+from ..ops.causal_conv import causal_conv1d, causal_conv_census
 from ..ops.flash_attention import flash_attention, tile_census
 from ..ops.selective_scan import selective_scan
 from ..registry import register_model
@@ -143,6 +144,12 @@ class _Layer(nn.Module):
         return x, out
 
     def _mamba(self, x):
+        """The Mamba mixer; also returns the scan's output, the producer's
+        memory.  The convolution, its bias and the silu are one op with its
+        own backward, ops/causal_conv.py:causal_conv1d: two TPU kernels
+        where whole tiles hold the row (a TPU backend, channels and
+        tokens multiples of 128), the same passes as array operations
+        elsewhere."""
         d, n, r = self.d_inner, self.d_state, self.dt_rank
         with jax.named_scope("mamba_proj"):
             u, z = jnp.split(self._dense(2 * d, "in_proj")(x), 2, axis=-1)
@@ -150,11 +157,7 @@ class _Layer(nn.Module):
             w = self.param("conv_kernel", nn.initializers.lecun_normal(),
                            (self.d_conv, d))
             b = self.param("conv_bias", nn.initializers.zeros, (d,))
-            pad = jnp.pad(u, ((0, 0), (self.d_conv - 1, 0), (0, 0)))
-            l = u.shape[1]
-            u = sum(pad[:, k:k + l] * w[k].astype(u.dtype)
-                    for k in range(self.d_conv)) + b.astype(u.dtype)
-            u = nn.silu(u)
+            u = causal_conv1d(u, w, b)
         with jax.named_scope("mamba_proj"):
             dbc = self._dense(r + 2 * n, "x_proj")(u)
             delta, bm, cm = jnp.split(dbc, [r, r + n], axis=-1)
@@ -290,6 +293,16 @@ class Phi4Flash(nn.Module):
                     c["visited"] for c in tile_census(
                         seq_len, blk, blk, True, window).values())
         return visited
+
+    def causal_conv_layers(self, seq_len: int) -> Tuple[int, int]:
+        """The Mamba layers by the form their causal convolution takes over
+        rows of ``seq_len`` tokens, (kernels, array form):
+        ops/causal_conv.py:causal_conv_impl.  Static per shape and backend:
+        a census."""
+        return causal_conv_census(
+            layer_schedule(self.self_periods,
+                           self.cross_periods).count(MAMBA),
+            seq_len, self.expand * self.d_model)
 
     def __call__(self, ids, training: bool = False):
         """Logits over the rows held, (batch, L, vocab_rows), float32."""

@@ -192,6 +192,12 @@ _GAUGE_CATALOG = (
      "fixed by the shapes)"),
     ("dw_grad_xla_stages", "Depthwise stages of the train step that keep "
      "XLA's own filter gradient"),
+    ("causal_conv_kernel_layers", "Mamba layers of the train step whose "
+     "causal convolution runs as the two TPU kernels (ops/causal_conv.py: "
+     "causal_conv_impl decides from the row's length, the channels and the "
+     "backend; a census fixed by the shapes)"),
+    ("causal_conv_xla_layers", "Mamba layers of the train step whose causal "
+     "convolution takes the array form"),
     ("restart_count", "Restart-wrapper relaunches of this run "
      "(DFD_RESTART_COUNT)"),
     ("watchdog_beat_age_s", "Seconds since the last watchdog heartbeat"),
@@ -213,7 +219,8 @@ class TrainTelemetry:
                  meta: Optional[Dict[str, Any]] = None,
                  attn_tiles_per_sample: int = 0,
                  ssd_chunks_per_sample: int = 0,
-                 dw_grad_stages: Tuple[int, int] = (0, 0)):
+                 dw_grad_stages: Tuple[int, int] = (0, 0),
+                 causal_conv_layers: Tuple[int, int] = (0, 0)):
         self.event_log = event_log
         self.flops_per_sample = float(flops_per_sample)
         # attention-kernel cells a step visits per row: a sequence model's
@@ -240,6 +247,9 @@ class TrainTelemetry:
         # (kernel, xla): the program's census of its depthwise stages
         self._g["dw_grad_kernel_stages"] = float(dw_grad_stages[0])
         self._g["dw_grad_xla_stages"] = float(dw_grad_stages[1])
+        # (kernels, array form): a model's causal_conv_layers(seq_len)
+        self._g["causal_conv_kernel_layers"] = float(causal_conv_layers[0])
+        self._g["causal_conv_xla_layers"] = float(causal_conv_layers[1])
         self._g["restart_count"] = float(
             os.environ.get("DFD_RESTART_COUNT", 0) or 0)
         self.h_step = LatencyHistogram(_STEP_BOUNDS)

@@ -213,6 +213,9 @@ class Program:
     # depthwise stages of the train step by who computes their filter
     # gradient, (kernel, xla): ops/conv.py:dw_grad_impl
     dw_grad_stages: Tuple[int, int] = (0, 0)
+    # Mamba layers of the train step by the form of their causal
+    # convolution, (kernels, array form): ops/causal_conv.py
+    causal_conv_layers: Tuple[int, int] = (0, 0)
 
 
 def _choose_mesh(cfg: TrainConfig):
@@ -324,10 +327,16 @@ def build_program(cfg: TrainConfig, mesh=None) -> Program:
                           len(census.get("xla", ())))
         _logger.info("Depthwise filter gradients: dw_grad_kernel_stages=%d "
                      "dw_grad_xla_stages=%d", *dw_grad_stages)
+    causal_conv_layers = (0, 0)
+    if hasattr(model, "causal_conv_layers"):
+        causal_conv_layers = model.causal_conv_layers(cfg.seq_len)
+        _logger.info("Causal convolutions: causal_conv_kernel_layers=%d "
+                     "causal_conv_xla_layers=%d", *causal_conv_layers)
     return Program(
         cfg=cfg, mesh=mesh, n_dev=n_dev, batch_axis=batch_axis, dp=dp_size,
         data_config=data_config, input_size=input_size, model=model,
         sequence_task=sequence_task, dw_grad_stages=dw_grad_stages,
+        causal_conv_layers=causal_conv_layers,
         lr=lr, tx=create_optimizer(cfg, learning_rate=lr),
         lr_scheduler=lr_scheduler, num_epochs=num_epochs,
         loss_fn=create_loss_fn(cfg),
@@ -500,6 +509,7 @@ def build_telemetry(program: Program, state, train_loader,
         ssd_chunks_per_sample=model.ssd_chunks(cfg.seq_len)
         if hasattr(model, "ssd_chunks") else 0,
         dw_grad_stages=program.dw_grad_stages,
+        causal_conv_layers=program.causal_conv_layers,
         # throughput is measured on the GLOBAL batch (the loader
         # assembles the global sharded array), so the MFU denominator
         # is the whole MESH's peak — n_dev == mesh.size, which a
@@ -749,7 +759,10 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
                         mesh_shape=[int(s) for s in mesh.shape.values()],
                         axis_names=list(mesh.axis_names),
                         dw_grad_kernel_stages=program.dw_grad_stages[0],
-                        dw_grad_xla_stages=program.dw_grad_stages[1])
+                        dw_grad_xla_stages=program.dw_grad_stages[1],
+                        causal_conv_kernel_layers=program
+                        .causal_conv_layers[0],
+                        causal_conv_xla_layers=program.causal_conv_layers[1])
         if resumed_from:
             telemetry.event("resume", path=resumed_from,
                             epoch=start_epoch, batch=resume_batch)

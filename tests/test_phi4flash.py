@@ -142,6 +142,20 @@ def test_model_logits_loss_and_gradients_match_the_reference(params, impl,
         assert _rel(a, b) < 1e-3, (jax.tree_util.keystr(path), _rel(a, b))
 
 
+def test_the_convolution_op_is_the_expression_it_replaced(params,
+                                                          monkeypatch):
+    """ops/causal_conv.py in the tiny model against the sum of shifted
+    slices the model wrote before PR 31."""
+    from test_causal_conv import assert_the_op_is_the_expression_it_replaced
+    ids, tg = _ids()
+
+    def loss_and_grads(dtype):
+        m = create_model("phi4_mini_flash_tiny", attn_impl="full", dtype=dtype)
+        return jax.value_and_grad(lambda p: m.apply(
+            {"params": p}, ids, tg, method="sequence_loss")[0])
+    assert_the_op_is_the_expression_it_replaced(loss_and_grads, params, P,
+                                                monkeypatch)
+
 def test_the_slice_ties_to_the_model(params):
     """With ids from the slice, the cut's logits are the columns [0, V/8) of
     the uncut model's: the rows held are the uncut model's first rows."""

@@ -3,8 +3,10 @@
 libtpu's compiler is installed in the CPU sandbox and compiles for a
 *described* (not attached) ``v5e:2x2`` topology, so Mosaic's verdict on
 each kernel at published widths (flagship / B4 depthwise stages, ViT-B/16
-attention, Phi-4-mini-flash's attention and selective scan and
-granite-4.0-h-micro's state-space dual scan at 16,384 tokens) is a two-second test instead of a chip call.  Interpret mode
+attention, Phi-4-mini-flash's attention and selective scan,
+granite-4.0-h-micro's state-space dual scan and both models' causal
+convolution at 16,384 tokens) is a two-second test instead of a chip call.
+Interpret mode
 cannot see what this sees: unaligned tiles, VMEM overflow, unsupported
 strided accesses.  Nothing runs, so nothing here is a measurement.
 
@@ -20,7 +22,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from deepfake_detection_tpu.ops import depthwise_pallas
+from deepfake_detection_tpu.ops import causal_conv, depthwise_pallas, ssd
+from deepfake_detection_tpu.ops.causal_conv import causal_conv1d
 from deepfake_detection_tpu.ops.conv import dw_grad_scope
 from deepfake_detection_tpu.ops.depthwise_pallas import (dw_filter_grad,
                                                          fused_depthwise)
@@ -246,3 +249,76 @@ def test_ssd_scan_granite4h_16k_compiles(one_chip, grad):
                           argnums=range(6)), *args)
     else:
         _compile(scan, *args)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("channels", [4352, 5120],
+                         ids=["granite4h-c4352", "phi4flash-c5120"])
+def test_causal_conv_16k_compiles(one_chip, channels, grad):
+    """The Mamba layers' causal convolution at 16,384 tokens: granite's
+    4,352 channels and phi4's 5,120 in whole rows of 128, bf16 rows and
+    float32 taps; the loop over slabs of 256 lanes, the sublane-shifted
+    views of a slab and the fold of its rows into eight sublanes are what
+    interpret mode cannot judge."""
+    spec = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dt, sharding=one_chip)
+    args = (spec(1, 16384, channels, dt=jnp.bfloat16), spec(4, channels),
+            spec(channels))
+
+    def conv(*a):
+        return causal_conv1d(*a, impl="pallas", interpret=False)
+    if grad:
+        _compile(jax.grad(lambda *a: conv(*a).astype(jnp.float32).sum(),
+                          argnums=range(3)), *args)
+    else:
+        _compile(conv, *args)
+
+
+def test_mamba2_layer_grad_takes_the_causal_conv_kernels(one_chip,
+                                                         monkeypatch):
+    """The mechanism's tripwire (PR 31): ``jax.grad`` of one remat'd
+    Mamba-2 layer at ``train_granite4h_long``'s widths and length.  Written
+    as a sum of shifted slices the convolution's backward came out of XLA
+    as fusions that wrote the four shifted products to memory (several
+    whole ``bf16[1,16384,4352]`` outputs) and summed the taps' and the
+    bias's gradients over the rows into ``bf16[4352]`` (7.6 ms a layer on
+    the chip); with the op both are gone and its two kernels are filed
+    under ``ssd_conv``."""
+    import functools
+    import re
+    from deepfake_detection_tpu.models import granite4h as G
+    from deepfake_detection_tpu.models.helpers import maybe_remat
+    # this process's backend is the CPU: say what the program is for, and
+    # have the kernels compiled as on the chip, not interpreted
+    for mod in (causal_conv, ssd):
+        monkeypatch.setattr(mod, "resolve_interpret",
+                            lambda interpret, kernel: False)
+    monkeypatch.setattr(causal_conv, "causal_conv_impl", functools.partial(
+        causal_conv.causal_conv_impl, backend="tpu"))
+    layer = maybe_remat(G._Layer, "full")(
+        kind=G.MAMBA, d_model=2048, n_heads=32, n_kv_heads=8, head_dim=64,
+        d_ff=8192, ssm_heads=64, ssm_head_dim=64, d_state=128, d_conv=4,
+        chunk=256, residual_multiplier=0.22,
+        attention_multiplier=0.015625, eps=1e-5, scan_impl="pallas",
+        dtype=jnp.bfloat16)
+    shape = (1, 16384, 2048)
+    variables = jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(0), jnp.zeros(shape, jnp.bfloat16), False))
+    params, x = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (variables["params"], jax.ShapeDtypeStruct(shape, jnp.bfloat16)))
+    text = jax.jit(jax.grad(
+        lambda p, x: layer.apply({"params": p}, x, False).astype(
+            jnp.float32).sum(), argnums=(0, 1))).lower(
+                params, x).compile().as_text()
+    scoped = [line for line in text.splitlines()
+              if re.search(r'op_name="[^"]*ssd_conv', line)
+              and re.search(r"\b(fusion|custom-call)\(", line)]
+    kernels = [line for line in scoped if "tpu_custom_call" in line]
+    # the forward made again under remat and the backward
+    assert len(kernels) == 2, [line[:120] for line in scoped]
+    for line in scoped:
+        outputs = re.split(r"\b(?:fusion|custom-call)\(",
+                           line.split(" = ", 1)[1])[0]
+        assert "bf16[4352]" not in outputs, line[:300]
+        assert outputs.count("bf16[1,16384,4352]") <= 1, line[:300]

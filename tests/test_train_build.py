@@ -206,6 +206,14 @@ def test_telemetry_is_the_runners(built, tmp_path):
             snap["gauges"]["dw_grad_xla_stages"]) == (kernel, xla)
     assert kernel == 0 and (xla == 0) == p.sequence_task
     assert "dfd_train_dw_grad_xla_stages" in telemetry.render_prometheus()
+    # the causal convolutions' census (PR 31): on the CPU every Mamba layer
+    # takes the array form; image models have none
+    assert p.causal_conv_layers == (
+        p.model.causal_conv_layers(p.cfg.seq_len)
+        if hasattr(p.model, "causal_conv_layers") else (0, 0))
+    assert (snap["gauges"]["causal_conv_kernel_layers"],
+            snap["gauges"]["causal_conv_xla_layers"]) == p.causal_conv_layers
+    assert p.causal_conv_layers[0] == 0
     assert os.path.isfile(tmp_path / "telemetry.jsonl")
 
 
