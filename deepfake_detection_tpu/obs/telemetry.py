@@ -88,6 +88,22 @@ _COUNTER_CATALOG = (
      "census (its Mamba-2 layers x the chunks of a row; 0 for models "
      "without the scan).  The scan's sequential depth goes with --seq-len "
      "over the chunk, its work with --seq-len times the chunk"),
+    ("moe_routed_tokens_total", "Tokens the routed expert layers of the "
+     "train steps routed: tokens x expert layers, counted on the device "
+     "(ops/moe.py:routing_counts), fetched with each step's metrics and "
+     "added at the drain with the two counters below, so that their ratios "
+     "are of like with like; 0 for models without such a layer"),
+    ("moe_assignments_total", "(token, selected expert) assignments that "
+     "fell on the experts this chip holds, summed the same way: over "
+     "moe_routed_tokens_total, the assignments a routed token brings here "
+     "(experts per token x held / all, were the routing uniform)"),
+    ("moe_peak_assignments_total", "The fullest held expert's assignments "
+     "of each expert layer, summed the same way"),
+    ("moe_full_capacity_passes_total", "Passes (one expert layer, one "
+     "microbatch) whose assignments on held experts passed the first "
+     "capacity, so that the layer walked every row (ops/moe.py); summed "
+     "the same way.  A pass is moe_routed_tokens_total over the tokens of "
+     "a microbatch"),
     ("drains_total", "Metric drain boundaries (telemetry records)"),
     ("step_seconds_total", "Wall seconds spent in the train loop"),
     ("data_wait_seconds_total", "Seconds the loop blocked on next(loader)"),
@@ -198,6 +214,9 @@ _GAUGE_CATALOG = (
      "backend; a census fixed by the shapes)"),
     ("causal_conv_xla_layers", "Mamba layers of the train step whose causal "
      "convolution takes the array form"),
+    ("moe_load_peak_to_mean", "The fullest held expert's assignments over "
+     "the held experts' mean, over the steps of the last drain that routed "
+     "(0 before any)"),
     ("restart_count", "Restart-wrapper relaunches of this run "
      "(DFD_RESTART_COUNT)"),
     ("watchdog_beat_age_s", "Seconds since the last watchdog heartbeat"),
@@ -319,6 +338,19 @@ class TrainTelemetry:
                 n_samples * self.ssd_chunks_per_sample
             self._c["step_seconds_total"] += step_wall_s
             self._c["data_wait_seconds_total"] += data_wait_s
+
+    def on_routing(self, tokens: int, assignments: int, peak: int,
+                   peak_filled: int, full_passes: int) -> None:
+        """Once per drain that fetched routing counts, with their sums over
+        the drained steps (ops/moe.py:routing_counts; host ints)."""
+        with self._lock:
+            self._c["moe_routed_tokens_total"] += tokens
+            self._c["moe_assignments_total"] += assignments
+            self._c["moe_peak_assignments_total"] += peak
+            self._c["moe_full_capacity_passes_total"] += full_passes
+            if assignments:
+                self._g["moe_load_peak_to_mean"] = round(
+                    peak_filled / assignments, 4)
 
     def on_drain(self, *, epoch: int, batch_idx: int, num_updates: int,
                  loss: float, prec1: float, lr: float,

@@ -268,13 +268,16 @@ def causal_conv1d(x, w, b, activation: Optional[str] = "silu",
                   impl: Optional[str] = None,
                   interpret: Optional[bool] = None):
     """``x`` (batch, L, C); ``w`` (d_conv, C), tap ``d_conv - 1`` on the
-    row itself; ``b`` (C,).  Returns ``activation(conv + b)`` in ``x``'s
-    dtype; ``activation`` is ``"silu"`` or None.  ``impl``: ``"xla"``,
+    row itself; ``b`` (C,) or None for a convolution without bias.  Returns
+    ``activation(conv + b)`` in ``x``'s dtype; ``activation`` is ``"silu"``
+    or None.  ``impl``: ``"xla"``,
     ``"pallas"`` (``interpret`` as for the other kernels: compiled on a TPU,
     interpreted elsewhere; ``C`` a multiple of 128 and ``L`` of the row tile
     of 128) or None, which :func:`causal_conv_impl` decides."""
     assert activation in ("silu", None), activation
     l, c = x.shape[1:]
+    if b is None:
+        b = jnp.zeros((c,), jnp.float32)
     assert w.shape[1:] == (c,) and b.shape == (c,), (x.shape, w.shape, b.shape)
     if impl is None:
         impl = causal_conv_impl(l, c)

@@ -216,6 +216,9 @@ class Program:
     # Mamba layers of the train step by the form of their causal
     # convolution, (kernels, array form): ops/causal_conv.py
     causal_conv_layers: Tuple[int, int] = (0, 0)
+    # routed expert layers of the train step by the form of their grouped
+    # products, (kernels, array form): ops/moe.py
+    moe_layers: Tuple[int, int] = (0, 0)
 
 
 def _choose_mesh(cfg: TrainConfig):
@@ -332,11 +335,18 @@ def build_program(cfg: TrainConfig, mesh=None) -> Program:
         causal_conv_layers = model.causal_conv_layers(cfg.seq_len)
         _logger.info("Causal convolutions: causal_conv_kernel_layers=%d "
                      "causal_conv_xla_layers=%d", *causal_conv_layers)
+    moe_layers = (0, 0)
+    if hasattr(model, "moe_layers"):
+        # the tokens one pass routes (the microbatch under --grad-accum)
+        moe_layers = model.moe_layers(
+            cfg.batch_size * dp_size * cfg.seq_len)
+        _logger.info("Routed expert layers: moe_kernel_layers=%d "
+                     "moe_xla_layers=%d", *moe_layers)
     return Program(
         cfg=cfg, mesh=mesh, n_dev=n_dev, batch_axis=batch_axis, dp=dp_size,
         data_config=data_config, input_size=input_size, model=model,
         sequence_task=sequence_task, dw_grad_stages=dw_grad_stages,
-        causal_conv_layers=causal_conv_layers,
+        causal_conv_layers=causal_conv_layers, moe_layers=moe_layers,
         lr=lr, tx=create_optimizer(cfg, learning_rate=lr),
         lr_scheduler=lr_scheduler, num_epochs=num_epochs,
         loss_fn=create_loss_fn(cfg),
@@ -762,7 +772,9 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
                         dw_grad_xla_stages=program.dw_grad_stages[1],
                         causal_conv_kernel_layers=program
                         .causal_conv_layers[0],
-                        causal_conv_xla_layers=program.causal_conv_layers[1])
+                        causal_conv_xla_layers=program.causal_conv_layers[1],
+                        moe_kernel_layers=program.moe_layers[0],
+                        moe_xla_layers=program.moe_layers[1])
         if resumed_from:
             telemetry.event("resume", path=resumed_from,
                             epoch=start_epoch, batch=resume_batch)

@@ -115,16 +115,22 @@ def make_train_step(model, tx: optax.GradientTransformation,
     sequence_task = bool(getattr(model, "sequence_task", False))
 
     def forward_backward_one(params, batch_stats, x, y, rng):
+        """(loss, grads, new batch stats, prec1, routing counts).  The last
+        is what the model's layers sowed into ``moe_counts``
+        (ops/moe.py:routing_counts), summed over the layers; None for a
+        model that sows none."""
         if sequence_task:
             def seq_lossf(p):
                 (loss, acc), mut = model.apply(
                     {"params": p, "batch_stats": batch_stats}, x, y,
-                    training=True, mutable=["batch_stats"],
+                    training=True, mutable=["batch_stats", "moe_counts"],
                     rngs={"dropout": rng}, method="sequence_loss")
-                return loss, (acc, mut.get("batch_stats", batch_stats))
-            (loss, (acc, new_stats)), grads = jax.value_and_grad(
+                sown = jax.tree.leaves(mut.get("moe_counts", {}))
+                return loss, (acc, mut.get("batch_stats", batch_stats),
+                              sum(sown) if sown else None)
+            (loss, (acc, new_stats, counts)), grads = jax.value_and_grad(
                 seq_lossf, has_aux=True)(params)
-            return loss, grads, new_stats, acc
+            return loss, grads, new_stats, acc, counts
 
         def lossf(p):
             variables = {"params": p, "batch_stats": batch_stats}
@@ -135,7 +141,7 @@ def make_train_step(model, tx: optax.GradientTransformation,
         (loss, (logits, new_stats)), grads = jax.value_and_grad(
             lossf, has_aux=True)(params)
         prec1 = accuracy(logits, y)
-        return loss, grads, new_stats, prec1
+        return loss, grads, new_stats, prec1, None
 
     def forward_backward(params, batch_stats, x, y, rng):
         if grad_accum == 1:
@@ -155,20 +161,23 @@ def make_train_step(model, tx: optax.GradientTransformation,
         def micro(carry, inp):
             stats, gsum, lsum, psum_ = carry
             xi, yi, i = inp
-            loss, grads, stats, prec1 = forward_backward_one(
+            loss, grads, stats, prec1, counts = forward_backward_one(
                 params, stats, xi, yi, jax.random.fold_in(rng, i))
             gsum = jax.tree.map(jnp.add, gsum, grads)
-            return (stats, gsum, lsum + loss, psum_ + prec1), None
+            return (stats, gsum, lsum + loss, psum_ + prec1), counts
 
         g0 = jax.tree.map(jnp.zeros_like, params)
         z = jnp.zeros((), jnp.float32)
-        (new_stats, gsum, lsum, psum_), _ = jax.lax.scan(
+        (new_stats, gsum, lsum, psum_), counts = jax.lax.scan(
             micro, (batch_stats, g0, z, z), (xm, ym, jnp.arange(grad_accum)))
         inv = 1.0 / grad_accum
         grads = jax.tree.map(lambda g: g * inv, gsum)
-        return lsum * inv, grads, new_stats, psum_ * inv
+        if counts is not None:         # counts add over the microbatches
+            counts = jnp.sum(counts, axis=0)
+        return lsum * inv, grads, new_stats, psum_ * inv, counts
 
-    def apply_updates(state: TrainState, grads, new_stats, loss, prec1):
+    def apply_updates(state: TrainState, grads, new_stats, loss, prec1,
+                      counts=None):
         grads = _clip_grads(grads, clip_grad)
         updates, opt_state = tx.update(grads, state.opt_state, state.params)
         params = optax.apply_updates(state.params, updates)
@@ -180,6 +189,9 @@ def make_train_step(model, tx: optax.GradientTransformation,
                                   batch_stats=new_stats, opt_state=opt_state,
                                   ema=ema)
         metrics = {"loss": loss, "prec1": prec1}
+        if counts is not None:
+            # made on the device, fetched with the loss at the drain
+            metrics["moe_counts"] = counts
         if nonfinite_guard:
             # the clipped-grad norm: clipping rescales by a finite factor
             # (or NaN-propagates), so finiteness is unchanged vs raw grads
@@ -197,9 +209,10 @@ def make_train_step(model, tx: optax.GradientTransformation,
 
     if mesh is None:
         def step(state: TrainState, x, y, rng):
-            loss, grads, new_stats, prec1 = forward_backward(
+            loss, grads, new_stats, prec1, counts = forward_backward(
                 state.params, state.batch_stats, x, y, rng)
-            return apply_updates(state, grads, new_stats, loss, prec1)
+            return apply_updates(state, grads, new_stats, loss, prec1,
+                                 counts)
         return jax.jit(step, donate_argnums=(0,) if donate else ())
 
     # ---- unified GSPMD path: plain jit over the mesh -------------------
@@ -228,9 +241,9 @@ def make_train_step(model, tx: optax.GradientTransformation,
         y = lax.with_sharding_constraint(y, batch_sh)
         # both entered at TRACE time (ops/norm.py's idiom)
         with bn_scope(), dw_grad_scope(n_dev):
-            loss, grads, new_stats, prec1 = forward_backward(
+            loss, grads, new_stats, prec1, counts = forward_backward(
                 state.params, state.batch_stats, x, y, rng)
-        return apply_updates(state, grads, new_stats, loss, prec1)
+        return apply_updates(state, grads, new_stats, loss, prec1, counts)
 
     jit_kwargs: Dict[str, Any] = {}
     if state_shardings is not None:

@@ -142,6 +142,7 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
         nonlocal nonfinite_total, drain_wait_acc, drain_bad_acc
         t_drain = time.monotonic()
         window_bad = 0
+        routing = None         # a routed model's counts, summed on the way
         for m, n, step_i in pending:
             loss_value = float(m["loss"])     # host sync, log steps only
             # the device-side guard flag (loss OR grad-norm non-finite)
@@ -160,6 +161,9 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
             else:
                 losses_m.update(loss_value, n)
             prec1_m.update(float(m["prec1"]), n)
+            if "moe_counts" in m:
+                counts = np.asarray(m["moe_counts"], np.int64)
+                routing = counts if routing is None else routing + counts
             if resilience is not None:
                 # may raise RewindRequested after K consecutive bad steps
                 resilience.observe_step(step_i, loss_value, bad)
@@ -168,6 +172,8 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
         # block time IS the device-bound share of the window
         drain_wait_acc += time.monotonic() - t_drain
         drain_bad_acc += window_bad
+        if routing is not None and telemetry is not None:
+            telemetry.on_routing(*(int(c) for c in routing))
 
     for batch_idx, batch in enumerate(loader, start=start_batch):
         x, y = batch[0], batch[1]

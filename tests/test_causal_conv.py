@@ -117,16 +117,28 @@ def test_array_form_equals_the_reference(batch, l, c, d_conv, dtype):
     _close(ga[2], gr[2], 1e-5)
 
 
+@pytest.mark.parametrize("d_conv,bias", [(4, True), (3, False)],
+                         ids=["four-taps-bias", "three-taps-no-bias"])
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_no_activation_is_the_convolution_and_its_bias(impl):
-    args, cot = _inputs(1, TILE, 128, jnp.float32, seed=2)
-    (_, g), (_, gr) = (_value_and_grads(fn, args, cot) for fn in (
-        lambda *a: causal_conv1d(*a, activation=None, impl=impl),
-        lambda *a: reference(*a, activation=None)))
-    _close(causal_conv1d(*args, activation=None, impl=impl),
-           reference(*args, activation=None), 2e-6)
-    for a, b in zip(g, gr):
-        _close(a, b, 1e-5)
+def test_no_activation_is_the_convolution_and_its_bias(impl, d_conv, bias):
+    """``b=None`` (models/lfm2moe.py's gated short convolution: three taps,
+    no bias, no activation) is the convolution alone."""
+    (x, w, b), cot = _inputs(1, TILE, 128, jnp.float32, d_conv, seed=2)
+    zero = jnp.zeros_like(b)
+
+    def op(x, w, b):
+        return causal_conv1d(x, w, b if bias else None, activation=None,
+                             impl=impl)
+
+    def ref(x, w, b):
+        return reference(x, w, b if bias else zero, activation=None)
+    (_, g), (_, gr) = (_value_and_grads(fn, (x, w, b), cot)
+                       for fn in (op, ref))
+    _close(op(x, w, b), ref(x, w, b), 2e-6)
+    for a, r in zip(g[:2 + bias], gr[:2 + bias]):
+        _close(a, r, 1e-5)
+    if not bias:
+        assert not np.any(np.asarray(g[2]))
 
 
 def test_the_result_looks_back_only():

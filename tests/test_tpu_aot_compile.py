@@ -322,3 +322,28 @@ def test_mamba2_layer_grad_takes_the_causal_conv_kernels(one_chip,
                            line.split(" = ", 1)[1])[0]
         assert "bf16[4352]" not in outputs, line[:300]
         assert outputs.count("bf16[1,16384,4352]") <= 1, line[:300]
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_expert_ffn_lfm2moe_16k_compiles(one_chip, grad):
+    """The routed experts of ``train_lfm2moe_8k`` at one pass's size: 16,384
+    tokens of width 2048, top-4, eight held experts of 1536, both
+    capacities.  The grouped-product kernels' tiles against the 16 MB of
+    scoped VMEM are what interpret mode cannot judge: the weights'
+    gradient, float32 out of its kernel, did not fit tiles of 1024 x 1024
+    (PR 32)."""
+    from deepfake_detection_tpu.ops.moe import Routing, expert_ffn
+    spec = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dt, sharding=one_chip)
+    args = (spec(16384, 2048, dt=jnp.bfloat16), spec(16384, 4),
+            spec(8, 2048, 3072), spec(8, 1536, 2048))
+    sel = spec(16384, 4, dt=jnp.int32)
+
+    def ffn(z, w, w13, w2, sel):
+        return expert_ffn(z, Routing(sel, w), w13, w2, (0, 8), 64,
+                          impl="pallas", interpret=False)[0]
+    if grad:
+        _compile(jax.grad(lambda *a: ffn(*a).astype(jnp.float32).sum(),
+                          argnums=range(4)), *args, sel)
+    else:
+        _compile(ffn, *args, sel)

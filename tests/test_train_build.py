@@ -41,6 +41,9 @@ CUTS = {
     "granite4_h_micro_10l": (["--model", "granite4_h_micro_tiny",
                               "--seq-len", "64"],
                              {"train": {"seq_len": 64}}),
+    # one pass a step here: the record's global batch is rows x devices
+    "lfm2_24b_a2b_5l": (["--model", "lfm2_24b_a2b_tiny", "--seq-len", "64",
+                         "--grad-accum", "1"], {"train": {"seq_len": 64}}),
 }
 
 
