@@ -38,7 +38,8 @@ import jax.numpy as jnp
 
 from ..losses import next_token_loss
 from ..ops.causal_conv import causal_conv1d, causal_conv_census
-from ..ops.flash_attention import flash_attention, tile_census
+from ..ops.flash_attention import (flash_attention, fused_bwd_census,
+                                   train_tiles_visited)
 from ..ops.ssd import ssd_scan
 from ..registry import register_model
 from .helpers import maybe_remat
@@ -223,13 +224,23 @@ class Granite4H(nn.Module):
     def attn_tiles_visited(self, seq_len: int) -> int:
         """Grid cells the attention kernels visit in one train step over one
         row of ``seq_len`` tokens: each attention layer's query heads times
-        the three kernels' counts (ops/flash_attention.py:tile_census).
-        Static per shape: a census.  0 where the dense path runs."""
+        the forward's and the backward's counts
+        (ops/flash_attention.py:train_tiles_visited).  Static per shape: a
+        census.  0 where the dense path runs."""
         if self.attn_impl != "flash":
             return 0
-        cells = sum(c["visited"] for c in tile_census(
-            seq_len, _FLASH_BLOCK, _FLASH_BLOCK, True).values())
+        cells = train_tiles_visited(seq_len, self.head_dim, _FLASH_BLOCK,
+                                    _FLASH_BLOCK, True)
         return self.layer_types.count(ATTENTION) * self.n_heads * cells
+
+    def attn_bwd_layers(self, seq_len: int) -> Tuple[int, int]:
+        """The attention layers by the form their backward takes over rows
+        of ``seq_len`` tokens, (fused, split):
+        ops/flash_attention.py:fused_bwd.  Static per shape: a census."""
+        if self.attn_impl != "flash":
+            return 0, 0
+        return fused_bwd_census(self.layer_types.count(ATTENTION), seq_len,
+                                self.head_dim, _FLASH_BLOCK)
 
     def ssd_chunks(self, seq_len: int) -> int:
         """Chunks the scan walks in sequence over one row of ``seq_len``

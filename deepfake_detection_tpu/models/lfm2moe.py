@@ -52,7 +52,8 @@ import jax.numpy as jnp
 
 from ..losses import next_token_loss
 from ..ops.causal_conv import causal_conv1d, causal_conv_census
-from ..ops.flash_attention import flash_attention, tile_census
+from ..ops.flash_attention import (flash_attention, fused_bwd_census,
+                                   train_tiles_visited)
 from ..ops.moe import expert_ffn, moe_census, route, routing_counts
 from ..registry import register_model
 from .helpers import maybe_remat
@@ -273,9 +274,17 @@ class Lfm2Moe(nn.Module):
         text).  0 where the dense path runs."""
         if self.attn_impl != "flash":
             return 0
-        cells = sum(c["visited"] for c in tile_census(
-            seq_len, _FLASH_BLOCK, _FLASH_BLOCK, True).values())
+        cells = train_tiles_visited(seq_len, self.head_dim, _FLASH_BLOCK,
+                                    _FLASH_BLOCK, True)
         return self.layer_types.count(ATTENTION) * self.n_heads * cells
+
+    def attn_bwd_layers(self, seq_len: int) -> Tuple[int, int]:
+        """The attention layers by the form their backward takes over rows
+        of ``seq_len`` tokens, (fused, split).  A census."""
+        if self.attn_impl != "flash":
+            return 0, 0
+        return fused_bwd_census(self.layer_types.count(ATTENTION), seq_len,
+                                self.head_dim, _FLASH_BLOCK)
 
     def causal_conv_layers(self, seq_len: int) -> Tuple[int, int]:
         """The conv layers by the form their causal convolution takes over

@@ -36,7 +36,8 @@ import jax.numpy as jnp
 
 from ..losses import next_token_loss
 from ..ops.causal_conv import causal_conv1d, causal_conv_census
-from ..ops.flash_attention import flash_attention, tile_census
+from ..ops.flash_attention import (flash_attention, fused_bwd_census,
+                                   train_tiles_visited)
 from ..ops.selective_scan import selective_scan
 from ..registry import register_model
 from .helpers import maybe_remat
@@ -276,23 +277,40 @@ class Phi4Flash(nn.Module):
                 kv = out
         return self.final_ln(x)
 
+    def _attn_layer_windows(self):
+        """Each attention layer's window (None: the whole causal row)."""
+        return [self.window if kind == WINDOW else None
+                for kind in layer_schedule(self.self_periods,
+                                           self.cross_periods)
+                if kind in (WINDOW, FULL, CROSS)]
+
     def attn_tiles_visited(self, seq_len: int) -> int:
         """Grid cells the attention kernels visit in one train step over one
         row of ``seq_len`` tokens: each attention layer's query heads times
-        the three kernels' counts (ops/flash_attention.py:tile_census; a
-        forward made again under remat is not counted again).  Static per
-        shape: a census, not a measurement.  0 where the dense path runs."""
+        the forward's and the backward's counts
+        (ops/flash_attention.py:train_tiles_visited; a forward made again
+        under remat is not counted again).  Static per shape: a census, not
+        a measurement.  0 where the dense path runs."""
         if self.attn_impl != "flash":
             return 0
-        visited = 0
-        for kind in layer_schedule(self.self_periods, self.cross_periods):
-            if kind in (WINDOW, FULL, CROSS):
-                window = self.window if kind == WINDOW else None
-                blk = _flash_block(window)
-                visited += self.n_heads * sum(
-                    c["visited"] for c in tile_census(
-                        seq_len, blk, blk, True, window).values())
-        return visited
+        return self.n_heads * sum(
+            train_tiles_visited(seq_len, self.head_dim, _flash_block(window),
+                                _flash_block(window), True, window)
+            for window in self._attn_layer_windows())
+
+    def attn_bwd_layers(self, seq_len: int) -> Tuple[int, int]:
+        """The attention layers by the form their backward takes over rows
+        of ``seq_len`` tokens, (fused, split):
+        ops/flash_attention.py:fused_bwd.  Static per shape: a census."""
+        if self.attn_impl != "flash":
+            return 0, 0
+        windows = self._attn_layer_windows()
+        fused = split = 0
+        for window in set(windows):         # the window's blocks, the full's
+            f, s = fused_bwd_census(windows.count(window), seq_len,
+                                    self.head_dim, _flash_block(window))
+            fused, split = fused + f, split + s
+        return fused, split
 
     def causal_conv_layers(self, seq_len: int) -> Tuple[int, int]:
         """The Mamba layers by the form their causal convolution takes over

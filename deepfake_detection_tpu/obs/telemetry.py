@@ -78,7 +78,8 @@ _COUNTER_CATALOG = (
     ("train_tokens_total", "Tokens of the train steps dispatched (sequence "
      "models: rows x positions of each step's batch; 0 for image batches)"),
     ("attn_tiles_visited_total", "Grid cells of the flash-attention kernels "
-     "(forward, dK/dV, dQ) with a visible pair, over the train steps "
+     "(forward and the fused backward; forward, dK/dV and dQ where the "
+     "backward is split) with a visible pair, over the train steps "
      "dispatched: rows x the model's per-row census (0 for image models). "
      "Attention's work goes with the square of --seq-len and with the "
      "kernels' blocks, train_tokens_total with neither: read the two rates "
@@ -214,6 +215,12 @@ _GAUGE_CATALOG = (
      "backend; a census fixed by the shapes)"),
     ("causal_conv_xla_layers", "Mamba layers of the train step whose causal "
      "convolution takes the array form"),
+    ("attn_fused_bwd_layers", "Attention layers of the train step whose "
+     "backward is the one fused kernel (ops/flash_attention.py: fused_bwd "
+     "decides from the row's length and the head's width; a census fixed "
+     "by the shapes)"),
+    ("attn_split_bwd_layers", "Attention layers of the train step whose "
+     "backward is the dK/dV and dQ pair of kernels"),
     ("moe_load_peak_to_mean", "The fullest held expert's assignments over "
      "the held experts' mean, over the steps of the last drain that routed "
      "(0 before any)"),
@@ -239,7 +246,8 @@ class TrainTelemetry:
                  attn_tiles_per_sample: int = 0,
                  ssd_chunks_per_sample: int = 0,
                  dw_grad_stages: Tuple[int, int] = (0, 0),
-                 causal_conv_layers: Tuple[int, int] = (0, 0)):
+                 causal_conv_layers: Tuple[int, int] = (0, 0),
+                 attn_bwd_layers: Tuple[int, int] = (0, 0)):
         self.event_log = event_log
         self.flops_per_sample = float(flops_per_sample)
         # attention-kernel cells a step visits per row: a sequence model's
@@ -269,6 +277,9 @@ class TrainTelemetry:
         # (kernels, array form): a model's causal_conv_layers(seq_len)
         self._g["causal_conv_kernel_layers"] = float(causal_conv_layers[0])
         self._g["causal_conv_xla_layers"] = float(causal_conv_layers[1])
+        # (fused, split): a model's attn_bwd_layers(seq_len)
+        self._g["attn_fused_bwd_layers"] = float(attn_bwd_layers[0])
+        self._g["attn_split_bwd_layers"] = float(attn_bwd_layers[1])
         self._g["restart_count"] = float(
             os.environ.get("DFD_RESTART_COUNT", 0) or 0)
         self.h_step = LatencyHistogram(_STEP_BOUNDS)

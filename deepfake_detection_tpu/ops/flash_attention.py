@@ -1,4 +1,5 @@
-"""Fused flash attention as Pallas TPU kernels (fwd + bwd, custom VJP).
+"""Fused flash attention as Pallas TPU kernels (forward + one fused backward,
+custom VJP; the split backward pair for ring attention and long rows).
 
 The reference framework has no attention op at all (its temporal axis is a
 channel concat, SURVEY.md §2.7); attention enters this framework through the
@@ -9,18 +10,34 @@ length and wastes HBM bandwidth (the usual TPU bottleneck).  This module
 implements the standard blocked online-softmax formulation (FlashAttention-2
 schedule) as Pallas kernels so scores never leave VMEM.
 
-All three kernels use the canonical TPU grid structure: the *tile* axis is
-the innermost (sequential) grid dimension, so Pallas pipelines one
-``(block, d)`` tile at a time through VMEM — O(block) on-chip residency
-regardless of sequence length — while online-softmax / gradient accumulators
+Every kernel uses the canonical TPU grid structure: the *tile* axis is the
+innermost (sequential) grid dimension, so Pallas pipelines one ``(block, d)``
+tile at a time through VMEM while online-softmax / gradient accumulators
 live in VMEM scratch that persists across the inner grid steps:
 
 * forward:          grid (B·H, Q blocks, K tiles) — scratch (acc, m, l);
                     emits O and the per-row logsumexp the backward reuses.
-* backward dQ:      grid (B·H, Q blocks, K tiles) — scratch dQ.
-* backward dK/dV:   grid (B·H, K blocks, Q tiles) — scratch (dK, dV);
-                    the per-(i,j) work is the FlashAttention-2 identity
-                    ``dS = P ∘ (dP − δ)`` with δ = rowsum(dO ∘ O).
+* backward, fused:  grid (B·H, K blocks, Q tiles) — scratch (dK, dV) of one
+                    key block; dQ of the head's **whole row**, float32, is
+                    an output block resident across both inner axes and
+                    accumulated in place.  A cell makes ``s``,
+                    the mask, ``p``, ``dp`` and the FlashAttention-2
+                    ``dS = P ∘ (dP − δ)``, δ = rowsum(dO ∘ O), once and
+                    adds all three gradients from them: five matrix
+                    products a tile.  Key blocks reach each query tile in
+                    ascending order, the order the dQ kernel below adds
+                    them in, so the bits are the pair's.
+* backward, split:  dK/dV on the same grid, then dQ on the forward's grid
+                    (scratch dQ of one block): each makes ``s`` … ``dS``
+                    again, seven products a tile.  Ring attention calls the
+                    pair by name (its offsets are traced and its dQ sums
+                    over ring steps outside), and a row too long for VMEM
+                    takes it.
+
+:func:`fused_bwd` chooses between the two from what the op can see: static
+offsets and the row's bytes (``_DQ_ROW_BYTES``: 16 MiB of the 64 MiB the
+fused launch is given, 32,768 positions of a 128-lane head).  The three
+backward kernels share :func:`_tile_grads` for the cell's expression.
 
 All matmuls run on the MXU in float32 accumulation
 (``preferred_element_type``) regardless of the bf16 inputs; masking (padded
@@ -35,7 +52,8 @@ alone, whether its tile holds any unmasked pair:
 * *outside* (none: above the causal diagonal, past ``seq_len``, outside the
   window): not computed and not fetched.  Where the sequence offsets are
   static (Python ints, as the standalone op passes) the causal index maps
-  clamp to the block's last (forward, dQ) or first (dK/dV) visible tile, so
+  clamp to the block's last (forward, dQ) or first (dK/dV, fused) visible
+  tile, so
   consecutive outside cells repeat a block index and Pallas issues no copy.
   Under ring attention the offsets are traced scalars that an index map
   cannot read: the maps stay plain there and the cell is only skipped.
@@ -78,12 +96,21 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "tile_visible", "tile_census"]
+__all__ = ["flash_attention", "tile_visible", "tile_census", "fused_bwd",
+           "train_tiles_visited", "fused_bwd_census"]
 
 _logger = logging.getLogger(__name__)
 
 _NEG_INF = float("-inf")
 _LANES = 128          # scalar-per-row scratch is lane-replicated to 128
+# the fused backward's scoped VMEM (ops/ssd.py and ops/causal_conv.py set the
+# same; a v5e core has 128 MiB) and the most of it the float32 dQ row of one
+# head may be: 16 MiB is 32,768 positions of a 128-lane head.  The row is an
+# output block, so Pallas holds two (the next head's fills while the last
+# one's is written back), beside the tile's operands and (block_q, block_k)
+# float32 intermediates, as in the split kernels.
+_VMEM_LIMIT = 64 * 1024 * 1024
+_DQ_ROW_BYTES = _VMEM_LIMIT // 4
 
 _warned_interpreted = set()
 
@@ -445,13 +472,48 @@ def _fwd(q, k, v, scale, block_q, block_k, causal, seq_len, interpret,
 # backward
 # ---------------------------------------------------------------------------
 
+def _tile_grads(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, it, jt,
+                q_off, kv_off, *, scale, seq_len, causal, window, dot_dtype):
+    """What every backward kernel makes of the cell that pairs query tile
+    ``it`` with key tile ``jt``: ``p = exp(s - lse)`` under the mask and
+    the FlashAttention-2 ``ds = p * (dp - delta)``, made in float32 and
+    returned, like the operands, in the MXU's dtype.  One function, so the
+    split pair and the fused kernel cannot drift apart.  Returns
+    (q, k, do, p, ds)."""
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    cd, scale_q = _dots(dot_dtype)
+    q = q_ref[0].astype(cd)
+    k = k_ref[0].astype(cd)
+    v = v_ref[0].astype(cd)
+    do = do_ref[0].astype(cd)
+    lse = lse_ref[0, :, :1]                                     # (BQ, 1)
+    delta = delta_ref[0, :, :1]
+    s = jax.lax.dot_general(_scaled(q, scale) if scale_q else q, k,
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if not scale_q:
+        s = _scaled(s, scale)
+    invalid = _invalid(it, jt, bq, bk, seq_len, causal, window, q_off,
+                       kv_off)
+    p = jnp.where(invalid, 0.0, jnp.exp(s - lse))               # (BQ, BK)
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = _scaled(p * (dp - delta), scale)
+    return q, k, do, p.astype(cd), ds.astype(cd)
+
+
+def _rows_dot(a, b):
+    """aᵀ·b: the (BQ, BK) tile ``a`` contracted over its rows, float32."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _bwd_dkv_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                     *, scale, seq_len, causal, window=None, dot_dtype=None,
                     q_tiles=None):
     """One (bh, k-block, q-tile) grid cell accumulating dK, dV."""
-    bk, d = k_ref.shape[1], k_ref.shape[2]
-    bq = q_ref.shape[1]
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
     jk = pl.program_id(1)
     iq = pl.program_id(2)
     nq = pl.num_programs(2)
@@ -463,36 +525,18 @@ def _bwd_dkv_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    cd, scale_q = _dots(dot_dtype)
     it = _q_tile(jk, iq, bq, bk, window)     # the query tile this cell reads
 
     # window: past the last query tile the clamped map repeats it
     @pl.when(tile_visible(it, jk, bq, bk, seq_len, causal, window, q_off,
                           kv_off, q_tiles))
     def _accumulate():
-        k = k_ref[0].astype(cd)
-        v = v_ref[0].astype(cd)
-        q = q_ref[0].astype(cd)
-        do = do_ref[0].astype(cd)
-        lse = lse_ref[0, :, :1]                                 # (BQ, 1)
-        delta = delta_ref[0, :, :1]
-        s = jax.lax.dot_general(_scaled(q, scale) if scale_q else q, k,
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if not scale_q:
-            s = _scaled(s, scale)
-        invalid = _invalid(it, jk, bq, bk, seq_len, causal, window, q_off,
-                           kv_off)
-        p = jnp.where(invalid, 0.0, jnp.exp(s - lse))           # (BQ, BK)
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(cd), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = _scaled(p * (dp - delta), scale)
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(cd), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        q, _, do, p, ds = _tile_grads(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, it, jk, q_off,
+            kv_off, scale=scale, seq_len=seq_len, causal=causal,
+            window=window, dot_dtype=dot_dtype)
+        dv_acc[:] += _rows_dot(p, do)
+        dk_acc[:] += _rows_dot(ds, q)
 
     @pl.when(iq == nq - 1)
     def _finalize():
@@ -504,8 +548,7 @@ def _bwd_dq_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, dq_ref, dq_acc, *, scale, seq_len,
                    causal, window=None, dot_dtype=None):
     """One (bh, q-block, k-tile) grid cell accumulating dQ."""
-    bq, d = q_ref.shape[1], q_ref.shape[2]
-    bk = k_ref.shape[1]
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
     iq = pl.program_id(1)
     jk = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -516,31 +559,17 @@ def _bwd_dq_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    cd, scale_q = _dots(dot_dtype)
     jt = _k_tile(iq, jk, bq, bk, window)
 
     @pl.when(tile_visible(iq, jt, bq, bk, seq_len, causal, window, q_off,
                           kv_off))
     def _accumulate():
-        q = q_ref[0].astype(cd)
-        k = k_ref[0].astype(cd)
-        v = v_ref[0].astype(cd)
-        do = do_ref[0].astype(cd)
-        lse = lse_ref[0, :, :1]                                 # (BQ, 1)
-        delta = delta_ref[0, :, :1]
-        s = jax.lax.dot_general(_scaled(q, scale) if scale_q else q, k,
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if not scale_q:
-            s = _scaled(s, scale)
-        invalid = _invalid(iq, jt, bq, bk, seq_len, causal, window, q_off,
-                           kv_off)
-        p = jnp.where(invalid, 0.0, jnp.exp(s - lse))
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = _scaled(p * (dp - delta), scale)
+        _, k, _, _, ds = _tile_grads(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, iq, jt, q_off,
+            kv_off, scale=scale, seq_len=seq_len, causal=causal,
+            window=window, dot_dtype=dot_dtype)
         dq_acc[:] += jax.lax.dot_general(
-            ds.astype(cd), k, (((1,), (0,)), ((), ())),
+            ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(jk == nk - 1)
@@ -548,12 +577,61 @@ def _bwd_dq_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv(q, k, v, do, lse, delta, scale, block_q, block_k, causal,
-             seq_len, interpret, q_off=0, kv_off=0, window=None,
-             dot_dtype=None):
-    """dK, dV for one KV buffer, streaming Q tiles.  Padded layout.  With
-    grouped heads the result is per QUERY head, (BH, Lk, ·): the caller sums
-    each group."""
+def _bwd_fused_kernel(q_off_ref, kv_off_ref, q_ref, k_ref, v_ref, do_ref,
+                      lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dk_acc,
+                      dv_acc, *, scale, seq_len, causal, window=None,
+                      dot_dtype=None, q_tiles=None):
+    """One (bh, k-block, q-tile) grid cell of the whole backward: the dK/dV
+    kernel's cell, which also adds ``ds·k`` into its query tile's rows of
+    ``dq_ref``, the head's whole float32 dQ row: an output block whose
+    index names only the head, so it stays in VMEM across both inner grid
+    axes and is written back when the head changes.  Key blocks reach every
+    query tile in ascending order, the order :func:`_bwd_dq_kernel` adds
+    them in."""
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    jk = pl.program_id(1)
+    iq = pl.program_id(2)
+    nq = pl.num_programs(2)
+    q_off = q_off_ref[0, 0]
+    kv_off = kv_off_ref[0, 0]
+
+    @pl.when((jk == 0) & (iq == 0))
+    def _init_head():
+        dq_ref[:] = jnp.zeros_like(dq_ref)
+
+    @pl.when(iq == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    it = _q_tile(jk, iq, bq, bk, window)
+
+    @pl.when(tile_visible(it, jk, bq, bk, seq_len, causal, window, q_off,
+                          kv_off, q_tiles))
+    def _accumulate():
+        q, k, do, p, ds = _tile_grads(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, it, jk, q_off,
+            kv_off, scale=scale, seq_len=seq_len, causal=causal,
+            window=window, dot_dtype=dot_dtype)
+        dv_acc[:] += _rows_dot(p, do)
+        dk_acc[:] += _rows_dot(ds, q)
+        rows = pl.ds(pl.multiple_of(it * bq, bq), bq)
+        dq_ref[0, rows, :] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(iq == nq - 1)
+    def _finalize():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _on_dkv_grid(fused, q, k, v, do, lse, delta, scale, block_q, block_k,
+                 causal, seq_len, interpret, q_off, kv_off, window,
+                 dot_dtype):
+    """The launch on the (bh, k block, q tile) grid: (dK, dV) per query
+    head, float32, from the dK/dV kernel or, ``fused``, the head's whole
+    dQ row before them from the fused one."""
     bh, lpq, d = q.shape
     lpk, dv = k.shape[1], v.shape[2]
     nq = lpq // block_q
@@ -569,8 +647,24 @@ def _bwd_dkv(q, k, v, do, lse, delta, scale, block_q, block_k, causal,
         (lambda b, j, i: (b // gk, j, 0))
     v_map = (lambda b, j, i: (b, j, 0)) if gv == 1 else \
         (lambda b, j, i: (b // gv, j, 0))
-    kern = functools.partial(_bwd_dkv_kernel, scale=scale, seq_len=seq_len,
-                             causal=causal, **kw)
+    out_specs = [
+        _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+        _vmem_spec((1, block_k, dv), lambda b, j, i: (b, j, 0)),
+    ]
+    out_shape = [
+        _out_struct((bh, lpk, d), jnp.float32, k),
+        _out_struct((bh, lpk, dv), jnp.float32, k),
+    ]
+    params = {}
+    if fused:
+        # the head's whole row: its index names only the head
+        out_specs.insert(0, _vmem_spec((1, lpq, d), lambda b, j, i: (b, 0, 0)))
+        out_shape.insert(0, _out_struct((bh, lpq, d), jnp.float32, q))
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT)
+    kern = functools.partial(
+        _bwd_fused_kernel if fused else _bwd_dkv_kernel, scale=scale,
+        seq_len=seq_len, causal=causal, **kw)
     return pl.pallas_call(
         kern,
         grid=(bh, lpk // block_k, nqt),
@@ -584,20 +678,37 @@ def _bwd_dkv(q, k, v, do, lse, delta, scale, block_q, block_k, causal,
             _vmem_spec((1, block_q, _LANES), q_map),
             _vmem_spec((1, block_q, _LANES), q_map),
         ],
-        out_specs=[
-            _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            _vmem_spec((1, block_k, dv), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            _out_struct((bh, lpk, d), jnp.float32, k),
-            _out_struct((bh, lpk, dv), jnp.float32, k),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             _scratch((block_k, d)),
             _scratch((block_k, dv)),
         ],
         interpret=interpret,
+        **params,
     )(_as_scalar(q_off), _as_scalar(kv_off), q, k, v, do, lse, delta)
+
+
+def _bwd_dkv(q, k, v, do, lse, delta, scale, block_q, block_k, causal,
+             seq_len, interpret, q_off=0, kv_off=0, window=None,
+             dot_dtype=None):
+    """dK, dV for one KV buffer, streaming Q tiles.  Padded layout.  With
+    grouped heads the result is per QUERY head, (BH, Lk, ·): the caller sums
+    each group."""
+    return _on_dkv_grid(False, q, k, v, do, lse, delta, scale, block_q,
+                        block_k, causal, seq_len, interpret, q_off, kv_off,
+                        window, dot_dtype)
+
+
+def _bwd_fused(q, k, v, do, lse, delta, scale, block_q, block_k, causal,
+               seq_len, interpret, q_off=0, kv_off=0, window=None,
+               dot_dtype=None):
+    """(dQ, dK, dV) from one launch on the dK/dV grid (:func:`fused_bwd`
+    says where), all float32 as :func:`_bwd_dq` and :func:`_bwd_dkv` give
+    them; dK, dV per query head."""
+    return _on_dkv_grid(True, q, k, v, do, lse, delta, scale, block_q,
+                        block_k, causal, seq_len, interpret, q_off, kv_off,
+                        window, dot_dtype)
 
 
 def _bwd_dq(q, k, v, do, lse, delta, scale, block_q, block_k, causal,
@@ -644,16 +755,37 @@ def _group_sum(x, heads: int):
     return x.reshape(heads, x.shape[0] // heads, *x.shape[1:]).sum(axis=1)
 
 
+def fused_bwd(lpq: int, d: int, static_offsets: bool = True) -> bool:
+    """Does the backward run as the one fused kernel?  Where the sequence
+    offsets are Python ints (the standalone op's zeros; ring attention's are
+    traced, and it calls the split pair by name) and the head's float32 dQ
+    row, ``lpq`` padded positions of ``d`` padded lanes, is within
+    ``_DQ_ROW_BYTES`` of VMEM.  Else dK/dV and dQ are two launches.  A
+    function of the shape alone, so a model can count its layers by it."""
+    return static_offsets and lpq * d * 4 <= _DQ_ROW_BYTES
+
+
+def _bwd_kernels(q, k, v, do, lse, delta, scale, block_q, block_k, causal,
+                 seq_len, interpret, q_off=0, kv_off=0, window=None,
+                 dot_dtype=None):
+    """(dQ, dK, dV) of the padded layout by whichever form :func:`fused_bwd`
+    names; dK, dV per query head."""
+    args = (q, k, v, do, lse, delta, scale, block_q, block_k, causal,
+            seq_len, interpret, q_off, kv_off, window, dot_dtype)
+    if fused_bwd(q.shape[1], q.shape[2],
+                 _static_off(q_off, kv_off) is not None):
+        return _bwd_fused(*args)
+    dk, dv = _bwd_dkv(*args)
+    return _bwd_dq(*args), dk, dv
+
+
 def _bwd(scale, block_q, block_k, causal, interpret, seq_len, res, g,
          window=None, dot_dtype=None):
     q, k, v, out, lse = res
     do = g[0] if isinstance(g, (tuple, list)) else g
-    delta = _delta(do, out)
-    kw = _kernel_kwargs(window, dot_dtype)
-    dk, dv = _bwd_dkv(q, k, v, do, lse, delta, scale, block_q, block_k,
-                      causal, seq_len, interpret, **kw)
-    dq = _bwd_dq(q, k, v, do, lse, delta, scale, block_q, block_k,
-                 causal, seq_len, interpret, **kw)
+    dq, dk, dv = _bwd_kernels(q, k, v, do, lse, _delta(do, out), scale,
+                              block_q, block_k, causal, seq_len, interpret,
+                              window=window, dot_dtype=dot_dtype)
     dk, dv = _group_sum(dk, k.shape[0]), _group_sum(dv, v.shape[0])
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
@@ -676,7 +808,8 @@ def tile_census(l: int, block_q: int = 128, block_k: int = 128,
     of them ``outside`` / ``visited`` (:func:`tile_visible` over the same
     grid-to-tile functions the kernels use).  Static per compiled shape, so
     a count and not a measurement: ``fwd`` and ``dq`` share a grid, ``dkv``
-    has the transposed one."""
+    has the transposed one, and the fused backward ``bwd`` runs on
+    ``dkv``'s (a step launches ``bwd`` or the pair, :func:`fused_bwd`)."""
     bq, bk, lpq, lpk = _blocks(l, block_q, block_k)
     nq, nk = lpq // bq, lpk // bk
 
@@ -693,7 +826,35 @@ def tile_census(l: int, block_q: int = 128, block_k: int = 128,
     j, i = np.meshgrid(np.arange(nk), np.arange(nqt), indexing="ij")
     dkv = count(tile_visible(np.asarray(_q_tile(j, i, bq, bk, window)), j,
                              bq, bk, l, causal, window, q_tiles=nq))
-    return {"fwd": qk, "dkv": dkv, "dq": dict(qk)}
+    return {"fwd": qk, "dkv": dkv, "dq": dict(qk), "bwd": dict(dkv)}
+
+
+def _op_fuses(l: int, head_dim: int, block_q: int) -> bool:
+    """:func:`fused_bwd` for :func:`flash_attention` over ``l`` tokens of
+    ``head_dim``-wide query heads at ``block_q``, as the op pads them."""
+    return fused_bwd(_blocks(l, block_q, block_q)[2],
+                     _round_up(head_dim, _LANES))
+
+
+def train_tiles_visited(l: int, head_dim: int, block_q: int = 128,
+                        block_k: int = 128, causal: bool = False,
+                        window: Optional[int] = None) -> int:
+    """Grid cells with a visible pair that one query head's forward and
+    backward launch over a row of ``l`` tokens: the forward's and the
+    fused backward's, or the split pair's where :func:`fused_bwd` refuses
+    the row (a forward made again under remat is not counted again)."""
+    census = tile_census(l, block_q, block_k, causal, window)
+    kernels = ("fwd", "bwd") if _op_fuses(l, head_dim, block_q) \
+        else ("fwd", "dkv", "dq")
+    return sum(census[kernel]["visited"] for kernel in kernels)
+
+
+def fused_bwd_census(layers: int, l: int, head_dim: int,
+                     block_q: int = 128):
+    """``layers`` attention layers over rows of ``l`` tokens by the form
+    their backward takes, (fused, split): what a model's
+    ``attn_bwd_layers`` reports."""
+    return (layers, 0) if _op_fuses(l, head_dim, block_q) else (0, layers)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

@@ -219,6 +219,9 @@ class Program:
     # routed expert layers of the train step by the form of their grouped
     # products, (kernels, array form): ops/moe.py
     moe_layers: Tuple[int, int] = (0, 0)
+    # attention layers of the train step by the form of their backward,
+    # (fused, split): ops/flash_attention.py:fused_bwd
+    attn_bwd_layers: Tuple[int, int] = (0, 0)
 
 
 def _choose_mesh(cfg: TrainConfig):
@@ -342,11 +345,17 @@ def build_program(cfg: TrainConfig, mesh=None) -> Program:
             cfg.batch_size * dp_size * cfg.seq_len)
         _logger.info("Routed expert layers: moe_kernel_layers=%d "
                      "moe_xla_layers=%d", *moe_layers)
+    attn_bwd_layers = (0, 0)
+    if hasattr(model, "attn_bwd_layers"):
+        attn_bwd_layers = model.attn_bwd_layers(cfg.seq_len)
+        _logger.info("Attention backward: attn_fused_bwd_layers=%d "
+                     "attn_split_bwd_layers=%d", *attn_bwd_layers)
     return Program(
         cfg=cfg, mesh=mesh, n_dev=n_dev, batch_axis=batch_axis, dp=dp_size,
         data_config=data_config, input_size=input_size, model=model,
         sequence_task=sequence_task, dw_grad_stages=dw_grad_stages,
         causal_conv_layers=causal_conv_layers, moe_layers=moe_layers,
+        attn_bwd_layers=attn_bwd_layers,
         lr=lr, tx=create_optimizer(cfg, learning_rate=lr),
         lr_scheduler=lr_scheduler, num_epochs=num_epochs,
         loss_fn=create_loss_fn(cfg),
@@ -520,6 +529,7 @@ def build_telemetry(program: Program, state, train_loader,
         if hasattr(model, "ssd_chunks") else 0,
         dw_grad_stages=program.dw_grad_stages,
         causal_conv_layers=program.causal_conv_layers,
+        attn_bwd_layers=program.attn_bwd_layers,
         # throughput is measured on the GLOBAL batch (the loader
         # assembles the global sharded array), so the MFU denominator
         # is the whole MESH's peak — n_dev == mesh.size, which a
@@ -774,7 +784,9 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
                         .causal_conv_layers[0],
                         causal_conv_xla_layers=program.causal_conv_layers[1],
                         moe_kernel_layers=program.moe_layers[0],
-                        moe_xla_layers=program.moe_layers[1])
+                        moe_xla_layers=program.moe_layers[1],
+                        attn_fused_bwd_layers=program.attn_bwd_layers[0],
+                        attn_split_bwd_layers=program.attn_bwd_layers[1])
         if resumed_from:
             telemetry.event("resume", path=resumed_from,
                             epoch=start_epoch, batch=resume_batch)

@@ -2,9 +2,11 @@
 brute-force mask, ``tile_census``, the clamped index maps (an outside cell
 names the block of its row's nearest visited cell; a visited cell the block
 it named at the parent commit; the kernels under them give the plain maps'
-bits), and shapes of several tiles a side bit for bit equal to the parent
+bits), shapes of several tiles a side bit for bit equal to the parent
 commit's kernels (tests/fixtures/flash_attention_tiles_parent.npz, written
-by this file run as a script against a checkout of the parent)."""
+by this file run as a script against a checkout of the parent), and the
+fused backward against the dK/dV and dQ pair: where ``fused_bwd`` sends a
+shape, and the same bits either way."""
 
 import importlib
 import os
@@ -100,7 +102,7 @@ CENSUS_CASES = [
 @pytest.mark.parametrize("args,want", CENSUS_CASES)
 def test_census_counts_the_classes_of_each_kernels_grid(args, want):
     census = FA.tile_census(*args)
-    assert sorted(census) == ["dkv", "dq", "fwd"]
+    assert sorted(census) == ["bwd", "dkv", "dq", "fwd"]
     for kernel, c in census.items():
         assert c["outside"] + c["visited"] == c["cells"], kernel
         assert (c["cells"], c["outside"]) == want, kernel
@@ -109,7 +111,24 @@ def test_census_counts_the_classes_of_each_kernels_grid(args, want):
 def test_census_of_unequal_blocks_gives_each_grid_its_own_count():
     c = FA.tile_census(600, 128, 256, True, 10)
     assert c["fwd"] == c["dq"] == {"cells": 10, "outside": 3, "visited": 7}
-    assert c["dkv"] == {"cells": 12, "outside": 5, "visited": 7}
+    assert c["dkv"] == c["bwd"] == {"cells": 12, "outside": 5, "visited": 7}
+
+
+@pytest.mark.parametrize("args", [
+    (16384, 64, 1024, 1024, True, None), (16384, 64, 512, 512, True, 512),
+    (600, 16, 128, 256, True, 10), (197, 64, 128, 128, False, None)],
+    ids=["16k-causal", "16k-window", "unequal-blocks", "vit-197"])
+def test_a_train_steps_tiles_are_the_forwards_and_one_backward_grids(
+        args, monkeypatch):
+    """Where the backward is fused a step visits the forward's cells and the
+    dK/dV grid's once; where the predicate refuses the row, the pair's."""
+    l, head_dim, *rest = args
+    c = FA.tile_census(l, *rest)
+    assert FA.train_tiles_visited(*args) == \
+        c["fwd"]["visited"] + c["bwd"]["visited"]
+    monkeypatch.setattr(FA, "_DQ_ROW_BYTES", 128 * 128 * 4 - 1)
+    assert FA.train_tiles_visited(*args) == sum(
+        c[kernel]["visited"] for kernel in ("fwd", "dkv", "dq"))
 
 
 # ---- the index maps ---------------------------------------------------------
@@ -323,6 +342,123 @@ def test_shapes_of_several_tiles_equal_the_parents_kernels_bit_for_bit(name):
     for key, got in _run_case(name).items():
         assert got.dtype == gold[key].dtype, key
         assert np.array_equal(got, gold[key]), key
+
+
+# ---- the fused backward against the dK/dV and dQ pair -----------------------
+
+@pytest.mark.parametrize("lpq,d,static,want", [
+    (16384, 128, True, True),       # the sequence cells' rows: 8 MiB
+    (8192, 128, True, True),
+    (32768, 128, True, True),       # the budget: 16 MiB
+    (32768 + 128, 128, True, False),
+    (16384, 256, True, True),       # a 256-lane head halves the length
+    (16384 + 128, 256, True, False),
+    (256, 128, False, False),       # ring attention's traced offsets
+])
+def test_the_backward_fuses_where_offsets_are_static_and_the_row_fits(
+        lpq, d, static, want):
+    assert FA._DQ_ROW_BYTES == 16 * 2 ** 20 <= FA._VMEM_LIMIT // 4
+    assert FA.fused_bwd(lpq, d, static) is want
+    assert FA.fused_bwd_census(3, lpq, d if d > 128 else 64, 128) == (
+        (3, 0) if FA.fused_bwd(lpq, d) else (0, 3))
+
+
+# beside PARENT_CASES (causal / plain / window, unequal blocks, grouped k and
+# a wider grouped v as phi4's in small, a scale that folds and one that does
+# not): a bidirectional grouped bf16 shape, a window under unequal blocks and
+# a float32 scale that is no power of two
+FUSED_CASES = dict(PARENT_CASES, **{
+    "plain_bf16_grouped_dv": (530, 4, 2, 1, 16, 32, "bfloat16", dict(
+        dot_dtype=jnp.bfloat16)),
+    "window_bq128_bk256_f32": (600, 2, 1, 1, 16, 16, "float32", dict(
+        causal=True, window=10, block_q=128, block_k=256)),
+    "causal_f32_scale_not_pow2": (530, 2, 2, 2, 16, 16, "float32", dict(
+        causal=True, scale=0.3)),
+    "one_tile_bf16": (100, 2, 1, 1, 64, 64, "bfloat16", dict(
+        causal=True, dot_dtype=jnp.bfloat16)),
+})
+
+
+def _grads(name):
+    l, h, hk, hv, d, dv, dtype, kw = FUSED_CASES[name]
+    ks = jax.random.split(jax.random.PRNGKey(33), 4)
+    q = jax.random.normal(ks[0], (2, l, h, d), dtype)
+    k = jax.random.normal(ks[1], (2, l, hk, d), dtype)
+    v = jax.random.normal(ks[2], (2, l, hv, dv), dtype)
+    w = jax.random.normal(ks[3], (2, l, h, dv), jnp.float32)
+    return jax.grad(lambda *a: jnp.sum(
+        flash_attention(*a, **kw).astype(jnp.float32) * w), (0, 1, 2))(
+            q, k, v)
+
+
+def _spy(monkeypatch, *names):
+    """Count the launches of the module's builders ``names``."""
+    calls = dict.fromkeys(names, 0)
+    for name in names:
+        def counted(*a, _name=name, _fn=getattr(FA, name), **kw):
+            calls[_name] += 1
+            return _fn(*a, **kw)
+        monkeypatch.setattr(FA, name, counted)
+    return calls
+
+
+@pytest.mark.parametrize("name", sorted(FUSED_CASES))
+def test_the_fused_backward_equals_the_split_pair_bit_for_bit(
+        name, monkeypatch):
+    """One launch on the dK/dV grid gives dQ, dK and dV the bits the two
+    kernels give: the same expression a tile, and every query tile's key
+    blocks added in the dQ kernel's order."""
+    calls = _spy(monkeypatch, "_bwd_fused", "_bwd_dkv", "_bwd_dq")
+    fused = _grads(name)
+    assert calls == {"_bwd_fused": 1, "_bwd_dkv": 0, "_bwd_dq": 0}
+    monkeypatch.setattr(FA, "fused_bwd", lambda *a, **kw: False)
+    split = _grads(name)
+    assert calls == {"_bwd_fused": 1, "_bwd_dkv": 1, "_bwd_dq": 1}
+    for n, a, b in zip(("dq", "dk", "dv"), fused, split):
+        assert a.dtype == b.dtype and a.shape == b.shape, n
+        assert np.isfinite(np.asarray(a, np.float32)).all(), n
+        assert np.array_equal(_bits(a), _bits(b)), n
+
+
+@pytest.mark.parametrize("refusal", ["traced-offsets", "row-past-the-budget"])
+def test_what_the_predicate_refuses_takes_the_split_pair(refusal,
+                                                         monkeypatch):
+    """Ring attention's traced offsets and a dQ row too long for VMEM run
+    dK/dV and dQ as two launches, with the fused kernel's bits."""
+    l, blk = 512, 128
+    q, k, v, do = _kernel_operands(l)
+    args = (128 ** -0.5, blk, blk, True, l - 20, True)
+    out, lse = FA._fwd(q, k, v, *args)
+    operands = (q, k, v, do, lse, FA._delta(do, out), *args)
+    fused = FA._bwd_kernels(*operands)
+    calls = _spy(monkeypatch, "_bwd_fused", "_bwd_dkv", "_bwd_dq")
+    if refusal == "traced-offsets":
+        split = FA._bwd_kernels(*operands, jnp.int32(0), jnp.int32(0))
+    else:
+        monkeypatch.setattr(FA, "_DQ_ROW_BYTES", l * 128 * 4 - 1)
+        split = FA._bwd_kernels(*operands)
+    assert calls == {"_bwd_fused": 0, "_bwd_dkv": 1, "_bwd_dq": 1}
+    for a, b in zip(fused, split):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("name,seq_len,layers,tiles", [
+    # 40 heads: the full and the cross layer 136 of 256 cells at blocks of
+    # 1024, the window layer 63 of 96 at blocks of 512
+    ("phi4_mini_flash_6l", 16384, (3, 0), 40 * 2 * (136 + 136 + 63)),
+    ("granite4_h_micro_10l", 16384, (1, 0), 32 * 2 * 136),
+    ("lfm2_24b_a2b_5l", 8192, (1, 0), 32 * 2 * 36),
+    ("granite4_h_micro_10l", 32769, (0, 1), 32 * 3 * 561),
+], ids=["phi4", "granite", "lfm2", "granite-row-past-the-budget"])
+def test_the_sequence_cells_models_count_their_layers_by_the_predicate(
+        name, seq_len, layers, tiles):
+    """What ``build_program`` logs and ``run_start`` carries for the three
+    sequence configurations at their cells' lengths, on any backend, and the
+    census of cells that go with it: forward + one backward grid."""
+    from deepfake_detection_tpu.models import create_model
+    model = create_model(name)
+    assert model.attn_bwd_layers(seq_len) == layers
+    assert model.attn_tiles_visited(seq_len) == tiles
 
 
 if __name__ == "__main__":

@@ -359,8 +359,12 @@ def test_telemetry_counts_the_scans_chunks_by_the_models_census():
         t.on_step(2, 0.0, 0.1, tokens=2 * 40)
     c = t.snapshot()["counters"]
     assert c["ssd_chunks_total"] == 3 * 2 * 45
-    # one attention layer, four query heads, three kernels, one tile each
-    assert c["attn_tiles_visited_total"] == 3 * 2 * 4 * 3
+    # one attention layer, four query heads, the forward and the fused
+    # backward, one tile each
+    assert c["attn_tiles_visited_total"] == 3 * 2 * 4 * 2
+    assert tiny.attn_bwd_layers(40) == (1, 0)
+    assert cell.attn_bwd_layers(16384) == (1, 0)
+    assert cell.attn_bwd_layers(32769) == (0, 1)    # a row past the budget
     assert "dfd_train_ssd_chunks_total" in t.render_prometheus()
     other = TrainTelemetry()
     other.on_step(3, 0.0, 0.1)

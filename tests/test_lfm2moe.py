@@ -392,6 +392,7 @@ def test_the_routing_counts_reach_the_telemetry_at_the_drain(tmp_path):
         "--log-interval", "3", "--workers", "1", "--output", str(tmp_path)])
     program = T.build_program(cfg)
     assert program.moe_layers == (0, 4)        # the CPU takes the array form
+    assert program.attn_bwd_layers == (0, 0)   # --attn-impl full: no kernel
     state, shardings = T.init_state(program, jax.random.PRNGKey(0))
     train_ds, _ = T.build_datasets(cfg, program.input_size,
                                    vocab_rows=program.model.vocab_rows)

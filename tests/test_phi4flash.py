@@ -378,10 +378,13 @@ def test_telemetry_counts_the_attention_tiles_by_the_models_census():
     assert (window["cells"], window["visited"]) == (15, 9)
     full = tile_census(2100, 1024, 1024, True)["dkv"]
     assert (full["cells"], full["visited"]) == (9, 6)
-    # 4 query heads, 3 kernels, one window, one full and one cross layer
+    # 4 query heads, the forward and the one fused backward, one window, one
+    # full and one cross layer
     visited = model.attn_tiles_visited(2100)
-    assert visited == 4 * 3 * (9 + 6 + 6)
-    assert model.attn_tiles_visited(300) == 4 * 3 * 3    # one tile a layer
+    assert visited == 4 * 2 * (9 + 6 + 6)
+    assert model.attn_tiles_visited(300) == 4 * 2 * 3    # one tile a layer
+    assert model.attn_bwd_layers(2100) == (3, 0)
+    assert model.clone(attn_impl="full").attn_bwd_layers(2100) == (0, 0)
     assert model.clone(attn_impl="full").attn_tiles_visited(2100) == 0
     t = TrainTelemetry(attn_tiles_per_sample=visited)
     for _ in range(3):

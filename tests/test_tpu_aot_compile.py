@@ -196,7 +196,8 @@ def test_flash_attention_vit_b16_compiles(one_chip, grad):
     (512, 256), (None, 512), (512, 512), (None, 1024)],
     ids=["window-256", "full-512", "window", "full"])
 def test_flash_attention_phi4flash_16k_compiles(one_chip, window, block):
-    """Phi-4-mini-flash at 16,384 tokens, forward and both backward kernels:
+    """Phi-4-mini-flash at 16,384 tokens, forward and the fused backward
+    (an 8 MiB float32 dQ row resident in VMEM beside the tile):
     40 query heads and 20 key heads of 64 reading 10 value pairs of 128
     through the clamped index maps, bf16 operands, the window layer's shrunk
     grid and the full layer's causal one, at the model's own block sizes
@@ -208,6 +209,23 @@ def test_flash_attention_phi4flash_16k_compiles(one_chip, window, block):
         q, k, v, causal=True, window=window, block_q=block, block_k=block,
         dot_dtype=jnp.bfloat16, interpret=False).astype(jnp.float32).sum(),
         argnums=(0, 1, 2)), spec(40, 64), spec(20, 64), spec(10, 128))
+
+
+@pytest.mark.parametrize("l,kernels", [(32768, 2), (32768 + 1024, 3)],
+                         ids=["fused-at-the-budget", "split-past-it"])
+def test_flash_attention_backward_at_the_dq_rows_budget(one_chip, l,
+                                                        kernels):
+    """The longest row whose backward is the one fused kernel (a 16 MiB
+    float32 dQ row, held twice as an output block, under the launch's 64 MiB)
+    compiles, and one block more takes the dK/dV and dQ pair: forward +
+    backward are 2 Mosaic calls and 3."""
+    spec = jax.ShapeDtypeStruct((1, l, 8, 64), jnp.bfloat16,
+                                sharding=one_chip)
+    compiled = _compile(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=1024, block_k=1024,
+        dot_dtype=jnp.bfloat16, interpret=False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), spec, spec, spec)
+    assert compiled.as_text().count("tpu_custom_call") == kernels
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])

@@ -217,6 +217,16 @@ def test_telemetry_is_the_runners(built, tmp_path):
     assert (snap["gauges"]["causal_conv_kernel_layers"],
             snap["gauges"]["causal_conv_xla_layers"]) == p.causal_conv_layers
     assert p.causal_conv_layers[0] == 0
+    # the attention backward's census (PR 33): a function of the shape alone,
+    # so the CPU reads what the chip reads; image models have no such layer
+    assert p.attn_bwd_layers == (
+        p.model.attn_bwd_layers(p.cfg.seq_len)
+        if hasattr(p.model, "attn_bwd_layers") else (0, 0))
+    assert (snap["gauges"]["attn_fused_bwd_layers"],
+            snap["gauges"]["attn_split_bwd_layers"]) == p.attn_bwd_layers
+    assert (p.attn_bwd_layers[0] > 0) == p.sequence_task
+    assert p.attn_bwd_layers[1] == 0
+    assert "dfd_train_attn_fused_bwd_layers" in telemetry.render_prometheus()
     assert os.path.isfile(tmp_path / "telemetry.jsonl")
 
 

@@ -11,6 +11,21 @@ are only meaningful on a real TPU — the tool exists so the measurement is
 one command on a chip::
 
     python tools/bench_attention.py [--iters 20] [--seqs 196,1024,4096]
+
+``--bwd`` (TPU only) times the backward of the flash kernels alone, the
+split pair (dK/dV, then dQ) against the one fused kernel, at the attention
+shapes of the three sequence cells (heads, kv heads, value width, length,
+block, window: ``BWD_SHAPES``).  A launch's time comes from ``CHAIN``
+launches in one program, each fed by the one before (the next launch's ``k``
+is this one's plus 1e-30 of a ``dq`` element; one pass over ``k``, under 0.5%
+of a launch), because one launch by the host's clock is the dispatch
+(PERF.md section 7, PR 29 (c)).  One JSON line a shape, with whether the two
+forms' gradients are the same bits on this device.  A probe, not the cell:
+PRs 27, 29 and 31 all found the cell to disagree with a kernel timed alone.
+``--step CELL [fused] [split]`` (TPU only) asks the cell: a sequence cell's
+own train step, as the benchmark's driver builds it, ten steps a form by the
+host's clock; ``split`` rebinds the op's predicate so that every layer takes
+the dK/dV and dQ pair.
 """
 
 from __future__ import annotations
@@ -25,8 +40,151 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
+# name: (rows a launch, L, q heads, k heads, v heads, d, dv, block, window)
+# as models/phi4flash.py, granite4h.py and lfm2moe.py call the op in the
+# cells train_phi4flash_long, train_granite4h_long, train_lfm2moe_8k (a
+# microbatch of 2 rows); every scale is a power of two, folded into q
+BWD_SHAPES = {
+    "phi4_full": (1, 16384, 40, 20, 10, 64, 128, 1024, None),
+    "phi4_window": (1, 16384, 40, 20, 10, 64, 128, 512, 512),
+    "granite_full": (1, 16384, 32, 8, 8, 64, 64, 1024, None),
+    "lfm2_full": (2, 8192, 32, 8, 8, 64, 64, 1024, None),
+}
+CHAIN = 8
+
+
+def bwd_probe(names) -> None:
+    import importlib
+    import statistics
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    fa = importlib.import_module("deepfake_detection_tpu.ops.flash_attention")
+    assert jax.default_backend() == "tpu", jax.default_backend()
+
+    def chain_ms(step, *operands):
+        @jax.jit
+        def run(k, *rest):
+            keep = jnp.float32(0)
+            for _ in range(CHAIN):
+                dq, dk, dv = step(k, *rest)
+                k = k + (dq[0, 0, 0].astype(jnp.float32) * 1e-30
+                         ).astype(k.dtype)
+                keep = keep + dk[0, 0, 0] + dv[0, 0, 0]
+            return k, keep
+        jax.block_until_ready(run(*operands))
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run(*operands))
+            times.append(time.perf_counter() - t0)
+        return 1e3 * statistics.median(times) / CHAIN
+
+    def probe(name):
+        b, l, h, hk, hv, d, dv, block, window = BWD_SHAPES[name]
+        bq, bk, lpq, lpk = fa._blocks(l, block, block)
+        keys = jax.random.split(jax.random.PRNGKey(0), 4)
+
+        def operand(key, heads, width, lp):
+            x = jax.random.normal(key, (b * heads, l, width), jnp.bfloat16)
+            return jnp.pad(x, ((0, 0), (0, lp - l),
+                               (0, fa._round_up(width, 128) - width)))
+        q = operand(keys[0], h, d, lpq) * d ** -0.5
+        k, v = operand(keys[1], hk, d, lpk), operand(keys[2], hv, dv, lpk)
+        do = operand(keys[3], h, dv, lpq)
+        kw = dict(window=window, dot_dtype=jnp.bfloat16)
+        static = (1.0, bq, bk, True, l, False)
+        out, lse = jax.jit(lambda q, k, v: fa._fwd(q, k, v, *static, **kw))(
+            q, k, v)
+        delta = fa._delta(do, out)
+
+        def split(k, q, v, do, lse, delta):
+            dk, dv_ = fa._bwd_dkv(q, k, v, do, lse, delta, *static, **kw)
+            dq = fa._bwd_dq(q, k, v, do, lse, delta, *static, **kw)
+            return dq, dk, dv_
+
+        def fused(k, q, v, do, lse, delta):
+            return fa._bwd_fused(q, k, v, do, lse, delta, *static, **kw)
+
+        rest = (q, v, do, lse, delta)
+        same = [bool(np.array_equal(np.asarray(a.astype(jnp.float32)),
+                                    np.asarray(b_.astype(jnp.float32))))
+                for a, b_ in zip(jax.jit(split)(k, *rest),
+                                 jax.jit(fused)(k, *rest))]
+        visited = b * h * fa.tile_census(l, block, block, True,
+                                         window)["bwd"]["visited"]
+        row = {"shape": name, "rows": b, "seq_len": l, "heads": [h, hk, hv],
+               "head_dims": [d, dv], "block": block, "window": window,
+               "fused_bwd": fa.fused_bwd(lpq, q.shape[2]),
+               "visited_tiles": visited, "chain": CHAIN,
+               "split_ms": chain_ms(split, k, *rest),
+               "fused_ms": chain_ms(fused, k, *rest),
+               "bit_equal": dict(zip(("dq", "dk", "dv"), same)),
+               "device": jax.devices()[0].device_kind}
+        row["split_us_per_tile"] = 1e3 * row["split_ms"] / visited
+        row["fused_us_per_tile"] = 1e3 * row["fused_ms"] / visited
+        print(json.dumps(row), flush=True)
+
+    for name in names:
+        probe(name)
+
+
+def cell_step(cell_name: str, forms) -> None:
+    import gc
+    import importlib
+    import statistics
+
+    import jax
+
+    from benchmark.drivers import train_seq as D
+    from benchmark.lib import manifest as M
+    fa = importlib.import_module("deepfake_detection_tpu.ops.flash_attention")
+    cell = M.Cell(cell_name)
+    D.require_chips(cell.chips)
+    D.setup_cache(cell.cache_dir)
+    seed, predicate = 20261005, fa.fused_bwd
+    for form in forms:
+        fa.fused_bwd = predicate if form == "fused" else \
+            (lambda *a, **kw: False)
+        built = D.TokenBuilt(cell, os.path.join(cell.cache_dir,
+                                                "attn_probe_" + form))
+        dataset, variables, _ = D.make_inputs(cell, seed, built.global_batch)
+        state = built.state_for(variables)
+        loader, _ = built.loader_for(dataset, seed, 0)
+        loader.set_epoch(0)
+        rng = built.rng_for(seed)
+        times = []
+        for x, y in loader:
+            t0 = time.perf_counter()
+            state, metrics = built.train_step(state, x, y, rng)
+            jax.block_until_ready(metrics["loss"])
+            times.append(time.perf_counter() - t0)
+        mem = jax.devices()[0].memory_stats() or {}
+        print(json.dumps({
+            "cell": cell_name, "form": form, "first_step_s": times[0],
+            "steps_ms": [round(1e3 * t, 2) for t in times[1:]],
+            "median_ms": 1e3 * statistics.median(times[1:]),
+            "loss": float(metrics["loss"]),
+            "peak_bytes_in_use": mem.get("peak_bytes_in_use"),
+            "attn_bwd_layers": list(
+                built.model.attn_bwd_layers(built.cfg.seq_len)),
+            "device": jax.devices()[0].device_kind}), flush=True)
+        loader.close()
+        del state, variables, built, loader
+        gc.collect()
+    fa.fused_bwd = predicate
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--bwd", nargs="*", choices=sorted(BWD_SHAPES),
+                    metavar="SHAPE", help="time the split and the fused "
+                    "backward at these cells' shapes (none named: all)")
+    ap.add_argument("--step", nargs="+", metavar="CELL [FORM ...]",
+                    help="a sequence cell's own train step with the fused "
+                    "and/or the split backward (default: both)")
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--heads", type=int, default=12)
@@ -34,6 +192,12 @@ def main() -> None:
     ap.add_argument("--seqs", default="196,1024,4096")
     ap.add_argument("--dtype", default="bfloat16")
     args = ap.parse_args()
+    if args.bwd is not None:
+        return bwd_probe(args.bwd or sorted(BWD_SHAPES))
+    if args.step:
+        forms = args.step[1:] or ["fused", "split"]
+        assert set(forms) <= {"fused", "split"}, forms
+        return cell_step(args.step[0], forms)
 
     import jax
     import jax.numpy as jnp
