@@ -63,14 +63,20 @@ class LoaderStats:
     region is also a ``dfd.input.*`` span on the profiler's clock.
     """
 
-    __slots__ = ("batches", "host_wait_s", "stage_block_s", "stage_s",
-                 "augment_elided")
+    __slots__ = ("batches", "host_wait_s", "stage_block_s", "h2d_block_s",
+                 "prologue_block_s", "stage_s", "augment_elided")
 
     def __init__(self):
         self.batches = 0        # batches staged to device
         self.host_wait_s = 0.0  # blocked in next(host_loader) — input starved
-        self.stage_block_s = 0.0  # blocked in the slab-recycle
-        # block_until_ready — prologue/staging backpressure (device busy)
+        self.stage_block_s = 0.0  # blocked in the slab-recycle wait: the
+        # sum of the two below
+        self.h2d_block_s = 0.0  # ... on the batch's host-to-device copy.
+        # The pipeline starving the chip only where the device is idle too
+        # (device_idle_share.train): a copy queued behind a running step
+        # waits here as well, and the loop would wait below instead
+        self.prologue_block_s = 0.0  # ... then on its prologue, queued
+        # behind the running step (the chip is the bottleneck)
         self.stage_s = 0.0      # in _stage: device_put + prologue dispatch
         self.augment_elided = 0  # host augment stages elided by
         # --augment-device (samples x stages moved into the prologue)
@@ -80,12 +86,10 @@ class HostLoaderStats:
     """Producer-side thread-backend counters (written by the producer
     thread; same single-writer torn-proof contract as LoaderStats)."""
 
-    __slots__ = ("batches", "fetch_s", "load_s", "collate_s", "mixup_s",
-                 "put_wait_s")
+    __slots__ = ("batches", "load_s", "collate_s", "mixup_s", "put_wait_s")
 
     def __init__(self):
         self.batches = 0        # batches collated
-        self.fetch_s = 0.0      # load_s + collate_s + mixup_s
         self.load_s = 0.0       # pool.map over the batch: decode+transform
         self.collate_s = 0.0    # fast_collate: stack to one uint8 array
         self.mixup_s = 0.0      # the collate_mixup call (uint8 blend)
@@ -216,7 +220,7 @@ class HostLoader:
                                         chaos.arg("stall_loader", 120.0), bi)
                         time.sleep(chaos.arg("stall_loader", 120.0))
                     # the three phases of a batch, each a span on the
-                    # profiler's clock and a counter; fetch_s is their sum
+                    # profiler's clock and a counter
                     t0 = time.monotonic()
                     with TraceAnnotation("dfd.input.load", batch=bi):
                         samples = list(pool.map(self._load_one, batch_idx))
@@ -235,7 +239,6 @@ class HostLoader:
                     stats.load_s += t1 - t0
                     stats.collate_s += t2 - t1
                     stats.mixup_s += t3 - t2
-                    stats.fetch_s += t3 - t0
                     stats.batches += 1
                     if vms is not None:
                         item: Any = (images, targets, vms[bi])
@@ -436,7 +439,9 @@ class DeviceLoader:
 
     def _stage(self, item, base_key, batch_index: int = 0,
                indices: Optional[Sequence[int]] = None):
-        """device_put + dispatch the prologue for one host batch."""
+        """device_put + dispatch the prologue for one host batch.  Returns
+        the staged batch and the images' device copy (what the prologue
+        reads: alive until it has run in any case, nothing is donated)."""
         images, targets = item[0], item[1]
         key = jax.random.fold_in(base_key, self._step)
         self._step += 1
@@ -459,18 +464,20 @@ class DeviceLoader:
                     bool(cm is not None and cm.mixup_enabled))
             else:
                 lam, om = np.float32(1.0), np.float32(0.0)
-            x = self._prologue(self._put(images), key, self._put(geom),
+            put = self._put(images)
+            x = self._prologue(put, key, self._put(geom),
                                self._put(blur_mask), lam, om)
             self.stats.augment_elided += \
                 images.shape[0] * self._augment.host_stages_elided
         else:
-            x = self._prologue(self._put(images), key)
+            put = self._put(images)
+            x = self._prologue(put, key)
         # targets/valid views may be ring-slab backed: small, copy before
         # the put so slot recycling can never touch them
         y = self._put(np.array(targets))
         if len(item) == 3:
-            return x, y, self._put(np.array(item[2]))
-        return x, y
+            return (x, y, self._put(np.array(item[2]))), put
+        return (x, y), put
 
     def __iter__(self):
         base_key = jax.random.PRNGKey(self.seed)
@@ -490,19 +497,33 @@ class DeviceLoader:
         # overlaps the consumer's compiled step on batch k — the async-
         # dispatch equivalent of the reference's CUDA-stream prefetcher.
         pending = None
-        prev_x = None
+        prev_x = prev_put = None
         stats = self.stats
         while True:
             if prev_x is not None:
                 # the shm ring recycles batch k's slab once batch k+2 is
                 # requested; jax CPU device_put zero-copies aligned host
                 # buffers, so batch k's prologue (the only reader of the
-                # slab) must have RUN before we pull the next host batch
+                # slab) must have RUN before we pull the next host batch.
+                # One chain, awaited in two places: the copy the prologue
+                # reads, then the prologue itself (which depends on it).  A
+                # long first wait on an idle device is the copy starving
+                # the chip; on a busy device the two waits trade places
+                # and their sum is the chip's backlog
                 t0 = time.monotonic()
                 with TraceAnnotation("dfd.input.stage_block", batch=bi - 1):
-                    jax.block_until_ready(prev_x)   # batch bi-1's prologue
-                stats.stage_block_s += time.monotonic() - t0
-                prev_x = None
+                    with TraceAnnotation("dfd.input.h2d_block",
+                                         batch=bi - 1):
+                        jax.block_until_ready(prev_put)
+                    t1 = time.monotonic()
+                    with TraceAnnotation("dfd.input.prologue_block",
+                                         batch=bi - 1):
+                        jax.block_until_ready(prev_x)   # batch bi-1's
+                t2 = time.monotonic()
+                stats.h2d_block_s += t1 - t0
+                stats.prologue_block_s += t2 - t1
+                stats.stage_block_s += t2 - t0
+                prev_x = prev_put = None
             try:
                 t0 = time.monotonic()
                 with TraceAnnotation("dfd.input.host_wait", batch=bi):
@@ -512,14 +533,14 @@ class DeviceLoader:
                 break
             t0 = time.monotonic()
             with TraceAnnotation("dfd.input.stage", batch=bi):
-                staged = self._stage(item, base_key, batch_index=bi,
-                                     indices=None if batches is None
-                                     else batches[bi])
+                staged, put = self._stage(item, base_key, batch_index=bi,
+                                          indices=None if batches is None
+                                          else batches[bi])
             stats.stage_s += time.monotonic() - t0
             bi += 1
             stats.batches += 1
             if pending is not None:
-                prev_x = staged[0]
+                prev_x, prev_put = staged[0], put
                 yield pending
             pending = staged
         if pending is not None:

@@ -304,7 +304,6 @@ class ShmRingLoader:
         # telemetry counters (obs/telemetry.py loader_collector): lifetime
         # totals, single-writer (the consumer thread), torn-proof reads
         self.stall_sweeps = 0           # lost-ack re-dispatch sweeps fired
-        self.collect_wait_s = 0.0       # consumer blocked waiting on a batch
         self.inflight_batches = 0       # dispatched, not yet yielded (ring
         # occupancy = inflight_batches / ring_depth)
 
@@ -493,8 +492,7 @@ class ShmRingLoader:
     def _collect(self, bi: int, done: Dict[int, Set[int]],
                  batches: List[List[int]], epoch: int, gen: int) -> None:
         need = len(batches[bi])
-        t_enter = time.monotonic()
-        last_progress = t_enter
+        last_progress = time.monotonic()
         sweeps = 0
         while len(done.get(bi, ())) < need:
             try:
@@ -537,7 +535,6 @@ class ShmRingLoader:
                 raise RuntimeError(
                     f"shm worker failed on sample {j} of batch {dbi}: {err}")
             done.setdefault(dbi, set()).add(j)
-        self.collect_wait_s += time.monotonic() - t_enter
 
     def __iter__(self):
         batches, vms = epoch_batches(self.sampler, self.batch_size,

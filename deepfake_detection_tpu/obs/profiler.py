@@ -53,7 +53,10 @@ def start_trace(trace_dir: str) -> None:
     distorts the host-bound loop it looks at.  Host tracer level 1 keeps
     the program's own ``dfd.*`` spans (``TraceAnnotation``) and the
     runtime's launch events, on the clock the device planes use.  The
-    device's trace mode is left at the backend's default.  Stop with
+    device's trace mode is left at the backend's default: a process's
+    first capture of a device-bound loop stalls in either mode, and the
+    benchmark's ``TRACE_ONLY_XLA`` stalled its second capture too where
+    the default did not (PERF.md section 7, PR 34's probe).  Stop with
     ``jax.profiler.stop_trace()``."""
     import jax
     opts = jax.profiler.ProfileOptions()

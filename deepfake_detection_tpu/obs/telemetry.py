@@ -20,6 +20,13 @@ boundaries add two more counters (``input_*``): time blocked in
 ``block_until_ready`` (prologue/staging backpressure) — both are waits the
 loader already performed; the tracker only timestamps them.
 
+**Steps are kept apart.**  Each :meth:`TrainTelemetry.on_step` writes one
+row (the step's period and its phases: ``STEP_FIELDS``) into the drain
+window; the drain's JSONL record carries the rows as ``steps``, and each
+step is judged against the median of the 16 before it, so that slow steps
+are counted, priced and filed under the phase that grew, in counters any
+snapshot carries (no drain needed inside the interval that is read).
+
 The process's compilations are counted too (``compiles_total`` and the
 seconds jax spent tracing, lowering and in the backend's compiler): one
 ``jax.monitoring`` listener, installed when this module is imported so that
@@ -41,10 +48,11 @@ from __future__ import annotations
 
 import logging
 import os
+import statistics
 import threading
 import time
-from collections import OrderedDict
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from collections import OrderedDict, deque
+from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
 
 from jax import monitoring
 
@@ -55,14 +63,43 @@ _logger = logging.getLogger(__name__)
 
 __all__ = ["TrainTelemetry", "forward_flops_per_sample", "peak_flops",
            "loader_collector", "native_warp_collector",
-           "resilience_collector"]
+           "resilience_collector", "STEP_FIELDS", "STEP_PHASES"]
 
 _PREFIX = "dfd_train"
 
-#: step/data-wait histogram bounds: 1 ms .. 60 s (first-step compile tails
-#: land in the top buckets; steady-state steps resolve at ms granularity)
+#: step histogram bounds: 1 ms .. 60 s (first-step compile tails land in the
+#: top buckets; the drain record's ``steps`` rows have every step exactly)
 _STEP_BOUNDS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
                 1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
+
+#: the phases of a step's period, the keys of ``on_step(phases=)``: seconds
+#: blocked on the producer's queue, in device_put + prologue dispatch, on the
+#: batch's host-to-device copy, on its prologue behind the running step (the
+#: four are this step's rise of ``LoaderStats``), inside the step call, in
+#: the drain's block, in ``_save_recovery``
+STEP_PHASES = ("host_wait", "stage", "h2d_block", "prologue_block",
+               "dispatch", "drain", "save")
+#: a row of the drain record's ``steps``: identifiers, the period (hand-over
+#: to hand-over, so the rows tile the epoch), the phases, and ``rest``, the
+#: period less their sum (scheduler, heartbeat, logging, telemetry)
+STEP_FIELDS = ("update", "batch", "period") + STEP_PHASES + ("rest",)
+#: a step is judged against the median of ``period - drain`` (a drain's
+#: block belongs to the steps it waited for, not to the iteration that paid
+#: it) over the _REF_STEPS steps before it, once there are _REF_MIN of them
+_REF_STEPS, _REF_MIN = 16, 8
+#: ... slow above _SLOW x that median, short below _SHORT x it (the one or
+#: two iterations after a drain, when the loop runs ahead into an empty
+#: device queue), normal between
+_SLOW, _SHORT = 1.25, 0.75
+#: what a slow step can be filed under: every phase but the drain, and rest
+_BLAME = tuple(k for k in STEP_FIELDS[3:] if k != "drain")
+_I_PERIOD, _I_DRAIN = (STEP_FIELDS.index(k) for k in ("period", "drain"))
+#: the phases summed into a ``step_<phase>_seconds_total`` counter as the
+#: row is written: those a benchmark metric divides by a counter of the
+#: loop's own steps and no counter on the loop's clock has yet (the loader's
+#: ``input_*`` run up to an iteration ahead; host_wait, stage and the drain
+#: are read from ``input_*`` and ``device_wait_seconds_total``)
+_SUMMED_PHASES = ("h2d_block", "prologue_block", "dispatch")
 
 # bf16 peak per chip by device_kind: the program's one peak table
 _PEAK_FLOPS = {
@@ -107,6 +144,39 @@ _COUNTER_CATALOG = (
      "a microbatch"),
     ("drains_total", "Metric drain boundaries (telemetry records)"),
     ("step_seconds_total", "Wall seconds spent in the train loop"),
+    ("step_h2d_block_seconds_total", "Seconds the loop's steps were blocked "
+     "on their batch's host-to-device copy.  This and the two below are the "
+     "step rows' phases summed as each row is written, so that their rise "
+     "between two snapshots is of the same steps as steps_total's and "
+     "step_seconds_total's (the loader's own input_* counters run up to one "
+     "iteration ahead of the loop's).  The input pipeline starving the chip "
+     "only where the device is idle too: a copy queued behind a running "
+     "step waits here as well"),
+    ("step_prologue_block_seconds_total", "... blocked on the batch's "
+     "prologue, queued behind the running step (the chip is the "
+     "bottleneck)"),
+    ("step_dispatch_seconds_total", "... inside the train step's call (the "
+     "body of the dfd.train.step span): argument handling and the launch, "
+     "not the device's work"),
+    ("recovery_save_seconds_total", "Seconds the loop thread spent in "
+     "in-epoch recovery snapshots (the dfd.train.recovery_save span)"),
+    ("steps_judged_total", "Steps with at least 8 steps before them, each "
+     "judged by its period less its drain block against the median of that "
+     "quantity over the 16 steps before it: normal within 0.75-1.25 x, "
+     "slow above, short below (judged - normal - slow: the iterations "
+     "after a drain, when the loop runs ahead into an empty device queue)"),
+    ("normal_steps_total", "Judged steps within 0.75-1.25 x the median"),
+    ("normal_step_seconds_total", "Their periods less their drain blocks: "
+     "over normal_steps_total, the period of a step that is neither slow "
+     "nor short"),
+    ("slow_steps_total", "Judged steps above 1.25 x the median"),
+    ("slow_step_excess_seconds_total", "The part of each slow step above "
+     "the median: over step_seconds_total, the share of the loop's time "
+     "lost to steps a quarter slower than their neighbours"),
+) + tuple(
+    (f"slow_steps_{k}_total", f"Slow steps filed under {k}: the phase "
+     "whose own excess over its median in the same 16 steps is largest")
+    for k in _BLAME) + (
     ("data_wait_seconds_total", "Seconds the loop blocked on next(loader)"),
     ("device_wait_seconds_total", "Seconds the drain blocked materializing "
      "buffered device scalars (device-bound time)"),
@@ -120,7 +190,6 @@ _COUNTER_CATALOG = (
     ("watchdog_beats_total", "Stall-watchdog heartbeats received"),
     ("watchdog_near_misses_total", "Heartbeats older than 0.5x the "
      "watchdog timeout when they landed"),
-    ("events_total", "Lifecycle events recorded to the JSONL log"),
     ("compiles_total", "Programs the backend built in this process (a load "
      "from the persistent cache counts: a program was built either way)"),
     ("jax_trace_seconds_total", "Seconds jax spent tracing functions to "
@@ -195,6 +264,9 @@ _GAUGE_CATALOG = (
     ("learning_rate", "Current learning rate"),
     ("throughput_imgs_per_s", "Images/sec over the last drain window"),
     ("step_time_ms", "Mean step wall time over the last drain window"),
+    ("step_time_p50_ms", "Median step period of the last drain window "
+     "(exact: from the window's rows)"),
+    ("step_time_max_ms", "Longest step period of the last drain window"),
     ("data_wait_frac", "Fraction of the last window blocked on input"),
     ("device_wait_frac", "Fraction of the last window blocked on the "
      "device backlog"),
@@ -283,7 +355,6 @@ class TrainTelemetry:
         self._g["restart_count"] = float(
             os.environ.get("DFD_RESTART_COUNT", 0) or 0)
         self.h_step = LatencyHistogram(_STEP_BOUNDS)
-        self.h_data_wait = LatencyHistogram(_STEP_BOUNDS)
         self._collectors: List[Callable[[], Dict[str, Dict[str, float]]]] = [
             _compile_collector]
         # drain-window accumulators (single-writer: the train loop).  The
@@ -297,6 +368,13 @@ class TrainTelemetry:
         self._win_samples = 0
         self._win_wall = 0.0
         self._win_data_wait = 0.0
+        # ... and the window's steps kept apart: one STEP_FIELDS row a step
+        # (seconds), cleared at the drain
+        self._rows: List[tuple] = []
+        # the _REF_STEPS steps before this one: period - drain of each, and
+        # its row (read for a slow step only)
+        self._ref_q: "deque[float]" = deque(maxlen=_REF_STEPS)
+        self._ref_rows: "deque[tuple]" = deque(maxlen=_REF_STEPS)
 
     # -- registry ------------------------------------------------------
     def inc(self, name: str, n: float = 1.0) -> None:
@@ -330,25 +408,62 @@ class TrainTelemetry:
 
     # -- hot-loop hooks ------------------------------------------------
     def on_step(self, n_samples: int, data_wait_s: float,
-                step_wall_s: float, tokens: int = 0) -> None:
+                step_wall_s: float, tokens: int = 0, *, update: int = -1,
+                batch: int = -1, period: Optional[float] = None,
+                phases: Optional[Mapping[str, float]] = None) -> None:
         """Once per loop iteration; host floats only.  ``tokens``: rows x
-        positions of a sequence batch (``--seq-len``), 0 for images."""
+        positions of a sequence batch (``--seq-len``), 0 for images;
+        ``update`` / ``batch`` the identifiers the step's spans carry;
+        ``period`` the row's period, hand-over to hand-over (left out:
+        ``step_wall_s``, which ends at the same hand-over and starts after
+        the previous iteration's tail); ``phases`` the seconds of the period
+        spent in each of ``STEP_PHASES``, by name (a phase left out is 0, so
+        a caller with no loop to take apart gets all of the period as
+        ``rest``)."""
+        if period is None:
+            period = step_wall_s
+        phases = phases or {}
+        ph = tuple(phases.get(k, 0.0) for k in STEP_PHASES)
+        row = (update, batch, period) + ph + (period - sum(ph),)
+        self._rows.append(row)
         self._win_steps += 1
         self._win_wall += step_wall_s
         self._win_samples += int(n_samples)
         self._win_data_wait += data_wait_s
         self.h_step.observe(step_wall_s)
-        self.h_data_wait.observe(data_wait_s)
+        # the verdict on this step, from the steps before it
+        q = period - row[_I_DRAIN]
+        ref = statistics.median(self._ref_q) \
+            if len(self._ref_q) >= _REF_MIN else 0.0
+        slow = ref > 0.0 and q > _SLOW * ref
+        if slow:
+            over = [row[i] - statistics.median(r[i] for r in self._ref_rows)
+                    for i in map(STEP_FIELDS.index, _BLAME)]
+            filed = _BLAME[over.index(max(over))]
+        self._ref_q.append(q)
+        self._ref_rows.append(row)
         with self._lock:
-            self._c["steps_total"] += 1
-            self._c["samples_total"] += n_samples
-            self._c["train_tokens_total"] += tokens
-            self._c["attn_tiles_visited_total"] += \
+            c = self._c
+            c["steps_total"] += 1
+            c["samples_total"] += n_samples
+            c["train_tokens_total"] += tokens
+            c["attn_tiles_visited_total"] += \
                 n_samples * self.attn_tiles_per_sample
-            self._c["ssd_chunks_total"] += \
+            c["ssd_chunks_total"] += \
                 n_samples * self.ssd_chunks_per_sample
-            self._c["step_seconds_total"] += step_wall_s
-            self._c["data_wait_seconds_total"] += data_wait_s
+            c["step_seconds_total"] += step_wall_s
+            c["data_wait_seconds_total"] += data_wait_s
+            for k in _SUMMED_PHASES:
+                c[f"step_{k}_seconds_total"] += phases.get(k, 0.0)
+            if ref > 0.0:
+                c["steps_judged_total"] += 1
+            if slow:
+                c["slow_steps_total"] += 1
+                c["slow_step_excess_seconds_total"] += q - ref
+                c[f"slow_steps_{filed}_total"] += 1
+            elif ref > 0.0 and q >= _SHORT * ref:
+                c["normal_steps_total"] += 1
+                c["normal_step_seconds_total"] += q
 
     def on_routing(self, tokens: int, assignments: int, peak: int,
                    peak_filled: int, full_passes: int) -> None:
@@ -374,6 +489,8 @@ class TrainTelemetry:
         if steps == 0:
             return
         data_wait = self._win_data_wait
+        rows = self._rows
+        periods = [r[_I_PERIOD] for r in rows]
         imgs_per_s = samples / wall
         mfu = 0.0
         if self.peak > 0 and self.flops_per_sample > 0:
@@ -390,6 +507,8 @@ class TrainTelemetry:
             g["learning_rate"] = float(lr)
             g["throughput_imgs_per_s"] = round(imgs_per_s, 3)
             g["step_time_ms"] = round(wall / steps * 1e3, 3)
+            g["step_time_p50_ms"] = round(statistics.median(periods) * 1e3, 3)
+            g["step_time_max_ms"] = round(max(periods) * 1e3, 3)
             g["data_wait_frac"] = round(min(data_wait / wall, 1.0), 4)
             g["device_wait_frac"] = round(min(drain_wait_s / wall, 1.0), 4)
             g["host_frac"] = round(
@@ -409,8 +528,13 @@ class TrainTelemetry:
                 device_wait_frac=gauges["device_wait_frac"],
                 host_frac=gauges["host_frac"],
                 loss=float(loss), prec1=float(prec1), lr=float(lr),
-                mfu=gauges["mfu"], counters=counters)
+                mfu=gauges["mfu"], counters=counters,
+                # the window's steps, one STEP_FIELDS row each, in ms
+                step_fields=list(STEP_FIELDS),
+                steps=[[r[0], r[1]] + [round(v * 1e3, 3) for v in r[2:]]
+                       for r in rows])
         # reset the window
+        self._rows = []
         self._win_steps = 0
         self._win_samples = 0
         self._win_wall = 0.0
@@ -418,7 +542,6 @@ class TrainTelemetry:
 
     # -- lifecycle -----------------------------------------------------
     def event(self, name: str, **fields: Any) -> None:
-        self.inc("events_total")
         if name == "rewind":
             self.inc("rewinds_total")
         elif name == "preempted":
@@ -448,8 +571,6 @@ class TrainTelemetry:
         for name, value in snap["gauges"].items():
             doc.gauge(name, self._help.get(name, name), _num(value))
         doc.histogram("step_seconds", "Per-step wall time", self.h_step)
-        doc.histogram("data_wait_seconds",
-                      "Per-step input wait", self.h_data_wait)
         return doc.render()
 
 
@@ -525,6 +646,12 @@ def loader_collector(device_loader, name: str = "train"):
             # wait on the prologue output): the per-drain breakdown's
             # attribution of "where the augment milliseconds live"
             f"input_{name}_stage_block_seconds_total": st.stage_block_s,
+            # ... its two halves: the batch's host-to-device copy
+            # (starvation only where the device is idle too), then its
+            # prologue behind the running step (the chip's backlog)
+            f"input_{name}_h2d_block_seconds_total": st.h2d_block_s,
+            f"input_{name}_prologue_block_seconds_total":
+                st.prologue_block_s,
             # device_put + prologue dispatch of each batch (host time)
             f"input_{name}_stage_seconds_total": st.stage_s,
             # samples x host-chain stages (warp/blur/mixup-blend) elided
@@ -544,9 +671,8 @@ def loader_collector(device_loader, name: str = "train"):
         host = device_loader.loader
         hstats = getattr(host, "stats", None)
         if hstats is not None:           # thread backend producer stats
-            c[f"input_{name}_fetch_seconds_total"] = hstats.fetch_s
-            # fetch = load (the pool's decode+transform) + collate (stack)
-            # + mixup (the uint8 blend), the producer's three phases
+            # load (the pool's decode+transform), collate (stack), mixup
+            # (the uint8 blend): the producer's three phases of a fetch
             c[f"input_{name}_load_seconds_total"] = hstats.load_s
             c[f"input_{name}_collate_seconds_total"] = hstats.collate_s
             c[f"input_{name}_mixup_seconds_total"] = hstats.mixup_s
@@ -555,8 +681,6 @@ def loader_collector(device_loader, name: str = "train"):
             c[f"input_{name}_worker_respawns_total"] = host.respawn_count
             c[f"input_{name}_ring_stall_sweeps_total"] = getattr(
                 host, "stall_sweeps", 0)
-            c[f"input_{name}_ring_collect_wait_seconds_total"] = getattr(
-                host, "collect_wait_s", 0.0)
             workers = [p for p in getattr(host, "_workers", [])
                        if p is not None]
             g[f"input_{name}_workers_alive"] = float(

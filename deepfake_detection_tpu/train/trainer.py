@@ -91,6 +91,18 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
     ``step_num``, around the dispatch), ``dfd.train.drain`` and
     ``dfd.train.recovery_save``.  All its timers read ``time.monotonic``,
     the clock of the loader's counters they are divided by.
+
+    With no session open the same facts reach ``telemetry.on_step`` as one
+    row a step: the period (from the previous iteration's hand-over to
+    this one's, so the periods tile the epoch: what follows the hand-over
+    -- logging, the drain record, a recovery snapshot, the scheduler, the
+    heartbeat -- is in the next row) and the seconds of it spent in each of
+    ``obs/telemetry.py:STEP_PHASES``: this step's rise of the loader's four
+    counters, two clock reads around the step call, the drain's and the
+    snapshot's own deltas.  The step's wall time it hands beside the row
+    (``batch_time_m.val``, what ``step_seconds_total`` sums) is as it was:
+    it ends at the same hand-over and leaves the previous iteration's tail
+    out.
     """
     if cfg.mixup > 0 and hasattr(loader, "mixup_enabled"):
         if cfg.mixup_off_epoch and epoch >= cfg.mixup_off_epoch:
@@ -136,10 +148,18 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
     drain_wait_acc = 0.0
     drain_bad_acc = 0
     profiler = getattr(telemetry, "profiler", None)
+    # the step row: where the last period ended, what the loader's counters
+    # read there, and the drain / snapshot seconds since (see the docstring)
+    t_row = end
+    lstats = getattr(loader, "stats", None)
+    if not hasattr(lstats, "prologue_block_s"):
+        lstats = None       # a loader that keeps no LoaderStats
+    loader_s = _loader_seconds(lstats)
+    drain_row = save_row = 0.0
 
     @functools.partial(annotate_function, name="dfd.train.drain")
     def _drain() -> None:
-        nonlocal nonfinite_total, drain_wait_acc, drain_bad_acc
+        nonlocal nonfinite_total, drain_wait_acc, drain_bad_acc, drain_row
         t_drain = time.monotonic()
         window_bad = 0
         routing = None         # a routed model's counts, summed on the way
@@ -170,10 +190,23 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
         pending.clear()
         # the scalar reads above are the loop's ONLY host syncs, so their
         # block time IS the device-bound share of the window
-        drain_wait_acc += time.monotonic() - t_drain
+        waited = time.monotonic() - t_drain
+        drain_wait_acc += waited
+        drain_row += waited
         drain_bad_acc += window_bad
         if routing is not None and telemetry is not None:
             telemetry.on_routing(*(int(c) for c in routing))
+
+    def _snapshot(batch_idx: int, sync: bool = False) -> None:
+        nonlocal save_row
+        t_save = time.monotonic()
+        _save_recovery(saver, state, meta, epoch, batch_idx, num_updates,
+                       sync=sync)
+        saved = time.monotonic() - t_save
+        save_row += saved
+        if telemetry is not None:
+            telemetry.inc("recovery_snapshots_total")
+            telemetry.inc("recovery_save_seconds_total", saved)
 
     for batch_idx, batch in enumerate(loader, start=start_batch):
         x, y = batch[0], batch[1]
@@ -202,8 +235,10 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
             step_exec = _compile_aligned(train_step, "train_step",
                                          state, x, y, step_rng)
         first_step = False
+        t_call = time.monotonic()
         with StepTraceAnnotation("dfd.train.step", step_num=num_updates):
             state, metrics = (step_exec or train_step)(state, x, y, step_rng)
+        dispatch_s = time.monotonic() - t_call
 
         if profiling and (batch_idx + 1 >= profile_start + profile_n
                           or last_batch):
@@ -220,11 +255,19 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
 
         if last_batch or batch_idx % cfg.log_interval == 0:
             _drain()
-        batch_time_m.update(time.monotonic() - end)
+        now = time.monotonic()
+        batch_time_m.update(now - end)
         if telemetry is not None:
             # host floats the loop already holds — no device access
-            telemetry.on_step(bs, data_time_m.val, batch_time_m.val,
-                              tokens=x.size if seq_len else 0)
+            was, loader_s = loader_s, _loader_seconds(lstats)
+            telemetry.on_step(
+                bs, data_time_m.val, batch_time_m.val,
+                tokens=x.size if seq_len else 0,
+                update=num_updates - 1, batch=batch_idx, period=now - t_row,
+                phases=dict(
+                    {k: v - was[k] for k, v in loader_s.items()},
+                    dispatch=dispatch_s, drain=drain_row, save=save_row))
+            t_row, drain_row, save_row = now, 0.0, 0.0
         if profiler is not None:
             # cheap flag check when idle; manages an active trace window
             profiler.on_step(num_updates, metrics.get("loss"))
@@ -268,10 +311,7 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
 
         if cfg.recovery_interval and (
                 last_batch or (batch_idx + 1) % cfg.recovery_interval == 0):
-            _save_recovery(saver, state, meta, epoch, batch_idx,
-                           num_updates)                     # ref :686-689
-            if telemetry is not None:
-                telemetry.inc("recovery_snapshots_total")
+            _snapshot(batch_idx)                            # ref :686-689
 
         if chaos is not None and saver is not None and \
                 chaos.fires("truncate_ckpt", num_updates):
@@ -326,10 +366,7 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
                 # Orbax write) are lockstep ops — safe exactly because the
                 # agreement above put every host here together.
                 _drain()
-                _save_recovery(saver, state, meta, epoch, batch_idx,
-                               num_updates, sync=True)
-                if telemetry is not None:
-                    telemetry.inc("recovery_snapshots_total")
+                _snapshot(batch_idx, sync=True)
                 raise Preempted(epoch, batch_idx, resilience.stop_signum)
         end = time.monotonic()
 
@@ -337,6 +374,16 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
                                ("prec1", prec1_m.avg),
                                ("learning_rate", lr),
                                ("nonfinite", nonfinite_total)])
+
+
+def _loader_seconds(stats) -> dict:
+    """The loader's four waits so far (``data/loader.py:LoaderStats``), by
+    their ``STEP_PHASES`` names; none for a loader that keeps no stats."""
+    if stats is None:
+        return {}
+    return dict(host_wait=stats.host_wait_s, stage=stats.stage_s,
+                h2d_block=stats.h2d_block_s,
+                prologue_block=stats.prologue_block_s)
 
 
 @functools.partial(annotate_function, name="dfd.train.recovery_save")

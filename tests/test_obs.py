@@ -629,6 +629,196 @@ class TestTrainTelemetryRenderer:
 
 
 # ---------------------------------------------------------------------------
+# The step record: one row a step, judged against its neighbours
+# ---------------------------------------------------------------------------
+
+def _phases(**kw):
+    from deepfake_detection_tpu.obs.telemetry import STEP_PHASES
+    assert set(kw) <= set(STEP_PHASES)
+    return kw
+
+
+class TestStepRecord:
+    """``on_step`` is a pure function of its inputs: no test here sleeps."""
+
+    P = 0.1
+
+    def _telemetry(self, **kw):
+        from deepfake_detection_tpu.obs import TrainTelemetry
+        return TrainTelemetry(**kw)
+
+    def _counters(self, t):
+        return t.snapshot()["counters"]
+
+    def test_one_slow_step_is_counted_priced_and_filed(self):
+        t = self._telemetry()
+        base = _phases(prologue_block=0.07, dispatch=0.02)
+        for i in range(7):
+            t.on_step(1, 0.07, self.P, update=i, batch=i, phases=base)
+        c = self._counters(t)
+        assert c["steps_total"] == 7 and c["steps_judged_total"] == 0
+        for i in range(7, 20):
+            t.on_step(1, 0.07, self.P, update=i, batch=i, phases=base)
+        c = self._counters(t)
+        # the 8th step has 7 before it and judges nothing either
+        assert c["steps_judged_total"] == c["normal_steps_total"] == 12
+        assert c["normal_step_seconds_total"] == pytest.approx(12 * self.P)
+        assert c["slow_steps_total"] == 0
+        t.on_step(1, 0.15, 1.8 * self.P, update=20, batch=20,
+                  phases=_phases(prologue_block=0.07, dispatch=0.02,
+                                 h2d_block=0.8 * self.P))
+        c = self._counters(t)
+        assert c["steps_judged_total"] == 13 and c["slow_steps_total"] == 1
+        assert c["slow_steps_h2d_block_total"] == 1
+        assert sum(v for k, v in c.items() if k.startswith("slow_steps_")
+                   and k != "slow_steps_total") == 1
+        assert c["slow_step_excess_seconds_total"] == \
+            pytest.approx(0.8 * self.P)
+        assert c["normal_steps_total"] == 12
+        assert c["normal_step_seconds_total"] == pytest.approx(12 * self.P)
+        assert c["step_dispatch_seconds_total"] == pytest.approx(21 * 0.02)
+        assert c["step_prologue_block_seconds_total"] == \
+            pytest.approx(21 * 0.07)
+        assert c["step_h2d_block_seconds_total"] == pytest.approx(0.8 * self.P)
+
+    @pytest.mark.parametrize("phase", [
+        "host_wait", "stage", "h2d_block", "prologue_block", "dispatch",
+        "save", "rest"])
+    def test_a_slow_step_is_filed_under_the_phase_that_grew(self, phase):
+        t = self._telemetry()
+        base = dict(host_wait=0.004, stage=0.003, h2d_block=0.001,
+                    prologue_block=0.06, dispatch=0.02, save=0.002)
+        for i in range(16):
+            t.on_step(1, 0.07, self.P, phases=_phases(**base))
+        grown = dict(base)
+        if phase != "rest":         # rest is the period less the phases
+            grown[phase] += 0.05
+        t.on_step(1, 0.07, self.P + 0.05, phases=_phases(**grown))
+        c = self._counters(t)
+        assert c["slow_steps_total"] == 1
+        assert c[f"slow_steps_{phase}_total"] == 1
+
+    def test_a_drain_is_not_slow_and_the_steps_after_it_are_short(self):
+        """An epoch of a device-bound loop: the first iteration pays the
+        drain and nothing else, the next runs ahead into an empty device
+        queue, eight wait a step each, the last waits and drains."""
+        t = self._telemetry()
+        p, d = self.P, 0.25
+        for _ in range(6):
+            t.on_step(1, 0.0, d, phases=_phases(drain=d))
+            t.on_step(1, 0.0, 1e-4, phases=_phases(dispatch=1e-4))
+            for _ in range(8):
+                t.on_step(1, p, p, phases=_phases(prologue_block=p))
+            t.on_step(1, p, p + d, phases=_phases(prologue_block=p, drain=d))
+        c = self._counters(t)
+        assert c["steps_total"] == 66 and c["steps_judged_total"] == 58
+        assert c["slow_steps_total"] == 0
+        assert c["slow_step_excess_seconds_total"] == 0
+        # 9 of an epoch's 11 steps are normal; the first epoch's first 8
+        # steps judge nothing (6 of them would have been normal)
+        assert c["normal_steps_total"] == 6 * 9 - 6
+        assert c["normal_step_seconds_total"] / c["normal_steps_total"] == \
+            pytest.approx(p)
+
+    def test_the_drain_record_carries_one_row_a_step(self, tmp_path):
+        from deepfake_detection_tpu.obs import EventLog, read_records
+        from deepfake_detection_tpu.obs.telemetry import STEP_FIELDS
+        path = str(tmp_path / "telemetry.jsonl")
+        t = self._telemetry(event_log=EventLog(path))
+        drain = dict(epoch=0, loss=1.0, prec1=50.0, lr=0.1)
+        for i, wall in enumerate([0.5, 0.1, 0.3]):
+            t.on_step(2, 0.01, wall, update=40 + i, batch=i,
+                      phases=_phases(host_wait=0.01, dispatch=0.02,
+                                     drain=0.05 if i == 2 else 0.0))
+        t.on_drain(batch_idx=2, num_updates=43, drain_wait_s=0.05, **drain)
+        t.on_step(2, 0.01, 0.2, update=43, batch=3)
+        t.on_drain(batch_idx=3, num_updates=44, drain_wait_s=0.0, **drain)
+        t.close()
+        first, second = [r for r in read_records(path)
+                         if r["type"] == "metrics"]
+        assert first["step_fields"] == list(STEP_FIELDS)
+        assert len(first["steps"]) == 3 and len(second["steps"]) == 1
+        rows = [dict(zip(STEP_FIELDS, r)) for r in first["steps"]]
+        assert [r["update"] for r in rows] == [40, 41, 42]
+        assert [r["batch"] for r in rows] == [0, 1, 2]
+        assert [r["period"] for r in rows] == [500.0, 100.0, 300.0]   # ms
+        assert rows[2]["drain"] == 50.0 and rows[0]["drain"] == 0.0
+        assert rows[2]["rest"] == pytest.approx(300.0 - 10 - 20 - 50)
+        # a caller that hands no phases: all of the period is rest
+        assert dict(zip(STEP_FIELDS, second["steps"][0]))["rest"] == 200.0
+        # the window's exact p50 / max, from the rows
+        g = t.snapshot()["gauges"]
+        assert g["step_time_p50_ms"] == g["step_time_max_ms"] == 200.0
+        assert first["counters"]["steps_total"] == 3
+
+    def test_window_gauges_are_exact(self):
+        t = self._telemetry()
+        for wall in (0.102, 0.101, 0.193, 0.103, 0.102):
+            t.on_step(3, 0.0, wall)
+        t.on_drain(epoch=0, batch_idx=4, num_updates=5, loss=1.0,
+                   prec1=50.0, lr=0.1, drain_wait_s=0.0)
+        g = t.snapshot()["gauges"]
+        assert g["step_time_p50_ms"] == 102.0
+        assert g["step_time_max_ms"] == 193.0
+        assert g["step_time_ms"] == pytest.approx(120.2)
+
+    def test_the_step_wall_and_the_rows_period_are_kept_apart(self,
+                                                              tmp_path):
+        """``step_seconds_total`` and the window's mean stay on the step's
+        wall time (the accepted shares' denominator, as before the rows);
+        the row, the verdicts and the exact gauges take the period, which
+        also holds the previous iteration's tail."""
+        from deepfake_detection_tpu.obs import EventLog, read_records
+        from deepfake_detection_tpu.obs.telemetry import STEP_FIELDS
+        path = str(tmp_path / "telemetry.jsonl")
+        t = self._telemetry(event_log=EventLog(path))
+        for i in range(12):
+            t.on_step(2, 0.0, self.P, update=i, batch=i, period=1.2 * self.P,
+                      phases=_phases(dispatch=0.01))
+        t.on_drain(epoch=0, batch_idx=11, num_updates=12, loss=1.0,
+                   prec1=50.0, lr=0.1, drain_wait_s=0.0)
+        t.close()
+        rec = [r for r in read_records(path) if r["type"] == "metrics"][0]
+        c = rec["counters"]
+        assert c["step_seconds_total"] == pytest.approx(12 * self.P)
+        assert rec["step_ms"] == pytest.approx(100.0)
+        assert [dict(zip(STEP_FIELDS, r))["period"] for r in rec["steps"]] \
+            == [120.0] * 12
+        assert c["steps_judged_total"] == c["normal_steps_total"] == 4
+        assert c["normal_step_seconds_total"] == \
+            pytest.approx(4 * 1.2 * self.P)
+        assert t.snapshot()["gauges"]["step_time_p50_ms"] == 120.0
+
+    def test_only_the_phases_no_other_counter_has_are_summed(self):
+        """host_wait, stage and the drain have counters already (``input_*``
+        and ``device_wait_seconds_total``); the rows add none beside
+        them."""
+        from deepfake_detection_tpu.obs.telemetry import STEP_PHASES
+        t = self._telemetry()
+        t.on_step(1, 0.0, self.P, phases={k: 0.01 for k in STEP_PHASES})
+        c = self._counters(t)
+        assert sorted(k for k in c if k.startswith("step_")) == [
+            "step_dispatch_seconds_total", "step_h2d_block_seconds_total",
+            "step_prologue_block_seconds_total", "step_seconds_total"]
+        assert c["step_h2d_block_seconds_total"] == 0.01
+        assert "dfd_train_step_drain_seconds_total" not in \
+            t.render_prometheus()
+
+    def test_a_window_of_any_length_keeps_every_row(self):
+        t = self._telemetry()
+        for i in range(700):
+            t.on_step(1, 0.0, self.P, update=i)
+        assert len(t._rows) == 700
+        t.on_drain(epoch=0, batch_idx=699, num_updates=700, loss=1.0,
+                   prec1=50.0, lr=0.1, drain_wait_s=0.0)
+        assert t._rows == []
+        t.on_step(1, 0.0, 0.3)
+        t.on_drain(epoch=0, batch_idx=700, num_updates=701, loss=1.0,
+                   prec1=50.0, lr=0.1, drain_wait_s=0.0)
+        assert t.snapshot()["gauges"]["step_time_max_ms"] == 300.0
+
+
+# ---------------------------------------------------------------------------
 # JSONL event log
 # ---------------------------------------------------------------------------
 
@@ -794,10 +984,19 @@ class TestOverheadGuard:
         seen_types = []
 
         class Checked(TrainTelemetry):
-            def on_step(self, n, data_wait_s, step_wall_s, tokens=0):
+            def on_step(self, n, data_wait_s, step_wall_s, tokens=0, *,
+                        update=-1, batch=-1, period=None, phases=None):
                 seen_types.extend([type(n), type(data_wait_s),
-                                   type(step_wall_s), type(tokens)])
-                super().on_step(n, data_wait_s, step_wall_s, tokens)
+                                   type(step_wall_s), type(tokens),
+                                   type(update), type(batch), type(period)])
+                seen_types.extend(type(v) for v in phases.values())
+                super().on_step(n, data_wait_s, step_wall_s, tokens,
+                                update=update, batch=batch, period=period,
+                                phases=phases)
+
+            def on_drain(self, **kw):
+                seen_types.extend(type(v) for v in kw.values())
+                super().on_drain(**kw)
 
         t = Checked()
         calls["n"] = 0
@@ -813,16 +1012,21 @@ class TestOverheadGuard:
     def test_spans_and_phase_counters_add_no_sync(self, devices, monkeypatch,
                                                   tmp_path):
         """The dfd.* spans cost no device sync, with a profiler session
-        open or not; the DeviceLoader still blocks exactly once per staged
-        batch after the first (its slab-recycle wait, nothing new); and
-        every per-phase / compile counter is a host number."""
+        open or not; the DeviceLoader's one wait per staged batch after the
+        first (its slab-recycle wait) is now awaited in two places of the
+        same chain, the batch's copy and then the prologue that reads it
+        (a pair, not a new sync); and every per-phase / compile counter is
+        a host number."""
         from deepfake_detection_tpu.obs import (TrainTelemetry,
                                                 loader_collector, start_trace)
         calls = {"n": 0}
         real = jax.block_until_ready
 
+        waited = []
+
         def counting(x):
             calls["n"] += 1
+            waited.append(x)
             return real(x)
 
         monkeypatch.setattr(jax, "block_until_ready", counting)
@@ -839,10 +1043,18 @@ class TestOverheadGuard:
             "an open profiler session changed the loop's sync count"
 
         loader = _mixup_loader()
-        calls["n"] = 0
-        n = sum(1 for _ in loader)
-        assert calls["n"] == n - 1, \
+        del waited[:]
+        yielded = [b[0] for b in loader]
+        n = len(yielded)
+        assert len(waited) == 2 * (n - 1), \
             "the loader's spans/counters added a block_until_ready"
+        # each pair: the uint8 wire batch, then the prologue's output that
+        # depends on it — the array the parent's one wait was on (batch
+        # k + 1's, awaited before batch k + 2 is pulled)
+        for k in range(n - 1):
+            put, x = waited[2 * k], waited[2 * k + 1]
+            assert put.dtype == jnp.uint8 and put.shape[0] == x.shape[0]
+            assert x is yielded[k + 1]
         t = TrainTelemetry()
         t.register_collector(loader_collector(loader))
         for k, v in t.snapshot()["counters"].items():
@@ -1052,10 +1264,17 @@ class TestObsReport:
                                 "steps_total": u,
                                 "recovery_snapshots_total": 1,
                                 "input_train_batches_total": u,
-                                "input_train_fetch_seconds_total": 9.0,
+                                "slow_steps_total": 1,
+                                "slow_steps_h2d_block_total": 1,
+                                "steps_judged_total": 2,
+                                "slow_step_excess_seconds_total": 0.08,
                                 "input_train_load_seconds_total": 1.5,
                                 "input_train_collate_seconds_total": 2.5,
-                                "input_train_mixup_seconds_total": 5.0})
+                                "input_train_mixup_seconds_total": 5.0},
+                            step_fields=["update", "batch", "period",
+                                         "h2d_block", "rest"],
+                            steps=[[u - 1, u - 1, 100.0 * u, 80.0 * u,
+                                    20.0 * u]])
             log.event("rewind", reason="3 consecutive bad steps")
             log.event("epoch_end", epoch=0, train={"loss": 0.33})
         out = subprocess.run(
@@ -1069,6 +1288,13 @@ class TestObsReport:
         assert "recovery_snapshots_total = 1" in out.stdout
         assert "host fetch 9.0s = load 1.5s + collate 2.5s + mixup 5.0s" \
             in out.stdout
+        # the steps table: p50 / p95 / max of the period and of each phase
+        # over the records' rows, and the slow steps by phase
+        assert "steps: 3 rows (ms)" in out.stdout
+        assert "| period | 200.000 | 300.000 | 300.000 |" in out.stdout
+        assert "| h2d_block | 160.000 | 240.000 | 240.000 |" in out.stdout
+        assert "slow steps: 1 of 2 judged, 0.080s over their neighbours' " \
+            "median (h2d_block 1)" in out.stdout
         # the mesh line (ISSUE 12 satellite): topology from run_start
         assert "mesh: batch=8 × model=1 (8 devices)" in out.stdout
         tail = subprocess.run(
@@ -1261,7 +1487,7 @@ class TestLoaderStats:
         assert st.host_wait_s >= 0.0
         out = loader_collector(loader)()
         assert out["counters"]["input_train_batches_total"] == n
-        assert out["counters"]["input_train_fetch_seconds_total"] > 0
+        assert out["counters"]["input_train_load_seconds_total"] > 0
         loader.close()
 
 
@@ -1298,6 +1524,24 @@ def _mixup_loader(wrap=False):
     return loader
 
 
+def _mixup_state_and_step():
+    """A tiny model's state and its train step for the mixup loader's soft
+    targets."""
+    from deepfake_detection_tpu.losses import soft_target_cross_entropy
+    from deepfake_detection_tpu.models import create_model, init_model
+    from deepfake_detection_tpu.optim import create_optimizer
+    from deepfake_detection_tpu.train import (create_train_state,
+                                              make_train_step)
+    model = create_model("mnasnet_small", num_classes=2, in_chans=3)
+    variables = init_model(model, jax.random.PRNGKey(0), (2, 32, 32, 3),
+                           training=True)
+    tx = create_optimizer(SimpleNamespace(
+        opt="sgd", opt_eps=1e-8, momentum=0.9, weight_decay=0.0,
+        lr=1e-3), inject=True)
+    return create_train_state(variables, tx), make_train_step(
+        model, tx, soft_target_cross_entropy, mesh=None, bn_mode="global")
+
+
 def _trace_spans(trace_dir):
     """[(name, {stat: value})] of every dfd.* span in the trace's host
     plane."""
@@ -1321,22 +1565,9 @@ class TestSpans:
         train_one_epoch: every dfd.input.* / dfd.train.* span is in the
         profiler's own file with its identifier, and the producer's and the
         consumer's spans of one batch carry the same ``batch``."""
-        from deepfake_detection_tpu.losses import soft_target_cross_entropy
-        from deepfake_detection_tpu.models import create_model, init_model
         from deepfake_detection_tpu.obs import start_trace
-        from deepfake_detection_tpu.optim import create_optimizer
-        from deepfake_detection_tpu.train import (create_train_state,
-                                                  make_train_step,
-                                                  train_one_epoch)
-        model = create_model("mnasnet_small", num_classes=2, in_chans=3)
-        variables = init_model(model, jax.random.PRNGKey(0), (2, 32, 32, 3),
-                               training=True)
-        tx = create_optimizer(SimpleNamespace(
-            opt="sgd", opt_eps=1e-8, momentum=0.9, weight_decay=0.0,
-            lr=1e-3), inject=True)
-        state = create_train_state(variables, tx)
-        step = make_train_step(model, tx, soft_target_cross_entropy,
-                               mesh=None, bn_mode="global")
+        from deepfake_detection_tpu.train import train_one_epoch
+        state, step = _mixup_state_and_step()
         loader = _mixup_loader()
         cfg = _loop_cfg(recovery_interval=2)
         state, _ = train_one_epoch(0, step, state, loader, cfg,
@@ -1355,7 +1586,7 @@ class TestSpans:
             by_name.setdefault(name, []).append(stats)
         n = len(loader)
         for name in ("load", "collate", "mixup", "put_wait", "host_wait",
-                     "stage", "stage_block"):
+                     "stage", "stage_block", "h2d_block", "prologue_block"):
             got = by_name.get(f"dfd.input.{name}")
             assert got, f"no dfd.input.{name} span in the trace"
             assert all("batch" in st for st in got), (name, got)
@@ -1369,27 +1600,104 @@ class TestSpans:
         produced = {st["batch"] for st in by_name["dfd.input.load"]}
         staged = {st["batch"] for st in by_name["dfd.input.stage"]}
         assert produced == staged == set(range(n))
+        # the split waits carry the batch their outer span carries
+        outer = sorted(st["batch"] for st in by_name["dfd.input.stage_block"])
+        assert outer == list(range(1, n))
+        for name in ("h2d_block", "prologue_block"):
+            assert sorted(st["batch"] for st in
+                          by_name[f"dfd.input.{name}"]) == outer
 
     @pytest.mark.parametrize("wrap", [False, True],
                              ids=["plain", "proxied"])
-    def test_phase_counters_sum_to_fetch(self, devices, wrap):
-        """fetch = load + collate + mixup, and loader_collector exposes the
-        four new counters — also through an attribute-forwarding proxy
-        around the host loader (the benchmark's HostTap)."""
+    def test_stage_block_is_the_sum_of_its_two_waits(self, devices, wrap):
+        """stage_block = h2d_block + prologue_block to float rounding, and
+        loader_collector exposes both halves beside the producer's three
+        phase counters — also through an attribute-forwarding proxy around
+        the host loader (the benchmark's HostTap)."""
         from deepfake_detection_tpu.obs import loader_collector
         loader = _mixup_loader(wrap)
         n = sum(1 for _ in loader)
         st = loader.loader.stats
         assert st.batches == n
         assert st.load_s > 0 and st.collate_s > 0 and st.mixup_s > 0
-        assert st.load_s + st.collate_s + st.mixup_s == \
-            pytest.approx(st.fetch_s, rel=1e-9)
+        dst = loader.stats
+        assert dst.h2d_block_s >= 0 and dst.prologue_block_s > 0
+        assert dst.h2d_block_s + dst.prologue_block_s == \
+            pytest.approx(dst.stage_block_s, rel=1e-9)
         c = loader_collector(loader)()["counters"]
         assert c["input_train_load_seconds_total"] == st.load_s
         assert c["input_train_collate_seconds_total"] == st.collate_s
         assert c["input_train_mixup_seconds_total"] == st.mixup_s
-        assert c["input_train_stage_seconds_total"] == loader.stats.stage_s > 0
-        assert c["input_train_fetch_seconds_total"] == st.fetch_s
+        assert c["input_train_stage_seconds_total"] == dst.stage_s > 0
+        assert c["input_train_h2d_block_seconds_total"] == dst.h2d_block_s
+        assert c["input_train_prologue_block_seconds_total"] == \
+            dst.prologue_block_s
+        assert c["input_train_stage_block_seconds_total"] == \
+            dst.stage_block_s
+        assert "input_train_fetch_seconds_total" not in c
+        loader.close()
+
+    def test_the_loop_thread_hands_a_row_a_step(self, devices):
+        """train_one_epoch over the real loader: each step's row carries the
+        loader's own rises (through ``loader.stats``), the periods tile
+        the loop's time and no phase is negative."""
+        from deepfake_detection_tpu.obs import TrainTelemetry
+        from deepfake_detection_tpu.obs.telemetry import STEP_PHASES
+        from deepfake_detection_tpu.train import train_one_epoch
+        state, step = _mixup_state_and_step()
+        loader = _mixup_loader()
+        rows = []
+
+        class Keeping(TrainTelemetry):
+            def on_step(self, n, data_wait_s, step_wall_s, tokens=0, *,
+                        update=-1, batch=-1, period=None, phases=None):
+                assert set(phases) == set(STEP_PHASES)
+                rows.append(dict(phases, update=update, batch=batch,
+                                 period=period, wall=step_wall_s))
+                super().on_step(n, data_wait_s, step_wall_s, tokens,
+                                update=update, batch=batch, period=period,
+                                phases=phases)
+
+        t = Keeping()
+        t0 = time.monotonic()
+        for e in range(2):
+            loader.set_epoch(e)
+            state, _ = train_one_epoch(e, step, state, loader,
+                                       _loop_cfg(log_interval=3),
+                                       jax.random.PRNGKey(1), telemetry=t)
+        wall = time.monotonic() - t0
+        n = len(loader)
+        assert [r["update"] for r in rows] == list(range(2 * n))
+        assert [r["batch"] for r in rows] == list(range(n)) * 2
+        assert all(v >= 0 for r in rows for v in r.values())
+        st = loader.stats
+        for name, total in (("host_wait", st.host_wait_s),
+                            ("stage", st.stage_s),
+                            ("h2d_block", st.h2d_block_s),
+                            ("prologue_block", st.prologue_block_s)):
+            assert sum(r[name] for r in rows) == pytest.approx(total)
+        assert sum(r["drain"] for r in rows) > 0
+        assert all(r["dispatch"] > 0 for r in rows)
+        # three phases are summed as their rows are written: over the same
+        # steps as step_seconds_total, and at an epoch's end what the
+        # loader counted; the drain's seconds are device_wait_seconds_total
+        c = t.snapshot()["counters"]
+        for name in ("h2d_block", "prologue_block", "dispatch"):
+            assert c[f"step_{name}_seconds_total"] == pytest.approx(
+                sum(r[name] for r in rows))
+        assert c["step_prologue_block_seconds_total"] == pytest.approx(
+            st.prologue_block_s)
+        assert sum(r["drain"] for r in rows) == pytest.approx(
+            c["device_wait_seconds_total"])
+        # the phases fit inside their period, the step's wall time (what
+        # step_seconds_total sums, as before the rows) ends where the
+        # period does and starts later, and the periods tile the epochs
+        assert all(sum(r[k] for k in STEP_PHASES) <= r["period"] * 1.001
+                   for r in rows)
+        assert all(r["wall"] <= r["period"] for r in rows)
+        assert c["step_seconds_total"] == pytest.approx(
+            sum(r["wall"] for r in rows))
+        assert sum(r["period"] for r in rows) <= wall
         loader.close()
 
     def test_compiles_total_counts_each_program_once(self, devices):

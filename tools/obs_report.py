@@ -82,6 +82,41 @@ def _epoch_rows(metrics):
     return rows
 
 
+def _quantile(sorted_vals, q: float):
+    """Nearest-rank quantile of an ascending list."""
+    return sorted_vals[min(int(q * len(sorted_vals)), len(sorted_vals) - 1)]
+
+
+def _step_lines(metrics) -> list:
+    """The per-step table: p50 / p95 / max of the period and of each phase
+    over every ``steps`` row of the run (ms), then the slow steps by the
+    phase they were filed under (the latest record's counters)."""
+    fields = next((m["step_fields"] for m in metrics
+                   if m.get("step_fields")), None)
+    rows = [r for m in metrics for r in m.get("steps") or ()]
+    if not fields or not rows:
+        return []
+    out = [f"\nsteps: {len(rows)} rows (ms)",
+           "| field | p50 | p95 | max |", "|---|---|---|---|"]
+    for i, name in enumerate(fields):
+        if name in ("update", "batch"):
+            continue
+        vals = sorted(r[i] for r in rows)
+        out.append(f"| {name} | {_fmt(_quantile(vals, 0.5), 3)} "
+                   f"| {_fmt(_quantile(vals, 0.95), 3)} "
+                   f"| {_fmt(vals[-1], 3)} |")
+    last = metrics[-1].get("counters", {})
+    slow = int(last.get("slow_steps_total", 0))
+    by = ", ".join(f"{k[len('slow_steps_'):-len('_total')]} {int(v)}"
+                   for k, v in sorted(last.items())
+                   if k.startswith("slow_steps_") and k != "slow_steps_total"
+                   and v)
+    out.append(f"slow steps: {slow} of {int(last.get('steps_judged_total', 0))}"
+               f" judged, {last.get('slow_step_excess_seconds_total', 0.0):.3f}"
+               f"s over their neighbours' median" + (f" ({by})" if by else ""))
+    return out
+
+
 def summarize_backfill(path, metrics, events) -> None:
     """The backfill shape of the report: per-shard progress/throughput
     (runners/backfill.py emits one metrics record per committed or
@@ -190,19 +225,22 @@ def summarize(paths: list) -> None:
         if "input_train_batches_total" in last:
             hw = last.get("input_train_host_wait_seconds_total", 0.0)
             sb = last.get("input_train_stage_block_seconds_total", 0.0)
-            fetch = last.get("input_train_fetch_seconds_total")
             aug_path = "device" if elided else "host"
             line = (f"\ninput augment path: {aug_path} "
                     f"(host stages elided: {int(elided)}; "
                     f"host-wait {hw:.1f}s, prologue stage-block {sb:.1f}s")
-            if fetch is not None:
-                line += f", host fetch {fetch:.1f}s"
-                parts = [(k, last.get(f"input_train_{k}_seconds_total"))
-                         for k in ("load", "collate", "mixup")]
-                if all(v is not None for _, v in parts):
-                    line += " = " + " + ".join(f"{k} {v:.1f}s"
-                                               for k, v in parts)
+            if "input_train_h2d_block_seconds_total" in last:
+                line += " = copy {:.1f}s + prologue {:.1f}s".format(
+                    last["input_train_h2d_block_seconds_total"],
+                    last.get("input_train_prologue_block_seconds_total", 0.0))
+            parts = [(k, last.get(f"input_train_{k}_seconds_total"))
+                     for k in ("load", "collate", "mixup")]
+            if all(v is not None for _, v in parts):
+                line += (f", host fetch {sum(v for _, v in parts):.1f}s = "
+                         + " + ".join(f"{k} {v:.1f}s" for k, v in parts))
             print(line + ")")
+        for line in _step_lines(metrics):
+            print(line)
     resil = [e for e in events if e.get("event") in
              ("rewind", "preempted", "resume")]
     if resil:
