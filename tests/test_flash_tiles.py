@@ -214,17 +214,19 @@ def _kernel_operands(l, dtype=jnp.float32, d=128):
     return q, k, v, do
 
 
+@pytest.mark.parametrize("bk", [128, 256])
 @pytest.mark.parametrize("kernel", ["fwd", "dkv", "dq"])
 @pytest.mark.parametrize("q_off,kv_off", [(0, 0), (256, 0), (0, 128)],
                          ids=["aligned", "q-after-kv", "q-before-kv"])
 def test_kernels_under_clamped_maps_give_the_plain_maps_bits(
-        kernel, q_off, kv_off):
+        kernel, q_off, kv_off, bk):
     """Python-int offsets clamp the causal maps, the same offsets as traced
     scalars (ring attention's) leave them plain: every visited cell reads
-    the same block either way, so every bit is the same."""
+    the same block either way, so every bit is the same (at key tiles of
+    256 the forward's row statistics are repeated across the tile)."""
     l, blk = 512, 128
     q, k, v, do = _kernel_operands(l)
-    args = (128 ** -0.5, blk, blk, True, l - 20, True)
+    args = (128 ** -0.5, blk, bk, True, l - 20, True)
 
     def run(q_off, kv_off):
         out, lse = FA._fwd(q, k, v, *args, q_off, kv_off)
@@ -289,8 +291,13 @@ def test_only_a_power_of_two_scale_is_folded(scale, folds, monkeypatch):
 
 # ---- bit for bit the parent's kernels, on shapes of several tiles a side ----
 
-# name: (L, q heads, k heads, v heads, d, dv, dtype, flash_attention kwargs)
-PARENT_CASES = {
+# name: (L, q heads, k heads, v heads, d, dv, dtype, flash_attention kwargs);
+# the fixture holds PR 26's kernels' bits (commit 3bb8954) of the first
+# group and PR 34's (765baa1) of the second: key tiles of 256 and 512 and
+# values of 256 lanes, where the forward's lane-replicated row statistics
+# are repeated across the tile (two, four copies) and across the values,
+# and key tiles of 64, where they are cut
+_PR26_CASES = {
     "causal_f32": (530, 1, 1, 1, 16, 16, "float32", dict(causal=True)),
     "plain_f32": (530, 1, 1, 1, 16, 16, "float32", dict()),
     "causal_bq256_bk128_f32": (600, 1, 1, 1, 16, 16, "float32", dict(
@@ -304,12 +311,33 @@ PARENT_CASES = {
     "causal_bf16_scale_not_pow2": (530, 2, 1, 1, 24, 24, "bfloat16", dict(
         causal=True, dot_dtype=jnp.bfloat16)),
 }
+_PR34_CASES = {
+    "causal_bk256_f32": (600, 1, 1, 1, 16, 16, "float32", dict(
+        causal=True, block_k=256)),
+    "causal_bk512_f32": (1100, 1, 1, 1, 16, 16, "float32", dict(
+        causal=True, block_k=512)),
+    "window_bk256_f32": (1100, 1, 1, 1, 16, 16, "float32", dict(
+        causal=True, window=300, block_k=256)),
+    "window_bk512_f32": (1100, 1, 1, 1, 16, 16, "float32", dict(
+        causal=True, window=300, block_k=512)),
+    "causal_bk256_bf16_grouped_dv64": (600, 4, 2, 1, 16, 64, "bfloat16",
+                                       dict(causal=True, block_k=256,
+                                            dot_dtype=jnp.bfloat16)),
+    "causal_bk512_bf16_grouped_dv128": (1100, 2, 1, 1, 16, 128, "bfloat16",
+                                        dict(causal=True, block_k=512,
+                                             dot_dtype=jnp.bfloat16)),
+    "causal_bf16_dv256": (530, 1, 1, 1, 16, 256, "bfloat16", dict(
+        causal=True, dot_dtype=jnp.bfloat16)),
+    "causal_bk64_f32": (530, 1, 1, 1, 16, 16, "float32", dict(
+        causal=True, block_k=64)),
+}
+PARENT_CASES = {**_PR26_CASES, **_PR34_CASES}
 
 
 def _run_case(name):
     l, h, hk, hv, d, dv, dtype, kw = PARENT_CASES[name]
-    ks = jax.random.split(jax.random.PRNGKey(
-        27 + sorted(PARENT_CASES).index(name)), 4)
+    seeds = sorted(_PR26_CASES) + sorted(_PR34_CASES)
+    ks = jax.random.split(jax.random.PRNGKey(27 + seeds.index(name)), 4)
     q = jax.random.normal(ks[0], (1, l, h, d), dtype)
     k = jax.random.normal(ks[1], (1, l, hk, d), dtype)
     v = jax.random.normal(ks[2], (1, l, hv, dv), dtype)
@@ -463,10 +491,15 @@ def test_the_sequence_cells_models_count_their_layers_by_the_predicate(
 
 if __name__ == "__main__":
     # PYTHONPATH=<a checkout of the PARENT commit> python tests/test_flash_tiles.py
-    assert not hasattr(FA, "tile_visible"), \
-        f"{FA.__file__} is not the parent's: it already has the classes"
-    arrays = {}
+    # writes the bits of the cases the fixture lacks, from that checkout's
+    # kernels, beside the ones it holds
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert not os.path.abspath(FA.__file__).startswith(here + os.sep), \
+        f"{FA.__file__} is this tree's: the bits must be another commit's"
+    arrays = dict(np.load(FIXTURE)) if os.path.exists(FIXTURE) else {}
     for case in sorted(PARENT_CASES):
-        arrays.update(_run_case(case))
+        if f"{case}_out" not in arrays:
+            arrays.update(_run_case(case))
+            print("wrote", case)
     np.savez(FIXTURE, **arrays)
     print(FIXTURE, os.path.getsize(FIXTURE), "bytes from", FA.__file__)

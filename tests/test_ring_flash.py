@@ -11,10 +11,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh
+from jax import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
+from deepfake_detection_tpu.ops.flash_attention import flash_attention
 from deepfake_detection_tpu.parallel.ring_attention import (
-    full_attention, ring_self_attention)
+    full_attention, ring_flash_attention, ring_self_attention)
 
 
 def _qkv(b, l, h, d, seed=0):
@@ -69,3 +71,31 @@ def test_ring_flash_agrees_with_xla_ring(sp_mesh):
         q, k, v, sp_mesh, seq_axis="sp", impl="ring_flash"))(q, k, v)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
                                atol=2e-5, rtol=2e-5)
+
+
+def _ring_flash(mesh, **kw):
+    """ring_flash_attention over ``mesh``'s "sp" axis with the block sizes
+    ``kw`` (the shard_map wrapper passes none)."""
+    spec = P(None, "sp", None, None)
+    return jax.jit(shard_map(
+        functools.partial(ring_flash_attention, axis_name="sp", **kw),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False))
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_ring_flash_at_key_tiles_of_256(devices, shards):
+    """The ring path's forward kernel takes traced offsets; at key tiles of
+    256 its lane-replicated row statistics are repeated across the tile.
+    Over four shards it matches dense; on one, where the offsets are 0 and
+    the merge of one block is exact, it is the standalone op's bits (its
+    ``lse`` against the standalone call's: tests/test_flash_tiles.py,
+    traced against Python-int offsets)."""
+    mesh = Mesh(np.asarray(devices[:shards]), ("sp",))
+    q, k, v = _qkv(1, shards * 300, 2, 32, seed=3)
+    out = _ring_flash(mesh, causal=True, block_k=256)(q, k, v)
+    if shards == 1:
+        want = flash_attention(q, k, v, causal=True, block_k=256)
+        assert np.array_equal(np.asarray(out), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(
+        full_attention(q, k, v, causal=True)), atol=2e-5, rtol=2e-5)

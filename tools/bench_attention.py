@@ -26,6 +26,10 @@ PRs 27, 29 and 31 all found the cell to disagree with a kernel timed alone.
 own train step, as the benchmark's driver builds it, ten steps a form by the
 host's clock; ``split`` rebinds the op's predicate so that every layer takes
 the dK/dV and dQ pair.
+``--fwd [shape ...]`` (TPU only) times the forward launch alone at the same
+shapes, chained the same way (the next launch's ``q`` is this one's plus
+1e-30 of an ``out`` element), and prints a sha256 of one launch's ``out``
+and ``lse``: run it on two commits and compare the digests for their bits.
 """
 
 from __future__ import annotations
@@ -53,9 +57,102 @@ BWD_SHAPES = {
 CHAIN = 8
 
 
+def _chain_ms(step, x, *rest) -> float:
+    """Device ms of one launch of ``step(x, *rest)`` from ``CHAIN`` launches
+    in one program, each fed by the one before: the next ``x`` is this one's
+    plus 1e-30 of the first output's first element, the others' first
+    elements are kept."""
+    import statistics
+
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def run(x, *rest):
+        keep = jnp.float32(0)
+        for _ in range(CHAIN):
+            first, *others = step(x, *rest)
+            x = x + (first[0, 0, 0].astype(jnp.float32) * 1e-30
+                     ).astype(x.dtype)
+            for o in others:
+                keep = keep + o[0, 0, 0]
+        return x, keep
+    jax.block_until_ready(run(x, *rest))
+    times = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        jax.block_until_ready(run(x, *rest))
+        times.append(time.perf_counter() - t0)
+    return 1e3 * statistics.median(times) / CHAIN
+
+
+def _operands(fa, name):
+    """(q, k, v, do) of ``BWD_SHAPES[name]`` in the kernels' padded layout,
+    bf16, the power-of-two scale folded into q as the op folds it."""
+    import jax
+    import jax.numpy as jnp
+
+    b, l, h, hk, hv, d, dv, block, window = BWD_SHAPES[name]
+    _, _, lpq, lpk = fa._blocks(l, block, block)
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+
+    def operand(key, heads, width, lp):
+        x = jax.random.normal(key, (b * heads, l, width), jnp.bfloat16)
+        return jnp.pad(x, ((0, 0), (0, lp - l),
+                           (0, fa._round_up(width, 128) - width)))
+    q = operand(keys[0], h, d, lpq) * d ** -0.5
+    k, v = operand(keys[1], hk, d, lpk), operand(keys[2], hv, dv, lpk)
+    return q, k, v, operand(keys[3], h, dv, lpq)
+
+
+def _static(fa, name):
+    """(the kernels' positional statics, their keywords) at a shape."""
+    import jax.numpy as jnp
+
+    _, l, *_, block, window = BWD_SHAPES[name]
+    bq, bk, _, _ = fa._blocks(l, block, block)
+    return (1.0, bq, bk, True, l, False), dict(window=window,
+                                               dot_dtype=jnp.bfloat16)
+
+
+def fwd_probe(names) -> None:
+    import hashlib
+    import importlib
+
+    import jax
+    import numpy as np
+
+    fa = importlib.import_module("deepfake_detection_tpu.ops.flash_attention")
+    assert jax.default_backend() == "tpu", jax.default_backend()
+
+    def probe(name):
+        b, l, h, hk, hv, d, dv, block, window = BWD_SHAPES[name]
+        q, k, v, _ = _operands(fa, name)
+        static, kw = _static(fa, name)
+
+        def fwd(q, k, v):
+            return fa._fwd(q, k, v, *static, **kw)
+        out, lse = jax.jit(fwd)(q, k, v)
+        visited = b * h * fa.tile_census(l, block, block, True,
+                                         window)["fwd"]["visited"]
+        row = {"shape": name, "rows": b, "seq_len": l, "heads": [h, hk, hv],
+               "head_dims": [d, dv], "block": block, "window": window,
+               "visited_tiles": visited, "chain": CHAIN,
+               "fwd_ms": _chain_ms(fwd, q, k, v),
+               "out_sha256": hashlib.sha256(
+                   np.asarray(out).tobytes()).hexdigest(),
+               "lse_sha256": hashlib.sha256(
+                   np.asarray(lse).tobytes()).hexdigest(),
+               "device": jax.devices()[0].device_kind}
+        row["fwd_us_per_tile"] = 1e3 * row["fwd_ms"] / visited
+        print(json.dumps(row), flush=True)
+
+    for name in names:
+        probe(name)
+
+
 def bwd_probe(names) -> None:
     import importlib
-    import statistics
 
     import jax
     import jax.numpy as jnp
@@ -64,38 +161,10 @@ def bwd_probe(names) -> None:
     fa = importlib.import_module("deepfake_detection_tpu.ops.flash_attention")
     assert jax.default_backend() == "tpu", jax.default_backend()
 
-    def chain_ms(step, *operands):
-        @jax.jit
-        def run(k, *rest):
-            keep = jnp.float32(0)
-            for _ in range(CHAIN):
-                dq, dk, dv = step(k, *rest)
-                k = k + (dq[0, 0, 0].astype(jnp.float32) * 1e-30
-                         ).astype(k.dtype)
-                keep = keep + dk[0, 0, 0] + dv[0, 0, 0]
-            return k, keep
-        jax.block_until_ready(run(*operands))
-        times = []
-        for _ in range(5):
-            t0 = time.perf_counter()
-            jax.block_until_ready(run(*operands))
-            times.append(time.perf_counter() - t0)
-        return 1e3 * statistics.median(times) / CHAIN
-
     def probe(name):
         b, l, h, hk, hv, d, dv, block, window = BWD_SHAPES[name]
-        bq, bk, lpq, lpk = fa._blocks(l, block, block)
-        keys = jax.random.split(jax.random.PRNGKey(0), 4)
-
-        def operand(key, heads, width, lp):
-            x = jax.random.normal(key, (b * heads, l, width), jnp.bfloat16)
-            return jnp.pad(x, ((0, 0), (0, lp - l),
-                               (0, fa._round_up(width, 128) - width)))
-        q = operand(keys[0], h, d, lpq) * d ** -0.5
-        k, v = operand(keys[1], hk, d, lpk), operand(keys[2], hv, dv, lpk)
-        do = operand(keys[3], h, dv, lpq)
-        kw = dict(window=window, dot_dtype=jnp.bfloat16)
-        static = (1.0, bq, bk, True, l, False)
+        q, k, v, do = _operands(fa, name)
+        static, kw = _static(fa, name)
         out, lse = jax.jit(lambda q, k, v: fa._fwd(q, k, v, *static, **kw))(
             q, k, v)
         delta = fa._delta(do, out)
@@ -117,10 +186,10 @@ def bwd_probe(names) -> None:
                                          window)["bwd"]["visited"]
         row = {"shape": name, "rows": b, "seq_len": l, "heads": [h, hk, hv],
                "head_dims": [d, dv], "block": block, "window": window,
-               "fused_bwd": fa.fused_bwd(lpq, q.shape[2]),
+               "fused_bwd": fa.fused_bwd(q.shape[1], q.shape[2]),
                "visited_tiles": visited, "chain": CHAIN,
-               "split_ms": chain_ms(split, k, *rest),
-               "fused_ms": chain_ms(fused, k, *rest),
+               "split_ms": _chain_ms(split, k, *rest),
+               "fused_ms": _chain_ms(fused, k, *rest),
                "bit_equal": dict(zip(("dq", "dk", "dv"), same)),
                "device": jax.devices()[0].device_kind}
         row["split_us_per_tile"] = 1e3 * row["split_ms"] / visited
@@ -182,6 +251,9 @@ def main() -> None:
     ap.add_argument("--bwd", nargs="*", choices=sorted(BWD_SHAPES),
                     metavar="SHAPE", help="time the split and the fused "
                     "backward at these cells' shapes (none named: all)")
+    ap.add_argument("--fwd", nargs="*", choices=sorted(BWD_SHAPES),
+                    metavar="SHAPE", help="time the forward launch and hash "
+                    "its out and lse at these cells' shapes (none: all)")
     ap.add_argument("--step", nargs="+", metavar="CELL [FORM ...]",
                     help="a sequence cell's own train step with the fused "
                     "and/or the split backward (default: both)")
@@ -192,6 +264,8 @@ def main() -> None:
     ap.add_argument("--seqs", default="196,1024,4096")
     ap.add_argument("--dtype", default="bfloat16")
     args = ap.parse_args()
+    if args.fwd is not None:
+        return fwd_probe(args.fwd or sorted(BWD_SHAPES))
     if args.bwd is not None:
         return bwd_probe(args.bwd or sorted(BWD_SHAPES))
     if args.step:
