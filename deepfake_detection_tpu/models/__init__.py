@@ -18,7 +18,8 @@ for _mod in ("resnet", "xception", "senet", "vit", "mobilenetv3", "densenet",
              "inception_v3", "inception_v4", "inception_resnet_v2", "dpn",
              "hrnet", "dla", "res2net", "sknet", "selecsls", "nasnet",
              "pnasnet", "gluon_resnet", "gluon_xception", "timesformer",
-             "video", "phi4flash", "granite4h", "lfm2moe"):
+             "video", "phi4flash", "granite4h", "lfm2moe",
+             "glm4moelite"):
     try:
         __import__(f"{__name__}.{_mod}")
     except ModuleNotFoundError as e:      # tolerate only a missing family

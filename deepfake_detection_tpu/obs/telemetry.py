@@ -293,6 +293,8 @@ _GAUGE_CATALOG = (
      "by the shapes)"),
     ("attn_split_bwd_layers", "Attention layers of the train step whose "
      "backward is the dK/dV and dQ pair of kernels"),
+    ("mla_layers", "Layers of the train step with multi-head latent "
+     "attention (models/glm4moelite.py; a census fixed by the model)"),
     ("moe_load_peak_to_mean", "The fullest held expert's assignments over "
      "the held experts' mean, over the steps of the last drain that routed "
      "(0 before any)"),
@@ -319,7 +321,8 @@ class TrainTelemetry:
                  ssd_chunks_per_sample: int = 0,
                  dw_grad_stages: Tuple[int, int] = (0, 0),
                  causal_conv_layers: Tuple[int, int] = (0, 0),
-                 attn_bwd_layers: Tuple[int, int] = (0, 0)):
+                 attn_bwd_layers: Tuple[int, int] = (0, 0),
+                 mla_layers: int = 0):
         self.event_log = event_log
         self.flops_per_sample = float(flops_per_sample)
         # attention-kernel cells a step visits per row: a sequence model's
@@ -352,6 +355,7 @@ class TrainTelemetry:
         # (fused, split): a model's attn_bwd_layers(seq_len)
         self._g["attn_fused_bwd_layers"] = float(attn_bwd_layers[0])
         self._g["attn_split_bwd_layers"] = float(attn_bwd_layers[1])
+        self._g["mla_layers"] = float(mla_layers)
         self._g["restart_count"] = float(
             os.environ.get("DFD_RESTART_COUNT", 0) or 0)
         self.h_step = LatencyHistogram(_STEP_BOUNDS)

@@ -222,6 +222,8 @@ class Program:
     # attention layers of the train step by the form of their backward,
     # (fused, split): ops/flash_attention.py:fused_bwd
     attn_bwd_layers: Tuple[int, int] = (0, 0)
+    # layers of the train step with latent attention (models/glm4moelite.py)
+    mla_layers: int = 0
 
 
 def _choose_mesh(cfg: TrainConfig):
@@ -350,12 +352,16 @@ def build_program(cfg: TrainConfig, mesh=None) -> Program:
         attn_bwd_layers = model.attn_bwd_layers(cfg.seq_len)
         _logger.info("Attention backward: attn_fused_bwd_layers=%d "
                      "attn_split_bwd_layers=%d", *attn_bwd_layers)
+    mla_layers = 0
+    if hasattr(model, "mla_layers"):
+        mla_layers = model.mla_layers()
+        _logger.info("Latent attention: mla_layers=%d", mla_layers)
     return Program(
         cfg=cfg, mesh=mesh, n_dev=n_dev, batch_axis=batch_axis, dp=dp_size,
         data_config=data_config, input_size=input_size, model=model,
         sequence_task=sequence_task, dw_grad_stages=dw_grad_stages,
         causal_conv_layers=causal_conv_layers, moe_layers=moe_layers,
-        attn_bwd_layers=attn_bwd_layers,
+        attn_bwd_layers=attn_bwd_layers, mla_layers=mla_layers,
         lr=lr, tx=create_optimizer(cfg, learning_rate=lr),
         lr_scheduler=lr_scheduler, num_epochs=num_epochs,
         loss_fn=create_loss_fn(cfg),
@@ -530,6 +536,7 @@ def build_telemetry(program: Program, state, train_loader,
         dw_grad_stages=program.dw_grad_stages,
         causal_conv_layers=program.causal_conv_layers,
         attn_bwd_layers=program.attn_bwd_layers,
+        mla_layers=program.mla_layers,
         # throughput is measured on the GLOBAL batch (the loader
         # assembles the global sharded array), so the MFU denominator
         # is the whole MESH's peak — n_dev == mesh.size, which a
@@ -786,7 +793,8 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
                         moe_kernel_layers=program.moe_layers[0],
                         moe_xla_layers=program.moe_layers[1],
                         attn_fused_bwd_layers=program.attn_bwd_layers[0],
-                        attn_split_bwd_layers=program.attn_bwd_layers[1])
+                        attn_split_bwd_layers=program.attn_bwd_layers[1],
+                        mla_layers=program.mla_layers)
         if resumed_from:
             telemetry.event("resume", path=resumed_from,
                             epoch=start_epoch, batch=resume_batch)

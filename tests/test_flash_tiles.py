@@ -476,8 +476,12 @@ def test_what_the_predicate_refuses_takes_the_split_pair(refusal,
     ("phi4_mini_flash_6l", 16384, (3, 0), 40 * 2 * (136 + 136 + 63)),
     ("granite4_h_micro_10l", 16384, (1, 0), 32 * 2 * 136),
     ("lfm2_24b_a2b_5l", 8192, (1, 0), 32 * 2 * 36),
+    # five latent-attention layers of 20 heads whose keys are 256 wide:
+    # an 8 MiB dQ row, fused like the others
+    ("glm47_flash_5l", 8192, (5, 0), 5 * 20 * 2 * 36),
     ("granite4_h_micro_10l", 32769, (0, 1), 32 * 3 * 561),
-], ids=["phi4", "granite", "lfm2", "granite-row-past-the-budget"])
+], ids=["phi4", "granite", "lfm2", "glm47flash",
+        "granite-row-past-the-budget"])
 def test_the_sequence_cells_models_count_their_layers_by_the_predicate(
         name, seq_len, layers, tiles):
     """What ``build_program`` logs and ``run_start`` carries for the three
@@ -488,6 +492,35 @@ def test_the_sequence_cells_models_count_their_layers_by_the_predicate(
     assert model.attn_bwd_layers(seq_len) == layers
     assert model.attn_tiles_visited(seq_len) == tiles
 
+
+@pytest.mark.parametrize("l,block", [(600, 256), (1024, 512)],
+                         ids=["ragged-blocks-of-256", "blocks-of-512"])
+def test_heads_of_256_lanes_match_dense_attention(monkeypatch, l, block):
+    """Keys and values 256 wide, two lane tiles a head (latent attention's
+    192 + 64 channels, models/glm4moelite.py), causal, scale 1/16: the
+    forward and the one fused backward (it is the one that runs) against
+    plain softmax attention, float32 under the interpreter."""
+    from deepfake_detection_tpu.parallel.ring_attention import \
+        full_attention
+    calls = _spy(monkeypatch, "_bwd_fused", "_bwd_dkv", "_bwd_dq")
+    q, k, v, w = (jax.random.normal(kk, (1, l, 2, 256), jnp.float32)
+                  for kk in jax.random.split(jax.random.PRNGKey(l), 4))
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+    flash = lambda q, k, v: FA.flash_attention(            # noqa: E731
+        q, k, v, causal=True, scale=1 / 16, block_q=block, block_k=block)
+    dense = lambda q, k, v: full_attention(                # noqa: E731
+        q, k, v, causal=True, scale=1 / 16)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(flash(q, k, v), dense(q, k, v),
+                                   atol=2e-5)
+        got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+        want = jax.grad(loss(dense), (0, 1, 2))(q, k, v)
+    assert calls == {"_bwd_fused": 1, "_bwd_dkv": 0, "_bwd_dq": 0}
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-4 * float(
+            jnp.abs(b).max()))
 
 if __name__ == "__main__":
     # PYTHONPATH=<a checkout of the PARENT commit> python tests/test_flash_tiles.py

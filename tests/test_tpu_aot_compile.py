@@ -365,3 +365,89 @@ def test_expert_ffn_lfm2moe_16k_compiles(one_chip, grad):
                           argnums=range(4)), *args, sel)
     else:
         _compile(ffn, *args, sel)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_attention_glm47flash_mla_8k_compiles(one_chip, grad):
+    """GLM-4.7-Flash's latent attention at 8,192 tokens: 20 heads whose
+    keys are 256 wide (192 of their own and the 64 of the shared rotary
+    key) and whose values are 256 wide, two lane tiles a head where every
+    other cell has one, blocks of 1024, the scale 1/16 folded into q, and
+    the fused backward (an 8 MiB float32 dQ row beside the tile): forward
+    + backward are 2 Mosaic calls."""
+    spec = jax.ShapeDtypeStruct((1, 8192, 20, 256), jnp.bfloat16,
+                                sharding=one_chip)
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=1 / 16,
+                               block_q=1024, block_k=1024,
+                               dot_dtype=jnp.bfloat16, interpret=False)
+    if grad:
+        compiled = _compile(jax.grad(lambda q, k, v: attn(q, k, v).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)), spec, spec, spec)
+        assert compiled.as_text().count("tpu_custom_call") == 2
+    else:
+        _compile(attn, spec, spec, spec)
+
+
+def test_glm47flash_step_fits_the_chip(one_chip, monkeypatch):
+    """The runner's whole train step of ``train_glm47flash_mla`` (the
+    configuration's own flags: 4 microbatches of one 8,192-token row),
+    every kernel compiled as on the chip: ``memory_analysis()`` within the
+    15.0 GB the configuration's split was chosen under (14.91 GB when it
+    was), the attention of all five layers and the grouped products of all
+    four expert layers in Mosaic."""
+    import functools
+    import importlib
+    import json
+    import re
+
+    from deepfake_detection_tpu.config import TrainConfig
+    from deepfake_detection_tpu.models import init_model
+    from deepfake_detection_tpu.ops import moe
+    from deepfake_detection_tpu.parallel import (batch_sharding,
+                                                 make_train_mesh,
+                                                 replicated_sharding,
+                                                 train_state_shardings)
+    from deepfake_detection_tpu.runners import train as T
+    from deepfake_detection_tpu.train import create_train_state
+    for name in ("flash_attention", "causal_conv", "moe"):
+        monkeypatch.setattr(
+            importlib.import_module("deepfake_detection_tpu.ops." + name),
+            "resolve_interpret", lambda interpret, kernel: False)
+    monkeypatch.setattr(moe, "moe_impl",
+                        functools.partial(moe.moe_impl, backend="tpu"))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "glm47_flash_5l.json")) as f:
+        flags = json.load(f)["train_flags"]
+    mesh = make_train_mesh(batch=1, model=1,
+                           devices=list(one_chip.device_set))
+    program = T.build_program(TrainConfig.from_args(flags), mesh=mesh)
+    assert program.moe_layers == (4, 0) and program.mla_layers == 5
+    assert program.attn_bwd_layers == (5, 0)
+    state = jax.eval_shape(lambda: create_train_state(init_model(
+        program.model, jax.random.PRNGKey(0), (1, 8), training=True,
+        dtype=jnp.int32), program.tx))
+    shardings = train_state_shardings(state, mesh, fsdp=False,
+                                      axis=program.batch_axis)
+    step = T.build_steps(program, shardings)[0]
+    ids = jax.ShapeDtypeStruct((program.global_batch, 8192), jnp.int32,
+                               sharding=batch_sharding(mesh))
+    key = jax.random.PRNGKey(0)
+    compiled = step.lower(
+        jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=s), state, shardings), ids, ids,
+        jax.ShapeDtypeStruct(key.shape, key.dtype,
+                             sharding=replicated_sharding(mesh))).compile()
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert 12.0e9 < total <= 15.0e9, total
+    lines = compiled.as_text().splitlines()
+    mosaic = lambda scope: sum(                              # noqa: E731
+        1 for line in lines if "tpu_custom_call" in line
+        and re.search(r'op_name="[^"]*' + scope, line))
+    # forward, forward again under remat, the fused backward: 3 a layer
+    assert mosaic("attn_latent") == 3 * 5
+    assert mosaic("moe_experts") > 0

@@ -44,6 +44,8 @@ CUTS = {
     # one pass a step here: the record's global batch is rows x devices
     "lfm2_24b_a2b_5l": (["--model", "lfm2_24b_a2b_tiny", "--seq-len", "64",
                          "--grad-accum", "1"], {"train": {"seq_len": 64}}),
+    "glm47_flash_5l": (["--model", "glm47_flash_tiny", "--seq-len", "64",
+                        "--grad-accum", "1"], {"train": {"seq_len": 64}}),
 }
 
 
@@ -227,6 +229,9 @@ def test_telemetry_is_the_runners(built, tmp_path):
     assert (p.attn_bwd_layers[0] > 0) == p.sequence_task
     assert p.attn_bwd_layers[1] == 0
     assert "dfd_train_attn_fused_bwd_layers" in telemetry.render_prometheus()
+    # the latent-attention census: every layer of the GLM stack, no other
+    assert snap["gauges"]["mla_layers"] == p.mla_layers == (
+        p.model.mla_layers() if hasattr(p.model, "mla_layers") else 0)
     assert os.path.isfile(tmp_path / "telemetry.jsonl")
 
 
