@@ -361,7 +361,11 @@ class TrainConfig:
     tp_size: int = 1     # model-axis extent for transformer tensor
     # parallelism: builds a (data, model) 2-D mesh and applies the
     # Megatron-paired shardings from parallel/tp.py (ViT/TimeSformer)
-    checkpoint_policy: str = "none"      # remat policy: none|full|dots
+    # remat policy (models/helpers.py:maybe_remat): none | full (each layer
+    # computed again in the backward) | dots (keeps matmul/conv outputs);
+    # full and dots also keep the flash attention op's output and one
+    # float32 a row of its statistics, so its forward kernel runs once
+    checkpoint_policy: str = "none"
     # transformer attention kernel: "" = model default (full). 'flash' runs
     # the Pallas kernels; 'ring'/'ring_flash'/'ulysses' are sequence-
     # parallel and need an sp mesh — library-level for now (models/vit.py)

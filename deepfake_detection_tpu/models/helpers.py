@@ -287,14 +287,23 @@ def maybe_remat(block_cls, policy: str):
     surface of EfficientNet/ViT/TimeSformer; TrainConfig.checkpoint_policy).
 
     'none' — save all activations; 'full' — recompute the whole block in
-    the backward pass; 'dots' — save only matmul/conv outputs.  Blocks must
-    take ``training`` as their second positional argument (static).
+    the backward pass; 'dots' — save only matmul/conv outputs.  Both
+    remat policies also keep what the flash attention op names
+    (``ops/flash_attention.py:FLASH_RESIDUALS``: its output and one float32
+    a row of its statistics), so a block that calls the op never runs its
+    forward kernel again; a block without the op has no such names and is
+    rematerialised as before.  Blocks must take ``training`` as their
+    second positional argument (static).
     """
     import flax.linen as nn
+    from ..ops.flash_attention import FLASH_RESIDUALS
     assert policy in ("none", "full", "dots"), \
         f"remat policy must be none|full|dots, got {policy!r}"
     if policy == "none":
         return block_cls
-    jpolicy = None if policy == "full" \
-        else jax.checkpoint_policies.checkpoint_dots
+    policies = jax.checkpoint_policies
+    jpolicy = policies.save_only_these_names(*FLASH_RESIDUALS)
+    if policy == "dots":
+        jpolicy = policies.save_from_both_policies(policies.checkpoint_dots,
+                                                   jpolicy)
     return nn.remat(block_cls, policy=jpolicy, static_argnums=(2,))

@@ -288,8 +288,9 @@ class Phi4Flash(nn.Module):
         """Grid cells the attention kernels visit in one train step over one
         row of ``seq_len`` tokens: each attention layer's query heads times
         the forward's and the backward's counts
-        (ops/flash_attention.py:train_tiles_visited; a forward made again
-        under remat is not counted again).  Static per shape: a census, not
+        (ops/flash_attention.py:train_tiles_visited; under remat the
+        forward's saved output keeps it from running again).  Static per
+        shape: a census, not
         a measurement.  0 where the dense path runs."""
         if self.attn_impl != "flash":
             return 0

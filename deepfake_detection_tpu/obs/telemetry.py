@@ -293,6 +293,11 @@ _GAUGE_CATALOG = (
      "by the shapes)"),
     ("attn_split_bwd_layers", "Attention layers of the train step whose "
      "backward is the dK/dV and dQ pair of kernels"),
+    ("attn_fwd_saved_layers", "Rematerialised attention layers of a "
+     "sequence model's train step whose backward reuses the forward "
+     "kernel's saved output and row statistics instead of running it again "
+     "(ops/flash_attention.py:saved_fwd_census, from the layers and the "
+     "remat policy; a census: ViT and TimeSformer are not counted)"),
     ("mla_layers", "Layers of the train step with multi-head latent "
      "attention (models/glm4moelite.py; a census fixed by the model)"),
     ("moe_load_peak_to_mean", "The fullest held expert's assignments over "
@@ -322,6 +327,7 @@ class TrainTelemetry:
                  dw_grad_stages: Tuple[int, int] = (0, 0),
                  causal_conv_layers: Tuple[int, int] = (0, 0),
                  attn_bwd_layers: Tuple[int, int] = (0, 0),
+                 attn_fwd_saved_layers: int = 0,
                  mla_layers: int = 0):
         self.event_log = event_log
         self.flops_per_sample = float(flops_per_sample)
@@ -355,6 +361,7 @@ class TrainTelemetry:
         # (fused, split): a model's attn_bwd_layers(seq_len)
         self._g["attn_fused_bwd_layers"] = float(attn_bwd_layers[0])
         self._g["attn_split_bwd_layers"] = float(attn_bwd_layers[1])
+        self._g["attn_fwd_saved_layers"] = float(attn_fwd_saved_layers)
         self._g["mla_layers"] = float(mla_layers)
         self._g["restart_count"] = float(
             os.environ.get("DFD_RESTART_COUNT", 0) or 0)

@@ -46,6 +46,15 @@ offsets and the row's bytes (``_DQ_ROW_BYTES``: 16 MiB of the 64 MiB the
 fused launch is given, 32,768 positions of a 128-lane head).  The three
 backward kernels share :func:`_tile_grads` for the cell's expression.
 
+**Residuals under remat.**  The op's custom VJP keeps ``(q, k, v, out,
+lse)``.  Its forward tags ``out`` (the op's result and its residual both,
+one value, in the padded layout) and ``lse`` (one float32 a row, cut from
+the kernel's lane-replicated array and widened again in the backward) with
+``jax.ad_checkpoint.checkpoint_name`` under :data:`FLASH_RESIDUALS`.  A
+remat policy that saves those names (``models/helpers.py:maybe_remat``)
+keeps the two from the forward, so a rematerialised layer's backward never
+runs the forward kernel again; without such a policy the tags do nothing.
+
 All matmuls run on the MXU in float32 accumulation
 (``preferred_element_type``) regardless of the bf16 inputs; masking (padded
 keys, causal) is computed from ``broadcasted_iota`` against dynamic global
@@ -100,13 +109,20 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "tile_visible", "tile_census", "fused_bwd",
-           "train_tiles_visited", "fused_bwd_census"]
+           "train_tiles_visited", "fused_bwd_census", "FLASH_RESIDUALS",
+           "saved_fwd_census"]
 
 _logger = logging.getLogger(__name__)
+
+# the names the op's forward gives its output and its row statistics: a
+# remat policy that saves them keeps the backward from running the forward
+# kernel again (models/helpers.py:maybe_remat)
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
 
 _NEG_INF = float("-inf")
 _LANES = 128          # scalar-per-row scratch is lane-replicated to 128
@@ -762,10 +778,16 @@ def _bwd_dq(q, k, v, do, lse, delta, scale, block_q, block_k, causal,
     )(_as_scalar(q_off), _as_scalar(kv_off), q, k, v, do, lse, delta)
 
 
+def _lane_rows(x):
+    """One value a row, (BH, L), lane-replicated to the kernels' (BH, L,
+    128) layout of row statistics."""
+    return jnp.broadcast_to(x[..., None], (*x.shape, _LANES))
+
+
 def _delta(do, out):
     """δ = rowsum(dO ⊙ O), lane-replicated to match the lse layout."""
-    d = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    return jnp.broadcast_to(d[..., None], (*d.shape, _LANES))
+    return _lane_rows(jnp.sum(do.astype(jnp.float32) * out.astype(
+        jnp.float32), axis=-1))
 
 
 def _group_sum(x, heads: int):
@@ -801,11 +823,11 @@ def _bwd_kernels(q, k, v, do, lse, delta, scale, block_q, block_k, causal,
 
 def _bwd(scale, block_q, block_k, causal, interpret, seq_len, res, g,
          window=None, dot_dtype=None):
-    q, k, v, out, lse = res
+    q, k, v, out, lse = res              # lse: (BH, Lq), one float a row
     do = g[0] if isinstance(g, (tuple, list)) else g
-    dq, dk, dv = _bwd_kernels(q, k, v, do, lse, _delta(do, out), scale,
-                              block_q, block_k, causal, seq_len, interpret,
-                              window=window, dot_dtype=dot_dtype)
+    dq, dk, dv = _bwd_kernels(q, k, v, do, _lane_rows(lse), _delta(do, out),
+                              scale, block_q, block_k, causal, seq_len,
+                              interpret, window=window, dot_dtype=dot_dtype)
     dk, dv = _group_sum(dk, k.shape[0]), _group_sum(dv, v.shape[0])
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
@@ -862,7 +884,8 @@ def train_tiles_visited(l: int, head_dim: int, block_q: int = 128,
     """Grid cells with a visible pair that one query head's forward and
     backward launch over a row of ``l`` tokens: the forward's and the
     fused backward's, or the split pair's where :func:`fused_bwd` refuses
-    the row (a forward made again under remat is not counted again)."""
+    the row (one forward: under remat the saved residuals,
+    :func:`saved_fwd_census`, keep it from running again)."""
     census = tile_census(l, block_q, block_k, causal, window)
     kernels = ("fwd", "bwd") if _op_fuses(l, head_dim, block_q) \
         else ("fwd", "dkv", "dq")
@@ -875,6 +898,16 @@ def fused_bwd_census(layers: int, l: int, head_dim: int,
     their backward takes, (fused, split): what a model's
     ``attn_bwd_layers`` reports."""
     return (layers, 0) if _op_fuses(l, head_dim, block_q) else (0, layers)
+
+
+def saved_fwd_census(layers: int, remat_policy: str) -> int:
+    """Of ``layers`` attention layers rematerialised under
+    ``remat_policy`` (``models/helpers.py:maybe_remat``), those whose
+    backward reuses the forward kernel's saved :data:`FLASH_RESIDUALS`
+    instead of running it again: all of them under ``full`` and ``dots``,
+    none where nothing is rematerialised.  What a train step's
+    ``attn_fwd_saved_layers`` reports."""
+    return layers if remat_policy in ("full", "dots") else 0
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -934,6 +967,12 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     def _op_fwd(qp, kp, vp):
         out, lse = _fwd_call(qp, kp, vp)
+        # tagged for a remat policy (FLASH_RESIDUALS): the result and the
+        # residual are the one tagged ``out``, or the policy would save the
+        # residual and still run the kernel again for the result; ``lse``
+        # at one float a row, every lane of the kernel's array being equal
+        out = checkpoint_name(out, FLASH_RESIDUALS[0])
+        lse = checkpoint_name(lse[..., 0], FLASH_RESIDUALS[1])
         return out, (qp, kp, vp, out, lse)
 
     def _fwd_call(qp, kp, vp):

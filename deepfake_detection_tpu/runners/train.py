@@ -51,6 +51,7 @@ from ..data import (DeepFakeClipDataset, FastCollateMixup, SyntheticDataset,
 from ..losses import create_loss_fn, cross_entropy
 from ..models import (create_deepfake_model, create_deepfake_model_v3,
                       create_deepfake_model_v4, create_model, init_model)
+from ..ops.flash_attention import saved_fwd_census
 from ..optim import create_optimizer
 from ..parallel import (batch_sharding, data_axis_name,
                         initialize_distributed, make_mesh, make_train_mesh,
@@ -222,6 +223,9 @@ class Program:
     # attention layers of the train step by the form of their backward,
     # (fused, split): ops/flash_attention.py:fused_bwd
     attn_bwd_layers: Tuple[int, int] = (0, 0)
+    # attention layers whose backward reuses the forward kernel's saved
+    # output under the remat policy: ops/flash_attention.py:saved_fwd_census
+    attn_fwd_saved_layers: int = 0
     # layers of the train step with latent attention (models/glm4moelite.py)
     mla_layers: int = 0
 
@@ -347,11 +351,14 @@ def build_program(cfg: TrainConfig, mesh=None) -> Program:
             cfg.batch_size * dp_size * cfg.seq_len)
         _logger.info("Routed expert layers: moe_kernel_layers=%d "
                      "moe_xla_layers=%d", *moe_layers)
-    attn_bwd_layers = (0, 0)
+    attn_bwd_layers, attn_fwd_saved_layers = (0, 0), 0
     if hasattr(model, "attn_bwd_layers"):
         attn_bwd_layers = model.attn_bwd_layers(cfg.seq_len)
-        _logger.info("Attention backward: attn_fused_bwd_layers=%d "
-                     "attn_split_bwd_layers=%d", *attn_bwd_layers)
+        attn_fwd_saved_layers = saved_fwd_census(sum(attn_bwd_layers),
+                                                 model.remat_policy)
+        _logger.info("Attention: attn_fused_bwd_layers=%d "
+                     "attn_split_bwd_layers=%d attn_fwd_saved_layers=%d",
+                     *attn_bwd_layers, attn_fwd_saved_layers)
     mla_layers = 0
     if hasattr(model, "mla_layers"):
         mla_layers = model.mla_layers()
@@ -361,7 +368,8 @@ def build_program(cfg: TrainConfig, mesh=None) -> Program:
         data_config=data_config, input_size=input_size, model=model,
         sequence_task=sequence_task, dw_grad_stages=dw_grad_stages,
         causal_conv_layers=causal_conv_layers, moe_layers=moe_layers,
-        attn_bwd_layers=attn_bwd_layers, mla_layers=mla_layers,
+        attn_bwd_layers=attn_bwd_layers,
+        attn_fwd_saved_layers=attn_fwd_saved_layers, mla_layers=mla_layers,
         lr=lr, tx=create_optimizer(cfg, learning_rate=lr),
         lr_scheduler=lr_scheduler, num_epochs=num_epochs,
         loss_fn=create_loss_fn(cfg),
@@ -536,6 +544,7 @@ def build_telemetry(program: Program, state, train_loader,
         dw_grad_stages=program.dw_grad_stages,
         causal_conv_layers=program.causal_conv_layers,
         attn_bwd_layers=program.attn_bwd_layers,
+        attn_fwd_saved_layers=program.attn_fwd_saved_layers,
         mla_layers=program.mla_layers,
         # throughput is measured on the GLOBAL batch (the loader
         # assembles the global sharded array), so the MFU denominator
@@ -794,6 +803,7 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
                         moe_xla_layers=program.moe_layers[1],
                         attn_fused_bwd_layers=program.attn_bwd_layers[0],
                         attn_split_bwd_layers=program.attn_bwd_layers[1],
+                        attn_fwd_saved_layers=program.attn_fwd_saved_layers,
                         mla_layers=program.mla_layers)
         if resumed_from:
             telemetry.event("resume", path=resumed_from,

@@ -84,3 +84,103 @@ def test_jit_and_vit_integration():
     ref = ref_model.apply(variables, x, training=False)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(ref),
                                atol=1e-4, rtol=1e-4)
+
+
+# ---- remat keeps what the forward made ---------------------------------------
+
+def _kernels(jaxpr, names=None):
+    """The kernels' names of every ``pallas_call`` in ``jaxpr`` and the
+    jaxprs nested in it (remat, custom VJP, jit), in order."""
+    from jax.extend import core as jcore
+    names = [] if names is None else names
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["jaxpr"].debug_info.func_name)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else [param]:
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    _kernels(sub.jaxpr, names)
+                elif isinstance(sub, jcore.Jaxpr):
+                    _kernels(sub, names)
+    return names
+
+
+def _attention_block(heads, kv_heads, v_heads, d, dv, window):
+    """A residual block around one flash attention call, with projections
+    before and after it, as the sequence models' layers have."""
+    import flax.linen as nn
+
+    class Block(nn.Module):
+        @nn.compact
+        def __call__(self, x, training=False):
+            b, l, _ = x.shape
+            q = nn.Dense(heads * d)(x).reshape(b, l, heads, d)
+            k = nn.Dense(kv_heads * d)(x).reshape(b, l, kv_heads, d)
+            v = nn.Dense(v_heads * dv)(x).reshape(b, l, v_heads, dv)
+            o = flash_attention(q, k, v, causal=True, window=window)
+            return x + nn.Dense(x.shape[-1])(o.reshape(b, l, heads * dv))
+    return Block
+
+
+ATTENTION_CASES = {
+    "causal": (2, 2, 2, 64, 64, None),
+    "window": (2, 2, 2, 64, 64, 48),
+    "grouped-64": (4, 2, 1, 64, 64, None),
+    "grouped-256": (4, 1, 2, 256, 256, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ATTENTION_CASES))
+@pytest.mark.parametrize("policy", ["full", "dots"])
+def test_remat_runs_the_forward_kernel_once(policy, case):
+    """Under ``maybe_remat``'s ``full`` and ``dots`` the gradient of a block
+    that calls the op runs the forward kernel once: the policy keeps the
+    output and row statistics the op names (``FLASH_RESIDUALS``), so the
+    rematerialised block does not launch it again (it did before they
+    were saved: two forwards and the backward).  The gradients are those
+    of the block with nothing rematerialised."""
+    from deepfake_detection_tpu.models.helpers import maybe_remat
+    block = _attention_block(*ATTENTION_CASES[case])
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 160, 16))
+    params = block().init(jax.random.PRNGKey(1), x, False)
+
+    def grad(policy):
+        m = maybe_remat(block, policy)()
+        return jax.grad(lambda p: (m.apply(p, x, False) ** 2).sum())
+
+    kernels = _kernels(jax.make_jaxpr(grad(policy))(params).jaxpr)
+    assert kernels.count("_fwd_kernel") == 1, kernels
+    assert kernels.count("_bwd_fused_kernel") == 1, kernels
+    for a, b in zip(jax.tree.leaves(grad("none")(params)),
+                    jax.tree.leaves(grad(policy)(params))):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=2e-5)
+
+
+@pytest.mark.parametrize("policy", ["full", "dots"])
+def test_remat_of_a_block_without_attention_lowers_as_before(policy):
+    """A block that calls no flash op has none of its names: its gradient
+    under ``maybe_remat`` lowers to the text of the plain ``nn.remat`` the
+    policy was before (nothing saved for ``full``, the matmul/conv outputs
+    for ``dots``): the EfficientNet and image models' steps are the
+    parent's."""
+    import flax.linen as nn
+    from deepfake_detection_tpu.models.efficientnet_blocks import \
+        InvertedResidual
+    from deepfake_detection_tpu.models.helpers import maybe_remat
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 16, 8))
+    kw = dict(out_chs=8, exp_ratio=4.0, se_ratio=0.25, act="swish")
+    variables = InvertedResidual(**kw).init(jax.random.PRNGKey(1), x, True)
+    old = None if policy == "full" else jax.checkpoint_policies.checkpoint_dots
+
+    def text(cls):
+        m = cls(**kw)
+
+        def loss(p):
+            y, _ = m.apply({"params": p,
+                            "batch_stats": variables["batch_stats"]}, x, True,
+                           mutable=["batch_stats"])
+            return (y ** 2).sum()
+        return jax.jit(jax.grad(loss)).lower(variables["params"]).as_text()
+
+    assert text(maybe_remat(InvertedResidual, policy)) == text(
+        nn.remat(InvertedResidual, policy=old, static_argnums=(2,)))

@@ -390,13 +390,20 @@ def test_flash_attention_glm47flash_mla_8k_compiles(one_chip, grad):
         _compile(attn, spec, spec, spec)
 
 
-def test_glm47flash_step_fits_the_chip(one_chip, monkeypatch):
+def test_glm47flash_step_fits_the_chip(one_chip, monkeypatch, tmp_path):
     """The runner's whole train step of ``train_glm47flash_mla`` (the
     configuration's own flags: 4 microbatches of one 8,192-token row),
-    every kernel compiled as on the chip: ``memory_analysis()`` within the
-    15.0 GB the configuration's split was chosen under (14.91 GB when it
-    was), the attention of all five layers and the grouped products of all
-    four expert layers in Mosaic."""
+    every kernel compiled as on the chip: the bytes the buffer assignment
+    allocates within 13.46 GB, the attention of all five layers and the
+    grouped products of all four expert layers in Mosaic.
+
+    The bound is what the step allocated before remat kept the flash op's
+    output and row statistics (12.96 GB; 13.15 GB peak on a v5e) plus
+    0.5 GB: the five saved attention outputs are 84 MB a layer in bf16
+    (13.42 GB with them; 13.60 GB peak on a v5e).  It reads the compiler's
+    memory-usage report, not ``memory_analysis()``, which counts the loop
+    body's saved values about twice (14.91 GB before them, 15.82 GB
+    with)."""
     import functools
     import importlib
     import json
@@ -439,15 +446,18 @@ def test_glm47flash_step_fits_the_chip(one_chip, monkeypatch):
         jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=s), state, shardings), ids, ids,
         jax.ShapeDtypeStruct(key.shape, key.dtype,
-                             sharding=replicated_sharding(mesh))).compile()
-    mem = compiled.memory_analysis()
-    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
-        + mem.output_size_in_bytes - mem.alias_size_in_bytes
-    assert 12.0e9 < total <= 15.0e9, total
+                             sharding=replicated_sharding(mesh))).compile(
+        compiler_options={"xla_dump_to": str(tmp_path)})
+    reports = list(tmp_path.glob("*jit_step*memory-usage-report.txt"))
+    assert len(reports) == 1, reports
+    used = re.match(r"Total bytes used: (\d+)",
+                    reports[0].read_text()).group(1)
+    assert 12.0e9 < int(used) <= 13.46e9, used
     lines = compiled.as_text().splitlines()
     mosaic = lambda scope: sum(                              # noqa: E731
         1 for line in lines if "tpu_custom_call" in line
         and re.search(r'op_name="[^"]*' + scope, line))
-    # forward, forward again under remat, the fused backward: 3 a layer
-    assert mosaic("attn_latent") == 3 * 5
+    # the forward and the fused backward: 2 a layer (remat keeps the
+    # forward's output and row statistics, so it does not run again)
+    assert mosaic("attn_latent") == 2 * 5
     assert mosaic("moe_experts") > 0

@@ -229,10 +229,35 @@ def test_telemetry_is_the_runners(built, tmp_path):
     assert (p.attn_bwd_layers[0] > 0) == p.sequence_task
     assert p.attn_bwd_layers[1] == 0
     assert "dfd_train_attn_fused_bwd_layers" in telemetry.render_prometheus()
+    # the saved-forward census: every flash layer of a sequence model
+    # under its configuration's remat policy (full), none of an image model
+    assert snap["gauges"]["attn_fwd_saved_layers"] == \
+        p.attn_fwd_saved_layers == (sum(p.attn_bwd_layers)
+                                    if p.cfg.checkpoint_policy != "none"
+                                    else 0)
+    assert "dfd_train_attn_fwd_saved_layers" in \
+        telemetry.render_prometheus()
     # the latent-attention census: every layer of the GLM stack, no other
     assert snap["gauges"]["mla_layers"] == p.mla_layers == (
         p.model.mla_layers() if hasattr(p.model, "mla_layers") else 0)
     assert os.path.isfile(tmp_path / "telemetry.jsonl")
+
+
+@pytest.mark.parametrize("name,saved", [
+    ("phi4_mini_flash_6l", 3), ("granite4_h_micro_10l", 1),
+    ("lfm2_24b_a2b_5l", 1), ("glm47_flash_5l", 5)])
+def test_the_saved_forward_census_at_the_cells_size(name, saved):
+    """``attn_fwd_saved_layers`` of each sequence configuration's own flags
+    (full size: the census reads shapes, not arrays): every attention
+    layer under the cell's ``full``, which saves the flash op's output and
+    row statistics, none under ``none``."""
+    with open(os.path.join(REPO, "benchmark", "configs", name + ".json")) as f:
+        flags = json.load(f)["train_flags"]
+    mesh = T.make_train_mesh(batch=1, model=1, devices=jax.devices()[:1])
+    census = {policy: T.build_program(TrainConfig.from_args(
+        flags + ["--checkpoint-policy", policy]),
+        mesh=mesh).attn_fwd_saved_layers for policy in ("full", "none")}
+    assert census == {"full": saved, "none": 0}
 
 
 # ---- where a restored snapshot puts the loop -------------------------------
