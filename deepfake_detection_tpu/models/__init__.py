@@ -19,7 +19,7 @@ for _mod in ("resnet", "xception", "senet", "vit", "mobilenetv3", "densenet",
              "hrnet", "dla", "res2net", "sknet", "selecsls", "nasnet",
              "pnasnet", "gluon_resnet", "gluon_xception", "timesformer",
              "video", "phi4flash", "granite4h", "lfm2moe",
-             "glm4moelite"):
+             "glm4moelite", "keyevl2"):
     try:
         __import__(f"{__name__}.{_mod}")
     except ModuleNotFoundError as e:      # tolerate only a missing family

@@ -27,10 +27,11 @@ __all__ = ["create_model", "create_deepfake_model", "create_deepfake_model_v3",
 _BN_KWARG_MODULES = ("efficientnet", "mobilenetv3")
 # modules that consume the remat policy (TrainConfig.checkpoint_policy)
 _REMAT_MODULES = _BN_KWARG_MODULES + ("vit", "timesformer", "phi4flash",
-                                        "granite4h", "lfm2moe", "glm4moelite")
+                                        "granite4h", "lfm2moe", "glm4moelite",
+                                        "keyevl2")
 # modules with a pluggable attention kernel (TrainConfig.attn_impl)
 _ATTN_MODULES = ("vit", "timesformer", "phi4flash", "granite4h",
-                 "lfm2moe", "glm4moelite")
+                 "lfm2moe", "glm4moelite", "keyevl2")
 
 _DROP_BLOCK_MODULES = ("resnet", "res2net", "sknet", "gluon_resnet")
 _ATTN_IMPLS = ("full", "flash", "ring", "ring_flash", "ulysses")

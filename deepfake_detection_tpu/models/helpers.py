@@ -290,19 +290,23 @@ def maybe_remat(block_cls, policy: str):
     the backward pass; 'dots' — save only matmul/conv outputs.  Both
     remat policies also keep what the flash attention op names
     (``ops/flash_attention.py:FLASH_RESIDUALS``: its output and one float32
-    a row of its statistics), so a block that calls the op never runs its
-    forward kernel again; a block without the op has no such names and is
-    rematerialised as before.  Blocks must take ``training`` as their
+    a row of its statistics) and what the sparse attention op names
+    (``ops/sparse_attention.py:SPARSE_RESIDUALS``: its output, its row
+    statistics and the selection), so a block that calls either op never
+    runs its forward kernels again; a block without them has no such names
+    and is rematerialised as before.  Blocks must take ``training`` as their
     second positional argument (static).
     """
     import flax.linen as nn
     from ..ops.flash_attention import FLASH_RESIDUALS
+    from ..ops.sparse_attention import SPARSE_RESIDUALS
     assert policy in ("none", "full", "dots"), \
         f"remat policy must be none|full|dots, got {policy!r}"
     if policy == "none":
         return block_cls
     policies = jax.checkpoint_policies
-    jpolicy = policies.save_only_these_names(*FLASH_RESIDUALS)
+    jpolicy = policies.save_only_these_names(*FLASH_RESIDUALS,
+                                              *SPARSE_RESIDUALS)
     if policy == "dots":
         jpolicy = policies.save_from_both_policies(policies.checkpoint_dots,
                                                    jpolicy)

@@ -142,6 +142,19 @@ _COUNTER_CATALOG = (
      "capacity, so that the layer walked every row (ops/moe.py); summed "
      "the same way.  A pass is moe_routed_tokens_total over the tokens of "
      "a microbatch"),
+    ("dsa_selected_pairs_total", "(query, key) pairs the learned sparse "
+     "attention layers of the train steps selected (ops/sparse_attention.py"
+     ": topk a query, every causal key of the first topk positions), counted "
+     "on the device, fetched with each step's metrics and added at the "
+     "drain with the two counters below; 0 for models without such a "
+     "layer"),
+    ("dsa_blocks_touched_total", "(128 query, 128 key) causal blocks that "
+     "hold at least one selected pair, summed the same way: over "
+     "dsa_blocks_causal_total, the share of the causal blocks a kernel that "
+     "skips blocks without a selected pair would still have to visit (a "
+     "census of the selection, data-dependent)"),
+    ("dsa_blocks_causal_total", "(128 query, 128 key) blocks holding a "
+     "causal pair, summed the same way"),
     ("drains_total", "Metric drain boundaries (telemetry records)"),
     ("step_seconds_total", "Wall seconds spent in the train loop"),
     ("step_h2d_block_seconds_total", "Seconds the loop's steps were blocked "
@@ -300,6 +313,12 @@ _GAUGE_CATALOG = (
      "remat policy; a census: ViT and TimeSformer are not counted)"),
     ("mla_layers", "Layers of the train step with multi-head latent "
      "attention (models/glm4moelite.py; a census fixed by the model)"),
+    ("dsa_layers", "Layers of the train step with learned sparse "
+     "attention (models/keyevl2.py; a census fixed by the model)"),
+    ("dsa_kl_loss", "The sparse-attention indexer's loss, KL of the heads' "
+     "mean attention over the selected keys from the indexer's softmax, "
+     "mean over rows and layers, over the steps of the last drain (0 "
+     "before any)"),
     ("moe_load_peak_to_mean", "The fullest held expert's assignments over "
      "the held experts' mean, over the steps of the last drain that routed "
      "(0 before any)"),
@@ -328,7 +347,8 @@ class TrainTelemetry:
                  causal_conv_layers: Tuple[int, int] = (0, 0),
                  attn_bwd_layers: Tuple[int, int] = (0, 0),
                  attn_fwd_saved_layers: int = 0,
-                 mla_layers: int = 0):
+                 mla_layers: int = 0,
+                 dsa_layers: int = 0):
         self.event_log = event_log
         self.flops_per_sample = float(flops_per_sample)
         # attention-kernel cells a step visits per row: a sequence model's
@@ -363,6 +383,7 @@ class TrainTelemetry:
         self._g["attn_split_bwd_layers"] = float(attn_bwd_layers[1])
         self._g["attn_fwd_saved_layers"] = float(attn_fwd_saved_layers)
         self._g["mla_layers"] = float(mla_layers)
+        self._g["dsa_layers"] = float(dsa_layers)
         self._g["restart_count"] = float(
             os.environ.get("DFD_RESTART_COUNT", 0) or 0)
         self.h_step = LatencyHistogram(_STEP_BOUNDS)
@@ -488,6 +509,17 @@ class TrainTelemetry:
             if assignments:
                 self._g["moe_load_peak_to_mean"] = round(
                     peak_filled / assignments, 4)
+
+    def on_sparse_attention(self, selected: int, touched: int, causal: int,
+                            kl_loss: float) -> None:
+        """Once per drain that fetched a sparse-attention census, with its
+        sums over the drained steps (ops/sparse_attention.py) and the
+        indexer's loss, their mean; host numbers."""
+        with self._lock:
+            self._c["dsa_selected_pairs_total"] += selected
+            self._c["dsa_blocks_touched_total"] += touched
+            self._c["dsa_blocks_causal_total"] += causal
+            self._g["dsa_kl_loss"] = kl_loss
 
     def on_drain(self, *, epoch: int, batch_idx: int, num_updates: int,
                  loss: float, prec1: float, lr: float,

@@ -5,7 +5,9 @@ combine.
 The router scores **all** ``E`` experts of the published layer with a
 sigmoid, *selects* the top ``k`` of a token by score plus a per-expert bias
 and *weighs* them by the score without it, normalised over the ``k``
-selected (``models/lfm2moe.py`` has the equations).  A chip of an
+selected (:func:`route`; ``models/lfm2moe.py`` has the equations); or, by
+the second rule (:func:`route_softmax`, ``models/keyevl2.py``), with a
+softmax over all ``E``, the top ``k`` of it renormalised over the ``k``.  A chip of an
 expert-parallel deployment holds ``held = (first, count)`` of the experts:
 it routes over all ``E`` and computes its own experts' part of the sum,
 
@@ -64,7 +66,7 @@ from jax import lax
 from .flash_attention import resolve_interpret
 
 __all__ = ["Routing", "expert_ffn", "moe_census", "moe_impl", "route",
-           "routing_counts"]
+           "route_softmax", "routing_counts"]
 
 # rows a grid cell of the grouped-product kernels takes, and its tiles of
 # the contracted and the output dimension (a v5e, the cell's shapes: PERF.md
@@ -96,6 +98,16 @@ def route(logits, bias, k: int, scale: float = 1.0,
     picked = jnp.take_along_axis(s, sel, axis=-1)
     weight = picked / (jnp.sum(picked, -1, keepdims=True) + norm_eps) * scale
     return Routing(sel.astype(jnp.int32), weight)
+
+
+def route_softmax(logits, k: int) -> Routing:
+    """The second rule: ``r = softmax(logits)`` over all ``E`` experts, the
+    top ``k`` of ``r`` selected, weighing ``r / (sum of the selected r)``;
+    no bias, no scale.  All float32."""
+    r = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    picked, sel = lax.top_k(r, k)
+    return Routing(sel.astype(jnp.int32),
+                   picked / jnp.sum(picked, -1, keepdims=True))
 
 
 def _held_key(sel, held: Tuple[int, int]):

@@ -63,6 +63,11 @@ def _clip_grads(grads, clip_grad: Optional[float]):
     return jax.tree.map(lambda g: g * scale, grads)
 
 
+# what a sequence model's layers may sow for the step's metrics (and, for
+# ``aux_loss``, its objective): train/trainer.py drains each by this name
+_SOWN = ("moe_counts", "dsa_counts", "aux_loss")
+
+
 def make_train_step(model, tx: optax.GradientTransformation,
                     loss_fn: Callable = cross_entropy,
                     mesh: Optional[Mesh] = None, axis: Optional[str] = None,
@@ -115,22 +120,35 @@ def make_train_step(model, tx: optax.GradientTransformation,
     sequence_task = bool(getattr(model, "sequence_task", False))
 
     def forward_backward_one(params, batch_stats, x, y, rng):
-        """(loss, grads, new batch stats, prec1, routing counts).  The last
-        is what the model's layers sowed into ``moe_counts``
-        (ops/moe.py:routing_counts), summed over the layers; None for a
-        model that sows none."""
+        """(loss, grads, new batch stats, prec1, sown).  The last holds, by
+        collection, what the model's layers sowed, each summed over the
+        layers: ``moe_counts`` (ops/moe.py:routing_counts), ``dsa_counts``
+        (ops/sparse_attention.py's census) and ``aux_loss`` (a loss of the
+        model's own, e.g. the sparse-attention indexer's, which the
+        gradient minimises beside ``loss``); None for a model that sows
+        none."""
         if sequence_task:
             def seq_lossf(p):
                 (loss, acc), mut = model.apply(
                     {"params": p, "batch_stats": batch_stats}, x, y,
-                    training=True, mutable=["batch_stats", "moe_counts"],
+                    training=True, mutable=["batch_stats", *_SOWN],
                     rngs={"dropout": rng}, method="sequence_loss")
-                sown = jax.tree.leaves(mut.get("moe_counts", {}))
-                return loss, (acc, mut.get("batch_stats", batch_stats),
-                              sum(sown) if sown else None)
-            (loss, (acc, new_stats, counts)), grads = jax.value_and_grad(
-                seq_lossf, has_aux=True)(params)
-            return loss, grads, new_stats, acc, counts
+                sown = {}
+                for name in _SOWN:
+                    leaves = jax.tree.leaves(mut.get(name, {}))
+                    if leaves:
+                        sown[name] = sum(leaves)
+                if "aux_loss" not in sown:
+                    return loss, (acc, mut.get("batch_stats", batch_stats),
+                                  sown or None, None)
+                # the objective is the sum; the metric stays the
+                # next-token loss
+                return loss + sown["aux_loss"], (
+                    acc, mut.get("batch_stats", batch_stats), sown, loss)
+            (loss, (acc, new_stats, sown, lm_loss)), grads = \
+                jax.value_and_grad(seq_lossf, has_aux=True)(params)
+            return (loss if lm_loss is None else lm_loss), grads, \
+                new_stats, acc, sown
 
         def lossf(p):
             variables = {"params": p, "batch_stats": batch_stats}
@@ -172,8 +190,10 @@ def make_train_step(model, tx: optax.GradientTransformation,
             micro, (batch_stats, g0, z, z), (xm, ym, jnp.arange(grad_accum)))
         inv = 1.0 / grad_accum
         grads = jax.tree.map(lambda g: g * inv, gsum)
-        if counts is not None:         # counts add over the microbatches
-            counts = jnp.sum(counts, axis=0)
+        if counts is not None:         # counts add over the microbatches,
+            counts = {k: jnp.sum(v, axis=0) for k, v in counts.items()}
+            if "aux_loss" in counts:       # ... and a loss is averaged
+                counts["aux_loss"] = counts["aux_loss"] * inv
         return lsum * inv, grads, new_stats, psum_ * inv, counts
 
     def apply_updates(state: TrainState, grads, new_stats, loss, prec1,
@@ -191,7 +211,7 @@ def make_train_step(model, tx: optax.GradientTransformation,
         metrics = {"loss": loss, "prec1": prec1}
         if counts is not None:
             # made on the device, fetched with the loss at the drain
-            metrics["moe_counts"] = counts
+            metrics.update(counts)
         if nonfinite_guard:
             # the clipped-grad norm: clipping rescales by a finite factor
             # (or NaN-propagates), so finiteness is unchanged vs raw grads

@@ -163,6 +163,7 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
         t_drain = time.monotonic()
         window_bad = 0
         routing = None         # a routed model's counts, summed on the way
+        sparse, kl = None, []  # a sparse-attention model's census and loss
         for m, n, step_i in pending:
             loss_value = float(m["loss"])     # host sync, log steps only
             # the device-side guard flag (loss OR grad-norm non-finite)
@@ -184,6 +185,10 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
             if "moe_counts" in m:
                 counts = np.asarray(m["moe_counts"], np.int64)
                 routing = counts if routing is None else routing + counts
+            if "dsa_counts" in m:
+                counts = np.asarray(m["dsa_counts"], np.int64)
+                sparse = counts if sparse is None else sparse + counts
+                kl.append(float(m["aux_loss"]))
             if resilience is not None:
                 # may raise RewindRequested after K consecutive bad steps
                 resilience.observe_step(step_i, loss_value, bad)
@@ -196,6 +201,9 @@ def train_one_epoch(epoch: int, train_step: Callable, state: TrainState,
         drain_bad_acc += window_bad
         if routing is not None and telemetry is not None:
             telemetry.on_routing(*(int(c) for c in routing))
+        if sparse is not None and telemetry is not None:
+            telemetry.on_sparse_attention(*(int(c) for c in sparse),
+                                          kl_loss=float(np.mean(kl)))
 
     def _snapshot(batch_idx: int, sync: bool = False) -> None:
         nonlocal save_row

@@ -461,3 +461,111 @@ def test_glm47flash_step_fits_the_chip(one_chip, monkeypatch, tmp_path):
     # forward's output and row statistics, so it does not run again)
     assert mosaic("attn_latent") == 2 * 5
     assert mosaic("moe_experts") > 0
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_sparse_attention_keye_32k_compiles(one_chip, grad):
+    """Keye-VL-2.0's learned sparse attention at 32,768 tokens: the
+    selection kernel (a (32,768, 128) int32 row of keys in VMEM beside the
+    indexer key's whole row), then the attention of 32 query heads of 128
+    over 4 key heads with the indexer's loss: forward and loss kernels, and
+    the dK/dV and dQ kernels of the backward, each making the indexer's 16
+    heads of 64 again a tile."""
+    import importlib
+    SA = importlib.import_module("deepfake_detection_tpu.ops.sparse_attention")
+    spec = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dt, sharding=one_chip)
+    l = 32768
+    q, k = spec(1, l, 32, 128), spec(1, l, 4, 128)
+    qi, ki, w = spec(1, l, 16, 64), spec(1, l, 64), spec(1, l, 16,
+                                                        dt=jnp.float32)
+    ints = spec(1, l, dt=jnp.int32)
+
+    def attend(q, k, v, qi, ki, w, thr, cut):
+        o, kl = SA.sparse_attention(q, k, v, qi, ki, w,
+                                    SA.Selection(thr, cut, None),
+                                    scale=128 ** -0.5, impl="pallas",
+                                    interpret=False)
+        return o.astype(jnp.float32).sum() + kl.sum()
+    if grad:
+        compiled = _compile(jax.grad(attend, argnums=range(6)), q, k, k, qi,
+                            ki, w, ints, ints)
+        # dK/dV and dQ, with the forward whose output the backward reads
+        assert compiled.as_text().count("tpu_custom_call") == 3
+    else:
+        compiled = _compile(attend, q, k, k, qi, ki, w, ints, ints)
+        assert compiled.as_text().count("tpu_custom_call") == 2
+        _compile(lambda qi, ki, w: SA.select_keys(
+            qi, ki, w, 2048, impl="pallas", interpret=False), qi, ki, w)
+
+
+def test_keyevl2_step_fits_the_chip(one_chip, monkeypatch, tmp_path):
+    """The runner's whole train step of ``train_keye_dsa_32k`` (the
+    configuration's own flags: one 32,768-token row), every kernel compiled
+    as on the chip: the bytes the buffer assignment allocates within 15.2
+    GB (15.04 GB when written: 7.45 GB of state less the gradients'
+    buffers, and the full-capacity branch of the expert layer's switch,
+    2.15 GB, at the peak), the sparse-attention kernels of all four layers
+    (forward, loss, dK/dV, dQ under ``attn_sparse``; the selection under
+    ``dsa_select``: remat keeps the selection, the output and the row
+    statistics, so neither forward kernel runs again) and the grouped
+    products in Mosaic."""
+    import functools
+    import importlib
+    import json
+    import re
+
+    from deepfake_detection_tpu.config import TrainConfig
+    from deepfake_detection_tpu.models import init_model
+    from deepfake_detection_tpu.ops import moe
+    from deepfake_detection_tpu.parallel import (batch_sharding,
+                                                 make_train_mesh,
+                                                 replicated_sharding,
+                                                 train_state_shardings)
+    from deepfake_detection_tpu.runners import train as T
+    from deepfake_detection_tpu.train import create_train_state
+    SA = importlib.import_module("deepfake_detection_tpu.ops.sparse_attention")
+    for name in ("flash_attention", "moe", "sparse_attention"):
+        monkeypatch.setattr(
+            importlib.import_module("deepfake_detection_tpu.ops." + name),
+            "resolve_interpret", lambda interpret, kernel: False)
+    monkeypatch.setattr(moe, "moe_impl",
+                        functools.partial(moe.moe_impl, backend="tpu"))
+    monkeypatch.setattr(SA, "sparse_impl",
+                        functools.partial(SA.sparse_impl, backend="tpu"))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "keye_vl2_30b_a3b_4l.json")) as f:
+        flags = json.load(f)["train_flags"]
+    mesh = make_train_mesh(batch=1, model=1,
+                           devices=list(one_chip.device_set))
+    program = T.build_program(TrainConfig.from_args(flags), mesh=mesh)
+    assert program.moe_layers == (4, 0) and program.dsa_layers == 4
+    state = jax.eval_shape(lambda: create_train_state(init_model(
+        program.model, jax.random.PRNGKey(0), (1, 8), training=True,
+        dtype=jnp.int32), program.tx))
+    shardings = train_state_shardings(state, mesh, fsdp=False,
+                                      axis=program.batch_axis)
+    step = T.build_steps(program, shardings)[0]
+    ids = jax.ShapeDtypeStruct((program.global_batch, 32768), jnp.int32,
+                               sharding=batch_sharding(mesh))
+    key = jax.random.PRNGKey(0)
+    compiled = step.lower(
+        jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=s), state, shardings), ids, ids,
+        jax.ShapeDtypeStruct(key.shape, key.dtype,
+                             sharding=replicated_sharding(mesh))).compile(
+        compiler_options={"xla_dump_to": str(tmp_path)})
+    reports = list(tmp_path.glob("*jit_step*memory-usage-report.txt"))
+    assert len(reports) == 1, reports
+    used = re.match(r"Total bytes used: (\d+)",
+                    reports[0].read_text()).group(1)
+    assert 13.0e9 < int(used) <= 15.2e9, used
+    lines = compiled.as_text().splitlines()
+    mosaic = lambda scope: sum(                              # noqa: E731
+        1 for line in lines if "tpu_custom_call" in line
+        and re.search(r'op_name="[^"]*' + scope, line))
+    assert mosaic("attn_sparse") == 4 * 4
+    assert mosaic("dsa_kl") == 4
+    assert mosaic("dsa_select") == 4
+    assert mosaic("moe_experts") > 0

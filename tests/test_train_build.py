@@ -46,6 +46,8 @@ CUTS = {
                          "--grad-accum", "1"], {"train": {"seq_len": 64}}),
     "glm47_flash_5l": (["--model", "glm47_flash_tiny", "--seq-len", "64",
                         "--grad-accum", "1"], {"train": {"seq_len": 64}}),
+    "keye_vl2_30b_a3b_4l": (["--model", "keye_vl2_tiny", "--seq-len", "64"],
+                            {"train": {"seq_len": 64}}),
 }
 
 
@@ -199,8 +201,12 @@ def test_telemetry_is_the_runners(built, tmp_path):
     gflops = snap["gauges"]["model_fwd_gflops_per_sample"]
     if p.sequence_task:
         assert gflops == 0.0                        # ROADMAP D13
+        # the sparse-attention kernels run on a TPU only (the array form
+        # here visits no tile); every other sequence model's kernels run
+        # interpreted
         assert telemetry.attn_tiles_per_sample == \
-            p.model.attn_tiles_visited(p.cfg.seq_len) > 0
+            p.model.attn_tiles_visited(p.cfg.seq_len)
+        assert (telemetry.attn_tiles_per_sample > 0) == (p.dsa_layers == 0)
     else:
         assert gflops > 0.0
         assert telemetry.attn_tiles_per_sample == 0
@@ -226,7 +232,9 @@ def test_telemetry_is_the_runners(built, tmp_path):
         if hasattr(p.model, "attn_bwd_layers") else (0, 0))
     assert (snap["gauges"]["attn_fused_bwd_layers"],
             snap["gauges"]["attn_split_bwd_layers"]) == p.attn_bwd_layers
-    assert (p.attn_bwd_layers[0] > 0) == p.sequence_task
+    # (a model of learned sparse attention has no flash layer)
+    assert (p.attn_bwd_layers[0] > 0) == (p.sequence_task
+                                          and p.dsa_layers == 0)
     assert p.attn_bwd_layers[1] == 0
     assert "dfd_train_attn_fused_bwd_layers" in telemetry.render_prometheus()
     # the saved-forward census: every flash layer of a sequence model
@@ -240,6 +248,10 @@ def test_telemetry_is_the_runners(built, tmp_path):
     # the latent-attention census: every layer of the GLM stack, no other
     assert snap["gauges"]["mla_layers"] == p.mla_layers == (
         p.model.mla_layers() if hasattr(p.model, "mla_layers") else 0)
+    # the learned-sparse-attention census: every layer of the Keye stack
+    assert snap["gauges"]["dsa_layers"] == p.dsa_layers == (
+        p.model.dsa_layers() if hasattr(p.model, "dsa_layers") else 0)
+    assert "dfd_train_dsa_layers" in telemetry.render_prometheus()
     assert os.path.isfile(tmp_path / "telemetry.jsonl")
 
 
