@@ -252,8 +252,10 @@ class KeyeVL2(nn.Module):
 
     def attn_tiles_visited(self, seq_len: int) -> int:
         """Grid cells the sparse-attention kernels visit in one train step
-        over one row of ``seq_len`` tokens (a cell runs every head); 0
-        where the array form runs."""
+        over one row of ``seq_len`` tokens (a cell runs every head): the
+        forward's, the loss's and the one backward's, and the selection's
+        query tiles (``ops/sparse_attention.py:train_cells``); 0 where the
+        array form runs."""
         impl = self.dsa_impl or (None if self.attn_impl == "flash" else "xla")
         if (impl or dsa_ops.sparse_impl(seq_len, self.head_dim)) != "pallas":
             return 0

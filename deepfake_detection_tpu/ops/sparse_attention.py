@@ -56,11 +56,15 @@ the one mask it made.
   online log-sum-exp of the selected index scores.
 * ``_kl_kernel``, same grid: ``p`` from the finished ``lse`` of every head,
   ``kl`` a query.
-* ``_dkv_kernel``, grid ``(B, L / 512, L / 128)``: dK, dV (FlashAttention-2,
-  ``ds = p (dp - delta)``) and the indexer key's gradient from ``dI =
-  g_kl (softmax(I) - p)``.
-* ``_dq_kernel``, grid ``(B, L / 128, L / 512)``: dQ, and the indexer
-  queries' and weights' gradients.
+* ``_bwd_kernel``, grid ``(B, L / C, L / 128, C / 512)`` over key chunks
+  of ``C`` keys (:func:`_chunk`: 4,096 at 32,768): the whole backward, each
+  cell's mask, ``p``, ``dp`` and ``ds = p (dp - delta)`` (FlashAttention-2)
+  and ``dI = g_kl (softmax(I) - p)`` made once for dQ, dK, dV and the
+  indexer's three gradients, the index heads' ``relu(x)`` kept from the
+  mask's pass in a VMEM scratch ``(J, 512, 128)``.  The chunk's dK, dV and
+  dKI stay in VMEM across the query tiles; dQ, dQI and dW of a query tile
+  are carried from chunk to chunk through HBM, outputs aliased to zeroed
+  inputs.
 
 Cells wholly above the diagonal are skipped and their index maps clamped,
 so nothing is copied for them.  Off the TPU the op takes the array form
@@ -95,6 +99,9 @@ _LANES = 128
 # the selection keeps a (L, 128) int32 row of keys in VMEM: 16 MiB at 32,768
 # positions beside the key head's whole row; a v5e core has 128 MiB
 _VMEM_LIMIT = 64 * 1024 * 1024
+# the backward's key chunk: its float32 dK, dV and dKI, resident in VMEM
+# single-buffered beside the tiles' double-buffered blocks
+_CHUNK_BYTES = 24 * 1024 * 1024
 _INT_MIN = -2 ** 31
 # ``cut`` where every tie at ``thr`` is taken
 _ALL = 2 ** 30
@@ -136,12 +143,12 @@ def blocks_causal(l: int) -> int:
 
 def train_cells(l: int) -> int:
     """Grid cells with a causal pair that one train step's kernels visit
-    over a row of ``l``: the forward's, the loss's, dK/dV's and dQ's (the
-    four grids hold the same cells; under remat the saved residuals keep the
-    first two from running again) and the selection's query tiles."""
+    over a row of ``l``: the forward's, the loss's and the backward's (the
+    three grids hold the same cells; under remat the saved residuals keep
+    the first two from running again) and the selection's query tiles."""
     bq, bk = BLOCK_Q, min(BLOCK_K, l)
     cells = sum(_last_k(i, bq, bk) + 1 for i in range(l // bq))
-    return 4 * cells + l // bq
+    return 3 * cells + l // bq
 
 
 def sparse_impl(l: int, d: int, backend: Optional[str] = None) -> str:
@@ -189,13 +196,17 @@ def _term(w, r):
     return wh * rh + (wh * (r - rh) + (w - wh) * rh)
 
 
-def _index_t(ki, qi_ref, w):
+def _index_t(ki, qi_ref, w, keep=None):
     """The index scores of a cell, keys by queries (bk, bq) float32: head by
     head in order, an exact zero made +0.  ``ki`` (bk, E), ``qi_ref[0, j]``
-    (bq, E), ``w`` (J, bq)."""
+    (bq, E), ``w`` (J, bq); ``keep[j]``, where given, takes head ``j``'s
+    ``relu(qi_j . ki)``."""
     acc = None
     for j in range(w.shape[0]):
-        t = _term(w[j:j + 1, :], _relu(_relu_scores(ki, qi_ref[0, j])))
+        r = _relu(_relu_scores(ki, qi_ref[0, j]))
+        if keep is not None:
+            keep[j] = r
+        t = _term(w[j:j + 1, :], r)
         acc = t if acc is None else acc + t
     return jnp.where(acc == 0.0, 0.0, acc)
 
@@ -206,10 +217,10 @@ def _positions(s0, t0, bk, bq):
     return s, t
 
 
-def _cell_mask(ki_ref, qi_ref, w_ref, thr_ref, cut_ref, s0, t0):
+def _cell_mask(ki_ref, qi_ref, w_ref, thr_ref, cut_ref, s0, t0, keep=None):
     """(index scores, selection) of a cell, both (bk, bq)."""
     w = w_ref[0]
-    index = _index_t(ki_ref[0], qi_ref, w)
+    index = _index_t(ki_ref[0], qi_ref, w, keep)
     bk, bq = index.shape
     s, t = _positions(s0, t0, bk, bq)
     return index, _rule(order_key(index), thr_ref[0], cut_ref[0], s, t)
@@ -521,95 +532,80 @@ def _index_grad(index, sel, p, ilse, gkl):
     return jnp.where(sel, gkl * (jnp.exp(index - ilse) - p), 0.0)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, thr_ref, cut_ref,
-                do_ref, lse_ref, delta_ref, ilse_ref, gkl_ref,
-                dk_ref, dv_ref, dki_ref, dk_acc, dv_acc, dki_acc, *, scale):
+def _bwd_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, thr_ref, cut_ref,
+                do_ref, lse_ref, delta_ref, ilse_ref, gkl_ref, dq_in, dqi_in,
+                dw_in, dq_ref, dqi_ref, dw_ref, dk_ref, dv_ref, dki_ref,
+                relu_x, *, scale, chunk):
+    """One (row, key chunk, query tile, key tile of the chunk) cell of the
+    whole backward: the cell's mask, ``p``, ``dp`` and ``ds`` of every head
+    made once for all six gradients, the index heads' ``relu(x)`` kept in
+    ``relu_x`` from the mask's pass.  dK, dV and dKI of the chunk are output
+    blocks named by the chunk alone, resident across both inner axes; dQ,
+    dQI and dW of the query tile are named by the tile and carry the earlier
+    chunks' sums in (``*_in``, aliased to them), so each gradient sums in
+    the order the two-kernel form summed it: key tiles ascending for a
+    query, query tiles ascending for a key, heads in order."""
     bq, bk = q_ref.shape[2], k_ref.shape[2]
-    j, i = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    c, i, jl = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    j = c * (chunk // bk) + jl
 
-    @pl.when(i == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-        dki_acc[...] = jnp.zeros_like(dki_acc)
-
-    @pl.when(i >= _first_q(j, bq, bk))
-    def _accumulate():
-        index, sel = _cell_mask(ki_ref, qi_ref, w_ref, thr_ref, cut_ref,
-                                j * bk, i * bq)
-        cd = q_ref.dtype
-
-        def head(h, g, p):
-            dp = lax.dot_general(v_ref[0, g], do_ref[0, h],
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_ref[0, h:h + 1, :]) * scale
-            dv_acc[g] += jnp.dot(p.astype(cd), do_ref[0, h],
-                                 preferred_element_type=jnp.float32)
-            dk_acc[g] += jnp.dot(ds.astype(cd), q_ref[0, h],
-                                 preferred_element_type=jnp.float32)
-        p = _heads_mean_p(q_ref, k_ref, lse_ref, sel, scale, head)
-        di = _index_grad(index, sel, p, ilse_ref[0], gkl_ref[0])
-        w = w_ref[0]
-        ki = ki_ref[0]
-        for jj in range(w.shape[0]):
-            x = _relu_scores(ki, qi_ref[0, jj])
-            gj = jnp.where(_relu_on(x), di * w[jj:jj + 1, :], 0.0)
-            dki_acc[...] += jnp.dot(gj.astype(ki.dtype), qi_ref[0, jj],
-                                    preferred_element_type=jnp.float32)
-
-    @pl.when(i == nq - 1)
-    def _finalize():
-        dk_ref[0] = dk_acc[...]
-        dv_ref[0] = dv_acc[...]
-        dki_ref[0] = dki_acc[...]
-
-
-def _dq_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, thr_ref, cut_ref,
-               do_ref, lse_ref, delta_ref, ilse_ref, gkl_ref,
-               dq_ref, dqi_ref, dw_ref, dq_acc, dqi_acc, dw_acc, *, scale):
-    bq, bk = q_ref.shape[2], k_ref.shape[2]
-    i, j = pl.program_id(1), pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-        dqi_acc[...] = jnp.zeros_like(dqi_acc)
-        dw_acc[...] = jnp.zeros_like(dw_acc)
+    @pl.when((i == 0) & (jl == 0))
+    def _init_chunk():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+        dki_ref[...] = jnp.zeros_like(dki_ref)
 
     @pl.when(j <= _last_k(i, bq, bk))
     def _accumulate():
+        # the tile's first visible cell of the chunk: the sums so far in
+        @pl.when(jl == 0)
+        def _carry():
+            dq_ref[...] = dq_in[...]
+            dqi_ref[...] = dqi_in[...]
+            dw_ref[...] = dw_in[...]
+
         index, sel = _cell_mask(ki_ref, qi_ref, w_ref, thr_ref, cut_ref,
-                                j * bk, i * bq)
+                                j * bk, i * bq, relu_x)
         cd = q_ref.dtype
+        rows = pl.ds(pl.multiple_of(jl * bk, bk), bk)
 
         def head(h, g, p):
             dp = lax.dot_general(v_ref[0, g], do_ref[0, h],
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_ref[0, h:h + 1, :]) * scale
-            dq_acc[h] += lax.dot_general(
-                k_ref[0, g], ds.astype(cd), (((0,), (0,)), ((), ())),
+            ds = (p * (dp - delta_ref[0, h:h + 1, :]) * scale).astype(cd)
+            dv_ref[0, g, rows] += jnp.dot(p.astype(cd), do_ref[0, h],
+                                          preferred_element_type=jnp.float32)
+            dk_ref[0, g, rows] += jnp.dot(ds, q_ref[0, h],
+                                          preferred_element_type=jnp.float32)
+            dq_ref[0, h] += lax.dot_general(
+                k_ref[0, g], ds, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
         p = _heads_mean_p(q_ref, k_ref, lse_ref, sel, scale, head)
         di = _index_grad(index, sel, p, ilse_ref[0], gkl_ref[0])
         w = w_ref[0]
         ki = ki_ref[0]
         for jj in range(w.shape[0]):
-            x = _relu_scores(ki, qi_ref[0, jj])
-            gj = jnp.where(_relu_on(x), di * w[jj:jj + 1, :], 0.0)
-            dqi_acc[jj] += lax.dot_general(
-                ki, gj.astype(ki.dtype), (((0,), (0,)), ((), ())),
+            r = relu_x[jj]
+            gj = jnp.where(_relu_on(r), di * w[jj:jj + 1, :], 0.0).astype(
+                ki.dtype)
+            dki_ref[0, rows] += jnp.dot(gj, qi_ref[0, jj],
+                                        preferred_element_type=jnp.float32)
+            dqi_ref[0, jj] += lax.dot_general(
+                ki, gj, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            dw_acc[jj:jj + 1, :] += jnp.sum(di * _relu(x), axis=0,
-                                            keepdims=True)
+            dw_ref[0, jj:jj + 1, :] += jnp.sum(di * r, axis=0, keepdims=True)
 
-    @pl.when(j == _last_k(i, bq, bk))
-    def _finalize():
-        dq_ref[0] = dq_acc[...]
-        dqi_ref[0] = dqi_acc[...]
-        dw_ref[0] = dw_acc[...]
+
+def _chunk(l, bk, nkv, d, e):
+    """Keys a key chunk of the backward spans: the most key tiles, a power
+    of two dividing ``l``, whose float32 dK, dV and dKI fit
+    ``_CHUNK_BYTES``."""
+    per_key = 4 * (2 * nkv * d + e)
+    c = bk
+    while l % (2 * c) == 0 and 2 * c * per_key <= _CHUNK_BYTES:
+        c *= 2
+    return c
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -618,67 +614,72 @@ def _bwd_pallas(res, do, gkl, scale, interpret):
     b, nh, l, d = q.shape
     nkv, nj, e = k.shape[1], qi.shape[1], qi.shape[3]
     bq, bk = BLOCK_Q, min(BLOCK_K, l)
+    chunk = _chunk(l, bk, nkv, d, e)
+    nc = chunk // bk
     # o and do in the kernels' (B, H, L, D); delta = rowsum(do * o)
     delta = jnp.sum(do.astype(jnp.float32) * jnp.swapaxes(
         o, 2, 3).astype(jnp.float32), axis=-1)
     gkl = gkl.astype(jnp.float32)[:, None, :]
-    args = (q, k, v, qi, ki, w, thr, cut, do, lse, delta, ilse, gkl)
-    tail = [_row_spec(nh, bq), _row_spec(nh, bq), _row_spec(1, bq),
-            _row_spec(1, bq)]
+    dq, dqi, dw = (jnp.zeros((b, nh, d, l), jnp.float32),
+                   jnp.zeros((b, nj, e, l), jnp.float32),
+                   jnp.zeros((b, nj, l), jnp.float32))
 
-    # (B, key tile, query tile): query tiles before the diagonal name the
-    # first visible one
-    def qmap4(b_, j, i):
-        return (b_, 0, jnp.maximum(i, _first_q(j, bq, bk)), 0)
+    # (B, key chunk, query tile, key tile of the chunk): query tiles wholly
+    # before the chunk name its first visible one, key tiles past the
+    # diagonal the last visible one, so nothing is copied or written back
+    # for them
+    def qt(c, i):
+        return jnp.maximum(i, _first_q(c * nc, bq, bk))
 
-    def qmap3(b_, j, i):
-        return (b_, 0, jnp.maximum(i, _first_q(j, bq, bk)))
-    dkv_specs = [_spec((1, nh, bq, d), qmap4),
-                 _spec((1, nkv, bk, d), lambda b_, j, i: (b_, 0, j, 0)),
-                 _spec((1, nkv, bk, d), lambda b_, j, i: (b_, 0, j, 0)),
-                 _spec((1, nj, bq, e), qmap4),
-                 _spec((1, bk, e), lambda b_, j, i: (b_, j, 0)),
-                 _spec((1, nj, bq), qmap3),
-                 _spec((1, 1, bq), qmap3),
-                 _spec((1, 1, bq), qmap3),
-                 _spec((1, nh, bq, d), qmap4),
-                 _spec((1, nh, bq), qmap3),
-                 _spec((1, nh, bq), qmap3),
-                 _spec((1, 1, bq), qmap3),
-                 _spec((1, 1, bq), qmap3)]
-    dk, dv, dki = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale),
-        grid=(b, l // bk, l // bq),
-        in_specs=dkv_specs,
-        out_specs=[_spec((1, nkv, bk, d), lambda b_, j, i: (b_, 0, j, 0)),
-                   _spec((1, nkv, bk, d), lambda b_, j, i: (b_, 0, j, 0)),
-                   _spec((1, bk, e), lambda b_, j, i: (b_, j, 0))],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(v.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(ki.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((nkv, bk, d), jnp.float32),
-                        pltpu.VMEM((nkv, bk, d), jnp.float32),
-                        pltpu.VMEM((bk, e), jnp.float32)],
+    def kt(c, i, jl):
+        return jnp.minimum(c * nc + jl, _last_k(qt(c, i), bq, bk))
+
+    def q4(b_, c, i, jl):
+        return (b_, 0, qt(c, i), 0)
+
+    def q3(b_, c, i, jl):
+        return (b_, 0, qt(c, i))
+
+    def qt4(b_, c, i, jl):
+        return (b_, 0, 0, qt(c, i))
+
+    def k4(b_, c, i, jl):
+        return (b_, 0, kt(c, i, jl), 0)
+
+    def once(block, index_map):
+        # resident across both inner axes: written back once a chunk
+        return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM,
+                            pipeline_mode=pl.Buffered(1))
+    carried = [_spec((1, nh, d, bq), qt4), _spec((1, nj, e, bq), qt4),
+               _spec((1, nj, bq), q3)]
+    in_specs = [_spec((1, nh, bq, d), q4),                        # q
+                _spec((1, nkv, bk, d), k4),                       # k
+                _spec((1, nkv, bk, d), k4),                       # v
+                _spec((1, nj, bq, e), q4),                        # qi
+                _spec((1, bk, e), lambda b_, c, i, jl: (b_, kt(c, i, jl), 0)),
+                _spec((1, nj, bq), q3),                           # w
+                _spec((1, 1, bq), q3), _spec((1, 1, bq), q3),     # thr, cut
+                _spec((1, nh, bq, d), q4),                        # do
+                _spec((1, nh, bq), q3), _spec((1, nh, bq), q3),   # lse, delta
+                _spec((1, 1, bq), q3), _spec((1, 1, bq), q3)]     # ilse, gkl
+    n_in = len(in_specs)
+    dq, dqi, dw, dk, dv, dki = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, chunk=chunk),
+        grid=(b, l // chunk, l // bq, nc),
+        in_specs=in_specs + carried,
+        out_specs=carried + [
+            once((1, nkv, chunk, d), lambda b_, c, i, jl: (b_, 0, c, 0)),
+            once((1, nkv, chunk, d), lambda b_, c, i, jl: (b_, 0, c, 0)),
+            once((1, chunk, e), lambda b_, c, i, jl: (b_, c, 0))],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, jnp.float32)
+                   for x in (dq, dqi, dw, k, v, ki)],
+        input_output_aliases={n_in: 0, n_in + 1: 1, n_in + 2: 2},
+        scratch_shapes=[pltpu.VMEM((nj, bk, bq), jnp.float32)],
         compiler_params=_params(),
-        interpret=interpret,
-    )(*args)
-    dq, dqi, dw = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale),
-        grid=(b, l // bq, l // bk),
-        in_specs=_q_grid_specs(nh, nkv, nj, d, e, bq, bk) + [
-            _spec((1, nh, bq, d), lambda b_, i, j: (b_, 0, i, 0))] + tail,
-        out_specs=[_spec((1, nh, d, bq), lambda b_, i, j: (b_, 0, 0, i)),
-                   _spec((1, nj, e, bq), lambda b_, i, j: (b_, 0, 0, i)),
-                   _row_spec(nj, bq)],
-        out_shape=[jax.ShapeDtypeStruct((b, nh, d, l), jnp.float32),
-                   jax.ShapeDtypeStruct((b, nj, e, l), jnp.float32),
-                   jax.ShapeDtypeStruct((b, nj, l), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((nh, d, bq), jnp.float32),
-                        pltpu.VMEM((nj, e, bq), jnp.float32),
-                        pltpu.VMEM((nj, bq), jnp.float32)],
-        compiler_params=_params(),
-        interpret=interpret,
-    )(*args)
+        # the TPU interpreter, whose aliased input and output share one
+        # buffer as on the chip (the plain interpreter copies the input)
+        interpret=pltpu.InterpretParams() if interpret is True else interpret,
+    )(q, k, v, qi, ki, w, thr, cut, do, lse, delta, ilse, gkl, dq, dqi, dw)
     return dq, dk, dv, dqi, dki, dw
 
 

@@ -24,6 +24,7 @@ see the same selection, and their selections are held equal bit for bit on
 index scores that are exact in float32 (small integers), ties and all.
 """
 
+import functools
 import importlib
 import json
 import os
@@ -350,6 +351,60 @@ def test_the_kernels_interpreted_equal_the_array_form():
     assert _rel(op, oa) < 1e-5 and _rel(klp, kla) < 1e-5
     for name, a, b in zip(("q", "k", "v", "qi", "ki", "w"), gp, ga):
         assert _rel(a, b) < 1e-5, name
+
+
+def test_the_backward_carries_its_query_sums_across_key_chunks(monkeypatch):
+    """The one backward kernel over a row its key chunks split in three (a
+    budget cut so that the tiny heads' chunk is 1,024 keys, two key tiles):
+    every gradient against the array form on exact index scores, ties and
+    the tie cut included.  Query tiles wholly
+    before a chunk write nothing back, and each tile's dQ, dQI and dW carry
+    the earlier chunks' sums, under the interpreter that gives the aliased
+    input and output one buffer and leaves an output block unset until the
+    kernel writes it, as the chip does: a stale or unset block written back
+    moves a gradient."""
+    l, chunk = 3072, 1024
+    per_key = 4 * (2 * 2 * 16 + SA._LANES)
+    monkeypatch.setattr(SA, "_CHUNK_BYTES", chunk * per_key)
+    assert SA._chunk(l, SA.BLOCK_K, 2, 16, SA._LANES) == chunk == l // 3
+    q, k, v, _, _, _ = _op_inputs(6, l=l)
+    _, _, _, qi, ki, w = _op_inputs(7, l=l, integer=True)
+    sel = SA.select_keys(qi, ki, w, 40, impl="xla")
+    assert int((sel.cut < SA._ALL).sum()) > 0          # a tie cut taken
+
+    def grads(impl):
+        def loss(*a):
+            o, kl = SA.sparse_attention(*a, sel, impl=impl, interpret=True)
+            return jnp.sum(o * jnp.cos(o)) + jnp.sum(
+                kl * jnp.arange(l)) / 100
+        with jax.default_matmul_precision("highest"):
+            return jax.grad(loss, argnums=range(6))(q, k, v, qi, ki, w)
+    for name, a, b in zip(("q", "k", "v", "qi", "ki", "w"), grads("pallas"),
+                          grads("xla")):
+        assert _rel(a, b) < 1e-5, name
+
+
+@pytest.mark.parametrize("l", [4096, 32768])
+def test_the_backward_chunk_divides_the_row_and_fits_its_budget(l):
+    """At the cell's widths (4 key heads of 128, the indexer's key on 128
+    lanes) the key chunk is whole key tiles that divide the row, and its
+    float32 dK, dV and dKI, held once, fit the launch's budget."""
+    chunk = SA._chunk(l, SA.BLOCK_K, 4, 128, SA._LANES)
+    assert l % chunk == 0 and chunk % SA.BLOCK_K == 0
+    assert chunk == 4096
+    assert chunk * 4 * (2 * 4 * 128 + SA._LANES) <= SA._CHUNK_BYTES \
+        <= SA._VMEM_LIMIT // 2
+
+
+def test_the_census_counts_three_kernel_grids_a_layer(monkeypatch):
+    """A layer's step at 32,768 visits 8,320 causal cells on each of the
+    forward's, the loss's and the backward's grids, and 256 query tiles of
+    the selection; the cut's four layers 100,864 a row."""
+    assert SA.train_cells(32768) == 3 * 8320 + 256 == 25216
+    monkeypatch.setattr(SA, "sparse_impl", functools.partial(
+        SA.sparse_impl, backend="tpu"))
+    model = create_model("keye_vl2_30b_a3b_4l")
+    assert model.attn_tiles_visited(32768) == 4 * 25216 == 100864
 
 
 # ---- the holds: each loss trains its own parameters ------------------------

@@ -469,8 +469,9 @@ def test_sparse_attention_keye_32k_compiles(one_chip, grad):
     selection kernel (a (32,768, 128) int32 row of keys in VMEM beside the
     indexer key's whole row), then the attention of 32 query heads of 128
     over 4 key heads with the indexer's loss: forward and loss kernels, and
-    the dK/dV and dQ kernels of the backward, each making the indexer's 16
-    heads of 64 again a tile."""
+    the one backward kernel (dQ, dK, dV and the indexer's three gradients
+    from each tile's mask, ``p`` and ``dp`` made once), each making the
+    indexer's 16 heads of 64 again a tile."""
     import importlib
     SA = importlib.import_module("deepfake_detection_tpu.ops.sparse_attention")
     spec = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
@@ -490,8 +491,8 @@ def test_sparse_attention_keye_32k_compiles(one_chip, grad):
     if grad:
         compiled = _compile(jax.grad(attend, argnums=range(6)), q, k, k, qi,
                             ki, w, ints, ints)
-        # dK/dV and dQ, with the forward whose output the backward reads
-        assert compiled.as_text().count("tpu_custom_call") == 3
+        # the backward, with the forward whose output it reads
+        assert compiled.as_text().count("tpu_custom_call") == 2
     else:
         compiled = _compile(attend, q, k, k, qi, ki, w, ints, ints)
         assert compiled.as_text().count("tpu_custom_call") == 2
@@ -506,10 +507,10 @@ def test_keyevl2_step_fits_the_chip(one_chip, monkeypatch, tmp_path):
     GB (15.04 GB when written: 7.45 GB of state less the gradients'
     buffers, and the full-capacity branch of the expert layer's switch,
     2.15 GB, at the peak), the sparse-attention kernels of all four layers
-    (forward, loss, dK/dV, dQ under ``attn_sparse``; the selection under
-    ``dsa_select``: remat keeps the selection, the output and the row
-    statistics, so neither forward kernel runs again) and the grouped
-    products in Mosaic."""
+    (forward, loss and the one fused backward under ``attn_sparse``; the
+    selection under ``dsa_select``: remat keeps the selection, the output
+    and the row statistics, so neither forward kernel runs again) and the
+    grouped products in Mosaic."""
     import functools
     import importlib
     import json
@@ -565,7 +566,7 @@ def test_keyevl2_step_fits_the_chip(one_chip, monkeypatch, tmp_path):
     mosaic = lambda scope: sum(                              # noqa: E731
         1 for line in lines if "tpu_custom_call" in line
         and re.search(r'op_name="[^"]*' + scope, line))
-    assert mosaic("attn_sparse") == 4 * 4
+    assert mosaic("attn_sparse") == 4 * 3
     assert mosaic("dsa_kl") == 4
     assert mosaic("dsa_select") == 4
     assert mosaic("moe_experts") > 0

@@ -30,6 +30,13 @@ the dK/dV and dQ pair.
 shapes, chained the same way (the next launch's ``q`` is this one's plus
 1e-30 of an ``out`` element), and prints a sha256 of one launch's ``out``
 and ``lse``: run it on two commits and compare the digests for their bits.
+``--sparse-bwd`` (TPU only) times the learned sparse attention's backward
+launch alone (``ops/sparse_attention.py:_bwd_pallas``) at the shape of
+``train_keye_dsa_32k`` (``SPARSE_SHAPE``), on a selection its own kernel
+made from seeded operands, chained the same way (the next launch's ``do``
+is this one's plus 1e-30 of a ``dq`` element), and prints the launch's
+Mosaic kernels and a sha256 of each of its six gradients: run it on two
+commits and compare the digests for their bits.
 """
 
 from __future__ import annotations
@@ -55,6 +62,9 @@ BWD_SHAPES = {
     "lfm2_full": (2, 8192, 32, 8, 8, 64, 64, 1024, None),
 }
 CHAIN = 8
+# rows, L, q heads, k heads, d, indexer heads, indexer width, topk: the
+# attention of models/keyevl2.py in train_keye_dsa_32k
+SPARSE_SHAPE = (1, 32768, 32, 4, 128, 16, 64, 2048)
 
 
 def _chain_ms(step, x, *rest) -> float:
@@ -200,6 +210,67 @@ def bwd_probe(names) -> None:
         probe(name)
 
 
+def sparse_bwd_probe() -> None:
+    import hashlib
+    import importlib
+    import statistics
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sa = importlib.import_module("deepfake_detection_tpu.ops.sparse_attention")
+    assert jax.default_backend() == "tpu", jax.default_backend()
+    b, l, h, hk, d, nj, e, topk = SPARSE_SHAPE
+    keys = jax.random.split(jax.random.PRNGKey(0), 8)
+    normal = lambda i, *s, dt=jnp.bfloat16: jax.random.normal(  # noqa: E731
+        keys[i], s, jnp.float32).astype(dt)
+    q, k, v = normal(0, b, h, l, d), normal(1, b, hk, l, d), \
+        normal(2, b, hk, l, d)
+    qi, ki, w = normal(3, b, l, nj, e), normal(4, b, l, e), \
+        normal(5, b, l, nj, dt=jnp.float32) * (nj * e) ** -0.5
+    sel = sa.select_keys(qi, ki, w, topk, impl="pallas", interpret=False)
+    scale = d ** -0.5
+    ops = (q, k, v, *sa._index_layout(qi, ki, w, q.dtype), sel.thr[:, None],
+           sel.cut[:, None])
+    res = ops + tuple(sa._fwd_pallas(*ops, scale, False))
+    do, gkl = normal(6, b, h, l, d), normal(7, b, l, dt=jnp.float32)
+
+    def bwd(do, res, gkl):
+        return sa._bwd_pallas(res, do, gkl, scale, False)
+
+    @jax.jit
+    def chain(do, res, gkl):
+        keep = jnp.float32(0)
+        for _ in range(CHAIN):
+            first, *others = bwd(do, res, gkl)
+            do = do + (first.reshape(-1)[0] * 1e-30).astype(do.dtype)
+            for o in others:
+                keep = keep + o.reshape(-1)[0]
+        return do, keep
+    one = jax.jit(bwd)
+    grads = one(do, res, gkl)
+    jax.block_until_ready(chain(do, res, gkl))
+    times = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        jax.block_until_ready(chain(do, res, gkl))
+        times.append(time.perf_counter() - t0)
+    bwd_ms = 1e3 * statistics.median(times) / CHAIN
+    cells = sum(sa._last_k(i, sa.BLOCK_Q, sa.BLOCK_K) + 1
+                for i in range(l // sa.BLOCK_Q))
+    text = one.lower(do, res, gkl).compile().as_text()
+    print(json.dumps({
+        "shape": "keye_dsa_32k", "seq_len": l, "heads": [h, hk, nj],
+        "topk": topk, "selected_pairs": int(sel.counts[0]),
+        "kernels": text.count("tpu_custom_call"), "chain": CHAIN,
+        "bwd_ms": bwd_ms, "bwd_us_per_cell": 1e3 * bwd_ms / cells,
+        "sha256": {name: hashlib.sha256(np.asarray(g).tobytes()).hexdigest()
+                   for name, g in zip(("dq", "dk", "dv", "dqi", "dki", "dw"),
+                                      grads)},
+        "device": jax.devices()[0].device_kind}), flush=True)
+
+
 def cell_step(cell_name: str, forms) -> None:
     import gc
     import importlib
@@ -254,6 +325,9 @@ def main() -> None:
     ap.add_argument("--fwd", nargs="*", choices=sorted(BWD_SHAPES),
                     metavar="SHAPE", help="time the forward launch and hash "
                     "its out and lse at these cells' shapes (none: all)")
+    ap.add_argument("--sparse-bwd", action="store_true",
+                    help="time the sparse attention's backward launch at "
+                    "train_keye_dsa_32k's shape and hash its gradients")
     ap.add_argument("--step", nargs="+", metavar="CELL [FORM ...]",
                     help="a sequence cell's own train step with the fused "
                     "and/or the split backward (default: both)")
@@ -266,6 +340,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.fwd is not None:
         return fwd_probe(args.fwd or sorted(BWD_SHAPES))
+    if args.sparse_bwd:
+        return sparse_bwd_probe()
     if args.bwd is not None:
         return bwd_probe(args.bwd or sorted(BWD_SHAPES))
     if args.step:
