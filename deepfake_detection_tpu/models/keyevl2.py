@@ -250,14 +250,27 @@ class KeyeVL2(nn.Module):
         census."""
         return self.n_layers
 
+    def _dsa_kernels(self, seq_len: int) -> bool:
+        """Whether the sparse attention takes its kernels over a row of
+        ``seq_len`` tokens (else the array form)."""
+        impl = self.dsa_impl or (None if self.attn_impl == "flash" else "xla")
+        return (impl or dsa_ops.sparse_impl(seq_len, self.head_dim)) \
+            == "pallas"
+
+    def dsa_fwd_bits_layers(self, seq_len: int) -> int:
+        """The layers whose attention forward reads the selection kernel's
+        bits (``ops/sparse_attention.py:Selection.bits``) over a row of
+        ``seq_len`` tokens: every one on the kernels, none under the array
+        form.  A census."""
+        return self.n_layers if self._dsa_kernels(seq_len) else 0
+
     def attn_tiles_visited(self, seq_len: int) -> int:
         """Grid cells the sparse-attention kernels visit in one train step
         over one row of ``seq_len`` tokens (a cell runs every head): the
         forward's, the loss's and the one backward's, and the selection's
         query tiles (``ops/sparse_attention.py:train_cells``); 0 where the
         array form runs."""
-        impl = self.dsa_impl or (None if self.attn_impl == "flash" else "xla")
-        if (impl or dsa_ops.sparse_impl(seq_len, self.head_dim)) != "pallas":
+        if not self._dsa_kernels(seq_len):
             return 0
         return self.n_layers * dsa_ops.train_cells(seq_len)
 

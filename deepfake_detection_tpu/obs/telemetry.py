@@ -315,6 +315,11 @@ _GAUGE_CATALOG = (
      "attention (models/glm4moelite.py; a census fixed by the model)"),
     ("dsa_layers", "Layers of the train step with learned sparse "
      "attention (models/keyevl2.py; a census fixed by the model)"),
+    ("dsa_fwd_bits_layers", "Learned sparse attention layers of the train "
+     "step whose attention forward reads the selection kernel's packed "
+     "bits, one a (query, key) pair, instead of making the index scores "
+     "again (ops/sparse_attention.py; a census: every layer on the "
+     "kernels, none under the array form)"),
     ("dsa_kl_loss", "The sparse-attention indexer's loss, KL of the heads' "
      "mean attention over the selected keys from the indexer's softmax, "
      "mean over rows and layers, over the steps of the last drain (0 "
@@ -348,7 +353,8 @@ class TrainTelemetry:
                  attn_bwd_layers: Tuple[int, int] = (0, 0),
                  attn_fwd_saved_layers: int = 0,
                  mla_layers: int = 0,
-                 dsa_layers: int = 0):
+                 dsa_layers: int = 0,
+                 dsa_fwd_bits_layers: int = 0):
         self.event_log = event_log
         self.flops_per_sample = float(flops_per_sample)
         # attention-kernel cells a step visits per row: a sequence model's
@@ -384,6 +390,7 @@ class TrainTelemetry:
         self._g["attn_fwd_saved_layers"] = float(attn_fwd_saved_layers)
         self._g["mla_layers"] = float(mla_layers)
         self._g["dsa_layers"] = float(dsa_layers)
+        self._g["dsa_fwd_bits_layers"] = float(dsa_fwd_bits_layers)
         self._g["restart_count"] = float(
             os.environ.get("DFD_RESTART_COUNT", 0) or 0)
         self.h_step = LatencyHistogram(_STEP_BOUNDS)

@@ -25,10 +25,12 @@ over two int32 a query: ``S_t = {s <= t : key > thr[t] or (key == thr[t]
 and s <= cut[t])}``, ``thr`` the ``topk``-th largest key of the row and
 ``cut`` the index where the ties that fill the ``topk`` end (``2**30``
 where every tie is taken; ``thr`` the least int32 where ``t < topk``).  No
-``L x L`` array of scores or masks exists anywhere: each kernel makes the
-scores of its tile again from ``qi``, ``ki`` and ``w`` with the one function
-:func:`_index_t`, at the one tile shape, so every kernel sees the bits the
-selection saw.  A score's terms are products float32 holds exactly
+``L x L`` array of scores exists anywhere.  The selection kernel hands the
+forward kernels the rule applied, one bit a pair (``Selection.bits``: 128
+MiB a row of 32,768), and the selected scores' log-sum-exp; the backward
+makes the scores of its tile again from ``qi``, ``ki`` and ``w`` with the
+one function :func:`_index_t`, at the one tile shape, so it sees the bits
+the selection saw.  A score's terms are products float32 holds exactly
 (:func:`_term`: each factor split into its bfloat16 part and the rest),
 so a compiler that fuses a multiply and an add cannot move a bit of it
 from one kernel to the next, and the weighting keeps float32's precision
@@ -38,33 +40,42 @@ whatever the operands' dtype.
 transposed tile, **keys on sublanes and queries on lanes**: a cell of
 ``BLOCK_K`` = 512 keys by ``BLOCK_Q`` = 128 queries, so a query's
 statistics (``thr``, ``cut``, a head's ``lse``, the indexer's ``lse``,
-``kl``) are lane-dense rows ``(1, 128)`` stored as ``(B, ·, L)``.  Inputs
-``q``, ``do``: ``(B, H, L, D)``; ``k``, ``v``: ``(B, Hk, L, D)``; ``qi``:
-``(B, J, L, 128)`` and ``ki``: ``(B, L, 128)`` (E zero-padded to the lane
-width); ``w``: ``(B, J, L)`` float32.  Outputs made per query come out
-transposed, ``(B, H, D, L)`` (``o``, ``dq``) and ``(B, J, 128, L)``
-(``dqi``), and are turned back outside.  A cell runs all H heads against
-the one mask it made.
+``kl``) are lane-dense rows ``(1, 128)`` stored as ``(B, ·, L)``, and a
+cell's selection is ``(16, 128)`` int32 words, bit ``b`` of word row ``r``
+the key ``16 b + r`` of the cell (:func:`_pack`), so that a bit unpacks to
+a whole slab of sublanes.  Inputs ``q``, ``do``: ``(B, H, L, D)``; ``k``,
+``v``: ``(B, Hk, L, D)``; ``qi``: ``(B, J, L, 128)`` and ``ki``: ``(B, L,
+128)`` (E zero-padded to the lane width); ``w``: ``(B, J, L)`` float32.
+Outputs made per query come out transposed, ``(B, H, D, L)`` (``o``,
+``dq``) and ``(B, J, 128, L)`` (``dqi``), and are turned back outside.  A
+cell runs all H heads against the one mask.
 
-* ``_select_kernel``, grid ``(B, L / 128)``: the query tile's scores
-  against every causal key into a VMEM row ``(L, 128)`` of keys (16 MiB at
-  32,768), then 32 passes of bisection over the key bits for ``thr``, one
-  for the ties, 16 over the index for ``cut``, one that counts the
-  selected pairs and the (128 query, 128 key) blocks holding one.
-* ``_fwd_kernel``, grid ``(B, L / 128, L / 512)``: online softmax of every
-  head over the selected keys (scratch ``(H, D, 128)`` float32), beside the
-  online log-sum-exp of the selected index scores.
-* ``_kl_kernel``, same grid: ``p`` from the finished ``lse`` of every head,
-  ``kl`` a query.
+* ``_select_kernel``, grid ``(B, L / 128)``, reads ``qi``, ``ki``, ``w``:
+  the query tile's scores against every causal key into a VMEM row ``(L,
+  128)`` of keys (16 MiB at 32,768), then 32 passes of bisection over the
+  key bits for ``thr``, one for the ties, 16 over the index for ``cut``,
+  and one that applies the rule to each causal key tile: it writes the
+  tile's bits (``(B, L / 512, 16, L)``, zeros past the diagonal), counts
+  the selected pairs and the (128 query, 128 key) blocks holding one, and
+  runs the online log-sum-exp of the selected scores (``ilse``).  Writes
+  ``thr``, ``cut``, the counts, ``ilse`` and the bits.
+* ``_fwd_kernel``, grid ``(B, L / 128, L / 512)``, reads ``q``, ``k``,
+  ``v`` and the cell's words: online softmax of every head over the
+  selected keys (scratch ``(H, D, 128)`` float32).  Writes ``o`` and each
+  head's ``lse``.
+* ``_kl_kernel``, same grid, reads ``q``, ``k``, ``qi``, ``ki``, ``w``, the
+  cell's words, ``lse`` and ``ilse``: ``p`` from the finished ``lse`` of
+  every head, the index scores for ``log q``.  Writes ``kl`` a query.
 * ``_bwd_kernel``, grid ``(B, L / C, L / 128, C / 512)`` over key chunks
-  of ``C`` keys (:func:`_chunk`: 4,096 at 32,768): the whole backward, each
-  cell's mask, ``p``, ``dp`` and ``ds = p (dp - delta)`` (FlashAttention-2)
-  and ``dI = g_kl (softmax(I) - p)`` made once for dQ, dK, dV and the
-  indexer's three gradients, the index heads' ``relu(x)`` kept from the
-  mask's pass in a VMEM scratch ``(J, 512, 128)``.  The chunk's dK, dV and
-  dKI stay in VMEM across the query tiles; dQ, dQI and dW of a query tile
-  are carried from chunk to chunk through HBM, outputs aliased to zeroed
-  inputs.
+  of ``C`` keys (:func:`_chunk`: 4,096 at 32,768), reads the operands,
+  ``thr``, ``cut``, ``do``, ``lse``, ``delta``, ``ilse`` and the loss's
+  cotangent: the whole backward, each cell's mask, ``p``, ``dp`` and ``ds
+  = p (dp - delta)`` (FlashAttention-2) and ``dI = g_kl (softmax(I) -
+  p)`` made once for dQ, dK, dV and the indexer's three gradients, the
+  index heads' ``relu(x)`` kept from the mask's pass in a VMEM scratch
+  ``(J, 512, 128)``.  The chunk's dK, dV and dKI stay in VMEM across the
+  query tiles; dQ, dQI and dW of a query tile are carried from chunk to
+  chunk through HBM, outputs aliased to zeroed inputs.
 
 Cells wholly above the diagonal are skipped and their index maps clamped,
 so nothing is copied for them.  Off the TPU the op takes the array form
@@ -103,6 +114,8 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 # single-buffered beside the tiles' double-buffered blocks
 _CHUNK_BYTES = 24 * 1024 * 1024
 _INT_MIN = -2 ** 31
+# pairs a word of the selection's bits holds
+_WORD = 32
 # ``cut`` where every tie at ``thr`` is taken
 _ALL = 2 ** 30
 _NEG_INF = float("-inf")
@@ -117,10 +130,16 @@ SPARSE_RESIDUALS = ("sparse_out", "sparse_lse", "sparse_index_lse",
 class Selection(NamedTuple):
     """``thr``, ``cut`` (B, L) int32: the selection rule's two numbers a
     query; ``counts`` (3,) int32: selected pairs, (128 query, 128 key)
-    blocks holding one, causal blocks."""
+    blocks holding one, causal blocks.  The selection kernel's, for the
+    forward kernels (None from the array form): ``ilse`` (B, L) float32,
+    the log-sum-exp of a query's selected index scores, and ``bits`` (B,
+    L / bk, bk / 32, L) int32, the selection packed (:func:`_pack`; bk =
+    ``min(BLOCK_K, L)``), zeros in key tiles past the diagonal."""
     thr: jax.Array
     cut: jax.Array
     counts: jax.Array
+    ilse: Optional[jax.Array] = None
+    bits: Optional[jax.Array] = None
 
 
 def order_key(x):
@@ -226,6 +245,24 @@ def _cell_mask(ki_ref, qi_ref, w_ref, thr_ref, cut_ref, s0, t0, keep=None):
     return index, _rule(order_key(index), thr_ref[0], cut_ref[0], s, t)
 
 
+def _pack(hit):
+    """A cell's selection (bk, bq) bool as (bk / 32, bq) int32 words: bit
+    ``b`` of word row ``r`` is key ``r + b bk / 32``, so that each bit
+    unpacks to a whole slab of sublanes."""
+    rows = hit.shape[0] // _WORD
+    hit = hit.astype(jnp.int32)
+    word = hit[:rows]
+    for b in range(1, _WORD):
+        word = word | lax.shift_left(hit[b * rows:(b + 1) * rows], b)
+    return word
+
+
+def _unpack(word):
+    """:func:`_pack` undone: (bk, bq) bool."""
+    return jnp.concatenate([lax.shift_right_logical(word, b) & 1
+                            for b in range(_WORD)], axis=0) != 0
+
+
 def _spec(block, index_map):
     return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
 
@@ -248,8 +285,8 @@ def _first_q(j, bq, bk):
 # the selection
 # ---------------------------------------------------------------------------
 
-def _select_kernel(qi_ref, ki_ref, w_ref, thr_ref, cut_ref, cnt_ref, buf,
-                   *, topk, bk, bits):
+def _select_kernel(qi_ref, ki_ref, w_ref, thr_ref, cut_ref, cnt_ref,
+                   ilse_ref, bits_ref, buf, *, topk, bk, bits):
     bq = w_ref.shape[-1]
     i = pl.program_id(1)
     t0 = i * bq
@@ -302,19 +339,35 @@ def _select_kernel(qi_ref, ki_ref, w_ref, thr_ref, cut_ref, cnt_ref, buf,
     thr_ref[0] = thr
     cut_ref[0] = cut
 
+    # the selection of each causal key tile, packed, with the census and
+    # the online log-sum-exp of the selected scores (a key is its own
+    # score: order_key undoes itself)
     def tally(c, carry):
-        touched, picked = carry
+        touched, picked, m, l_ = carry
         s0 = pl.multiple_of(c * bk, bk)
         s, t = _positions(s0, t0, bk, bq)
-        hit = _rule(buf[pl.ds(s0, bk), :], thr, cut, s, t).astype(
-            jnp.float32)
+        key = buf[pl.ds(s0, bk), :]
+        hit = _rule(key, thr, cut, s, t)
+        bits_ref[0, c] = _pack(hit)
+        index = lax.bitcast_convert_type(order_key(key), jnp.float32)
+        _, _, m, l_ = _softmax_step(jnp.where(hit, index, _NEG_INF), m, l_)
+        hit = hit.astype(jnp.float32)
         picked = picked + jnp.sum(hit, keepdims=True)
         for b in range(bk // _CENSUS):
             block = hit[b * _CENSUS:(b + 1) * _CENSUS, :]
             touched = touched + jnp.max(block, keepdims=True)
-        return touched, picked
+        return touched, picked, m, l_
     zero = jnp.zeros((1, 1), jnp.float32)
-    touched, picked = lax.fori_loop(0, chunks, tally, (zero, zero))
+    touched, picked, m, l_ = lax.fori_loop(
+        0, chunks, tally, (zero, zero, jnp.full((1, bq), _NEG_INF),
+                           jnp.zeros((1, bq), jnp.float32)))
+    ilse_ref[0] = _lse(m, l_)
+
+    # key tiles past the diagonal: zeros, never read
+    def clear(c, carry):
+        bits_ref[0, c] = jnp.zeros(bits_ref.shape[2:], jnp.int32)
+        return carry
+    lax.fori_loop(chunks, bits_ref.shape[1], clear, 0)
     lane = lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
     cnt_ref[0] = jnp.where(lane == 0, touched,
                            jnp.where(lane == 1, picked, 0.0))
@@ -325,8 +378,9 @@ def _select_pallas(qi, ki, w, topk, interpret):
     """qi (B, J, L, E), ki (B, L, E), w (B, J, L) -> Selection."""
     b, nj, l, e = qi.shape
     bq, bk = BLOCK_Q, min(BLOCK_K, l)
-    nq = l // bq
-    thr, cut, cnt = pl.pallas_call(
+    nq, nk = l // bq, l // bk
+    words = bk // _WORD
+    thr, cut, cnt, ilse, bits = pl.pallas_call(
         functools.partial(_select_kernel, topk=topk, bk=bk,
                           bits=max(1, (l - 1).bit_length())),
         grid=(b, nq),
@@ -335,10 +389,14 @@ def _select_pallas(qi, ki, w, topk, interpret):
                   _spec((1, nj, bq), lambda b_, i: (b_, 0, i))],
         out_specs=[_spec((1, 1, bq), lambda b_, i: (b_, 0, i)),
                    _spec((1, 1, bq), lambda b_, i: (b_, 0, i)),
-                   _spec((1, 1, _LANES), lambda b_, i: (b_, 0, i))],
+                   _spec((1, 1, _LANES), lambda b_, i: (b_, 0, i)),
+                   _spec((1, 1, bq), lambda b_, i: (b_, 0, i)),
+                   _spec((1, nk, words, bq), lambda b_, i: (b_, 0, 0, i))],
         out_shape=[jax.ShapeDtypeStruct((b, 1, l), jnp.int32),
                    jax.ShapeDtypeStruct((b, 1, l), jnp.int32),
-                   jax.ShapeDtypeStruct((b, 1, nq * _LANES), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, 1, nq * _LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((b, 1, l), jnp.float32),
+                   jax.ShapeDtypeStruct((b, nk, words, l), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((l, bq), jnp.int32)],
         compiler_params=_params(),
         interpret=interpret,
@@ -346,16 +404,16 @@ def _select_pallas(qi, ki, w, topk, interpret):
     cnt = cnt.reshape(b, nq, _LANES)
     counts = jnp.stack([jnp.sum(cnt[..., 1]), jnp.sum(cnt[..., 0]),
                         jnp.float32(b * blocks_causal(l))])
-    return Selection(thr[:, 0], cut[:, 0], counts.astype(jnp.int32))
+    return Selection(thr[:, 0], cut[:, 0], counts.astype(jnp.int32),
+                     ilse[:, 0], bits)
 
 
 # ---------------------------------------------------------------------------
-# forward: the attention and the index scores' log-sum-exp
+# forward: the attention over the selection's bits
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, thr_ref,
-                cut_ref, o_ref, lse_ref, ilse_ref, acc, m_ref, l_ref, im_ref,
-                il_ref, *, scale):
+def _fwd_kernel(q_ref, k_ref, v_ref, bits_ref, o_ref, lse_ref, acc, m_ref,
+                l_ref, *, scale):
     nh, bq = q_ref.shape[1], q_ref.shape[2]
     nkv, bk = k_ref.shape[1], k_ref.shape[2]
     i, j = pl.program_id(1), pl.program_id(2)
@@ -365,14 +423,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, thr_ref,
         acc[...] = jnp.zeros_like(acc)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
-        im_ref[...] = jnp.full_like(im_ref, _NEG_INF)
-        il_ref[...] = jnp.zeros_like(il_ref)
 
     @pl.when(j <= _last_k(i, bq, bk))
     def _accumulate():
-        index, sel = _cell_mask(ki_ref, qi_ref, w_ref, thr_ref, cut_ref,
-                                j * bk, i * bq)
-        _online(jnp.where(sel, index, _NEG_INF), im_ref, il_ref, 0)
+        sel = _unpack(bits_ref[0, 0])
         for h in range(nh):
             g = h // (nh // nkv)
             s = lax.dot_general(k_ref[0, g], q_ref[0, h],
@@ -388,47 +442,56 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, thr_ref,
         for h in range(nh):
             lh = jnp.maximum(l_ref[h:h + 1, :], 1e-30)
             o_ref[0, h] = (acc[h] / lh).astype(o_ref.dtype)
-        lse_ref[0] = _safe(m_ref[...]) + jnp.log(
-            jnp.maximum(l_ref[...], 1e-30))
-        ilse_ref[0] = _safe(im_ref[...]) + jnp.log(
-            jnp.maximum(il_ref[...], 1e-30))
+        lse_ref[0] = _lse(m_ref[...], l_ref[...])
 
 
 def _safe(m):
     return jnp.where(m == _NEG_INF, 0.0, m)
 
 
-def _online(s, m_ref, l_ref, h):
-    """One key tile of the online softmax of row ``h`` of the statistics:
-    the tile's weights and the old sum's correction, (bk, bq) and (1, bq);
-    the statistics updated."""
-    m_prev = m_ref[h:h + 1, :]
+def _lse(m, l_):
+    """A finished online softmax's log-sum-exp."""
+    return _safe(m) + jnp.log(jnp.maximum(l_, 1e-30))
+
+
+def _softmax_step(s, m_prev, l_prev):
+    """One key tile of an online softmax over rows ``(1, bq)``: the tile's
+    weights (bk, bq), the old sum's correction and the new statistics."""
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
     m_safe = _safe(m_new)
     p = jnp.exp(s - m_safe)
     corr = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_safe))
-    l_ref[h:h + 1, :] = l_ref[h:h + 1, :] * corr + jnp.sum(p, axis=0,
-                                                           keepdims=True)
+    return p, corr, m_new, l_prev * corr + jnp.sum(p, axis=0, keepdims=True)
+
+
+def _online(s, m_ref, l_ref, h):
+    """:func:`_softmax_step` on row ``h`` of the statistics in VMEM: the
+    tile's weights and the old sum's correction; the statistics updated."""
+    p, corr, m_new, l_new = _softmax_step(s, m_ref[h:h + 1, :],
+                                          l_ref[h:h + 1, :])
+    l_ref[h:h + 1, :] = l_new
     m_ref[h:h + 1, :] = m_new
     return p, corr
 
 
-def _q_grid_specs(nh, nkv, nj, d, e, bq, bk):
-    """in_specs of the (B, query tile, key tile) grids: q, k, v, qi, ki,
-    w, thr, cut; key tiles past the diagonal name the last visible one."""
-    def kmap(b, i, j):
-        return (b, 0, jnp.minimum(j, _last_k(i, bq, bk)), 0)
+def _visible(i, j, bq, bk):
+    """Key tile ``j`` of query tile ``i``'s row, past the diagonal the last
+    visible one: nothing is copied for the cells skipped."""
+    return jnp.minimum(j, _last_k(i, bq, bk))
 
-    def kimap(b, i, j):
-        return (b, jnp.minimum(j, _last_k(i, bq, bk)), 0)
-    return [_spec((1, nh, bq, d), lambda b, i, j: (b, 0, i, 0)),
-            _spec((1, nkv, bk, d), kmap),
-            _spec((1, nkv, bk, d), kmap),
-            _spec((1, nj, bq, e), lambda b, i, j: (b, 0, i, 0)),
-            _spec((1, bk, e), kimap),
-            _spec((1, nj, bq), lambda b, i, j: (b, 0, i)),
-            _spec((1, 1, bq), lambda b, i, j: (b, 0, i)),
-            _spec((1, 1, bq), lambda b, i, j: (b, 0, i))]
+
+def _q_spec(rows, bq, width):
+    return _spec((1, rows, bq, width), lambda b, i, j: (b, 0, i, 0))
+
+
+def _k_spec(rows, bq, bk, width):
+    return _spec((1, rows, bk, width),
+                 lambda b, i, j: (b, 0, _visible(i, j, bq, bk), 0))
+
+
+def _bits_spec(bq, bk):
+    return _spec((1, 1, bk // _WORD, bq),
+                 lambda b, i, j: (b, _visible(i, j, bq, bk), 0, i))
 
 
 def _row_spec(rows, bq):
@@ -436,27 +499,25 @@ def _row_spec(rows, bq):
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _fwd_pallas(q, k, v, qi, ki, w, thr, cut, scale, interpret):
+def _fwd_pallas(q, k, v, bits, scale, interpret):
     b, nh, l, d = q.shape
-    nkv, nj, e = k.shape[1], qi.shape[1], qi.shape[3]
+    nkv = k.shape[1]
     bq, bk = BLOCK_Q, min(BLOCK_K, l)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale),
         grid=(b, l // bq, l // bk),
-        in_specs=_q_grid_specs(nh, nkv, nj, d, e, bq, bk),
+        in_specs=[_q_spec(nh, bq, d), _k_spec(nkv, bq, bk, d),
+                  _k_spec(nkv, bq, bk, d), _bits_spec(bq, bk)],
         out_specs=[_spec((1, nh, d, bq), lambda b_, i, j: (b_, 0, 0, i)),
-                   _row_spec(nh, bq), _row_spec(1, bq)],
+                   _row_spec(nh, bq)],
         out_shape=[jax.ShapeDtypeStruct((b, nh, d, l), q.dtype),
-                   jax.ShapeDtypeStruct((b, nh, l), jnp.float32),
-                   jax.ShapeDtypeStruct((b, 1, l), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, nh, l), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((nh, d, bq), jnp.float32),
                         pltpu.VMEM((nh, bq), jnp.float32),
-                        pltpu.VMEM((nh, bq), jnp.float32),
-                        pltpu.VMEM((1, bq), jnp.float32),
-                        pltpu.VMEM((1, bq), jnp.float32)],
+                        pltpu.VMEM((nh, bq), jnp.float32)],
         compiler_params=_params(),
         interpret=interpret,
-    )(q, k, v, qi, ki, w, thr, cut)
+    )(q, k, v, bits)
 
 
 # ---------------------------------------------------------------------------
@@ -480,9 +541,8 @@ def _heads_mean_p(q_ref, k_ref, lse_ref, sel, scale, extra=None):
     return total * (1.0 / nh)
 
 
-def _kl_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, thr_ref, cut_ref,
-               lse_ref, ilse_ref, kl_ref, kacc, *, scale):
-    del v_ref
+def _kl_kernel(q_ref, k_ref, qi_ref, ki_ref, w_ref, bits_ref, lse_ref,
+               ilse_ref, kl_ref, kacc, *, scale):
     bq, bk = q_ref.shape[2], k_ref.shape[2]
     i, j = pl.program_id(1), pl.program_id(2)
 
@@ -492,8 +552,8 @@ def _kl_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, thr_ref, cut_ref,
 
     @pl.when(j <= _last_k(i, bq, bk))
     def _accumulate():
-        index, sel = _cell_mask(ki_ref, qi_ref, w_ref, thr_ref, cut_ref,
-                                j * bk, i * bq)
+        sel = _unpack(bits_ref[0, 0])
+        index = _index_t(ki_ref[0], qi_ref, w_ref[0])
         p = _heads_mean_p(q_ref, k_ref, lse_ref, sel, scale)
         logq = index - ilse_ref[0]
         term = jnp.where(p > 0.0, p * jnp.log(jnp.maximum(p, 1e-38)), 0.0) \
@@ -506,21 +566,25 @@ def _kl_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, thr_ref, cut_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _kl_pallas(q, k, v, qi, ki, w, thr, cut, lse, ilse, scale, interpret):
+def _kl_pallas(q, k, qi, ki, w, bits, lse, ilse, scale, interpret):
     b, nh, l, d = q.shape
     nkv, nj, e = k.shape[1], qi.shape[1], qi.shape[3]
     bq, bk = BLOCK_Q, min(BLOCK_K, l)
     return pl.pallas_call(
         functools.partial(_kl_kernel, scale=scale),
         grid=(b, l // bq, l // bk),
-        in_specs=_q_grid_specs(nh, nkv, nj, d, e, bq, bk)
-        + [_row_spec(nh, bq), _row_spec(1, bq)],
+        in_specs=[_q_spec(nh, bq, d), _k_spec(nkv, bq, bk, d),
+                  _q_spec(nj, bq, e),
+                  _spec((1, bk, e),
+                        lambda b_, i, j: (b_, _visible(i, j, bq, bk), 0)),
+                  _row_spec(nj, bq), _bits_spec(bq, bk), _row_spec(nh, bq),
+                  _row_spec(1, bq)],
         out_specs=_row_spec(1, bq),
         out_shape=jax.ShapeDtypeStruct((b, 1, l), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bq), jnp.float32)],
         compiler_params=_params(),
         interpret=interpret,
-    )(q, k, v, qi, ki, w, thr, cut, lse, ilse)
+    )(q, k, qi, ki, w, bits, lse, ilse)
 
 
 # ---------------------------------------------------------------------------
@@ -798,9 +862,12 @@ def select_keys(qi, ki, w, topk: int, impl: Optional[str] = None,
         interpret = resolve_interpret(interpret, "select_keys")
         sel = _select_pallas(*_index_layout(qi, ki, w, qi.dtype), topk,
                              interpret)
-    return Selection(checkpoint_name(sel.thr, SPARSE_RESIDUALS[3]),
-                     checkpoint_name(sel.cut, SPARSE_RESIDUALS[4]),
-                     sel.counts)
+        # ilse is saved for the backward; the bits, which only the forward
+        # kernels read, are not
+        sel = sel._replace(ilse=checkpoint_name(sel.ilse,
+                                                SPARSE_RESIDUALS[2]))
+    return sel._replace(thr=checkpoint_name(sel.thr, SPARSE_RESIDUALS[3]),
+                        cut=checkpoint_name(sel.cut, SPARSE_RESIDUALS[4]))
 
 
 def sparse_attention(q, k, v, qi, ki, w, sel: Selection,
@@ -808,29 +875,30 @@ def sparse_attention(q, k, v, qi, ki, w, sel: Selection,
                      impl: Optional[str] = None,
                      interpret: Optional[bool] = None):
     """``(o (B, L, H, D), kl (B, L) float32)`` over the selection ``sel``
-    (:func:`select_keys`): ``q`` (B, L, H, D), ``k``, ``v`` (B, L, Hk, D),
-    the indexer's ``qi`` (B, L, J, E), ``ki`` (B, L, E), ``w`` (B, L, J).
-    The kernels take the MXU's operands in ``q``'s dtype, float32
-    statistics and accumulators."""
+    (:func:`select_keys`, in the same form): ``q`` (B, L, H, D), ``k``,
+    ``v`` (B, L, Hk, D), the indexer's ``qi`` (B, L, J, E), ``ki`` (B, L,
+    E), ``w`` (B, L, J).  The kernels take the MXU's operands in ``q``'s
+    dtype, float32 statistics and accumulators."""
     b, l, nh, d = q.shape
     scale = d ** -0.5 if scale is None else scale
     impl = impl or sparse_impl(l, d)
     if impl == "xla":
         return _attend_array(q, k, v, qi, ki, w, sel.thr, sel.cut, scale)
+    if sel.bits is None:
+        raise ValueError("the kernels read the selection kernel's bits: "
+                         "select_keys in the kernels' form")
     interpret = resolve_interpret(interpret, "sparse_attention")
     @jax.custom_vjp
-    def op(qp, kp, vp, qip, kip, wp, thr, cut):
-        return fwd(qp, kp, vp, qip, kip, wp, thr, cut)[0]
+    def op(qp, kp, vp, qip, kip, wp, thr, cut, ilse, bits):
+        return fwd(qp, kp, vp, qip, kip, wp, thr, cut, ilse, bits)[0]
 
-    def fwd(qp, kp, vp, qip, kip, wp, thr, cut):
-        o, lse, ilse = _fwd_pallas(qp, kp, vp, qip, kip, wp, thr, cut,
-                                   scale, interpret)
+    def fwd(qp, kp, vp, qip, kip, wp, thr, cut, ilse, bits):
+        o, lse = _fwd_pallas(qp, kp, vp, bits, scale, interpret)
         o = checkpoint_name(o, SPARSE_RESIDUALS[0])
         lse = checkpoint_name(lse, SPARSE_RESIDUALS[1])
-        ilse = checkpoint_name(ilse, SPARSE_RESIDUALS[2])
         with jax.named_scope("dsa_kl"):
-            kl = _kl_pallas(qp, kp, vp, qip, kip, wp, thr, cut, lse, ilse,
-                            scale, interpret)
+            kl = _kl_pallas(qp, kp, qip, kip, wp, bits, lse, ilse, scale,
+                            interpret)
         return (o, kl), (qp, kp, vp, qip, kip, wp, thr, cut, o, lse, ilse)
 
     def bwd(res, g):
@@ -842,12 +910,14 @@ def sparse_attention(q, k, v, qi, ki, w, sel: Selection,
         return (jnp.swapaxes(dq, 2, 3).astype(qp.dtype),
                 dk.astype(kp.dtype), dv.astype(vp.dtype),
                 jnp.swapaxes(dqi, 2, 3).astype(qip.dtype),
-                dki.astype(kip.dtype), dw.astype(wp.dtype), None, None)
+                dki.astype(kip.dtype), dw.astype(wp.dtype), None, None, None,
+                None)
 
     op.defvjp(fwd, bwd)
-    # the selection's layout, so that every kernel makes its scores
+    # the selection's layout, in which the backward makes its scores again
     o_t, kl = op(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
                  jnp.swapaxes(v, 1, 2), *_index_layout(qi, ki, w, qi.dtype),
-                 sel.thr[:, None], sel.cut[:, None])
+                 sel.thr[:, None], sel.cut[:, None], sel.ilse[:, None],
+                 sel.bits)
     # (B, H, D, L) -> (B, L, H, D)
     return jnp.transpose(o_t, (0, 3, 1, 2)), kl[:, 0]

@@ -231,6 +231,9 @@ class Program:
     # layers of the train step with learned sparse attention
     # (models/keyevl2.py)
     dsa_layers: int = 0
+    # of those, the layers whose attention forward reads the selection
+    # kernel's bits (ops/sparse_attention.py)
+    dsa_fwd_bits_layers: int = 0
 
 
 def _choose_mesh(cfg: TrainConfig):
@@ -366,10 +369,13 @@ def build_program(cfg: TrainConfig, mesh=None) -> Program:
     if hasattr(model, "mla_layers"):
         mla_layers = model.mla_layers()
         _logger.info("Latent attention: mla_layers=%d", mla_layers)
-    dsa_layers = 0
+    dsa_layers, dsa_fwd_bits_layers = 0, 0
     if hasattr(model, "dsa_layers"):
         dsa_layers = model.dsa_layers()
-        _logger.info("Learned sparse attention: dsa_layers=%d", dsa_layers)
+        dsa_fwd_bits_layers = model.dsa_fwd_bits_layers(cfg.seq_len)
+        _logger.info("Learned sparse attention: dsa_layers=%d "
+                     "dsa_fwd_bits_layers=%d", dsa_layers,
+                     dsa_fwd_bits_layers)
     return Program(
         cfg=cfg, mesh=mesh, n_dev=n_dev, batch_axis=batch_axis, dp=dp_size,
         data_config=data_config, input_size=input_size, model=model,
@@ -377,7 +383,7 @@ def build_program(cfg: TrainConfig, mesh=None) -> Program:
         causal_conv_layers=causal_conv_layers, moe_layers=moe_layers,
         attn_bwd_layers=attn_bwd_layers,
         attn_fwd_saved_layers=attn_fwd_saved_layers, mla_layers=mla_layers,
-        dsa_layers=dsa_layers,
+        dsa_layers=dsa_layers, dsa_fwd_bits_layers=dsa_fwd_bits_layers,
         lr=lr, tx=create_optimizer(cfg, learning_rate=lr),
         lr_scheduler=lr_scheduler, num_epochs=num_epochs,
         loss_fn=create_loss_fn(cfg),
@@ -555,6 +561,7 @@ def build_telemetry(program: Program, state, train_loader,
         attn_fwd_saved_layers=program.attn_fwd_saved_layers,
         mla_layers=program.mla_layers,
         dsa_layers=program.dsa_layers,
+        dsa_fwd_bits_layers=program.dsa_fwd_bits_layers,
         # throughput is measured on the GLOBAL batch (the loader
         # assembles the global sharded array), so the MFU denominator
         # is the whole MESH's peak — n_dev == mesh.size, which a
@@ -814,7 +821,8 @@ def main(cfg: TrainConfig) -> Dict[str, float]:
                         attn_split_bwd_layers=program.attn_bwd_layers[1],
                         attn_fwd_saved_layers=program.attn_fwd_saved_layers,
                         mla_layers=program.mla_layers,
-                        dsa_layers=program.dsa_layers)
+                        dsa_layers=program.dsa_layers,
+                        dsa_fwd_bits_layers=program.dsa_fwd_bits_layers)
         if resumed_from:
             telemetry.event("resume", path=resumed_from,
                             epoch=start_epoch, batch=resume_batch)

@@ -336,9 +336,10 @@ def test_the_kernels_interpreted_equal_the_array_form():
     (exact index scores), two query tiles and two key tiles of a row."""
     q, k, v, _, _, _ = _op_inputs(4, l=256)
     _, _, _, qi, ki, w = _op_inputs(5, l=256, integer=True)
-    sel = SA.select_keys(qi, ki, w, 48, impl="xla")
 
     def run(impl):
+        sel = SA.select_keys(qi, ki, w, 48, impl=impl, interpret=True)
+
         def loss(*a):
             o, kl = SA.sparse_attention(*a, sel, impl=impl, interpret=True)
             return jnp.sum(o * jnp.cos(o)) + jnp.sum(
@@ -351,6 +352,91 @@ def test_the_kernels_interpreted_equal_the_array_form():
     assert _rel(op, oa) < 1e-5 and _rel(klp, kla) < 1e-5
     for name, a, b in zip(("q", "k", "v", "qi", "ki", "w"), gp, ga):
         assert _rel(a, b) < 1e-5, name
+
+
+def _unpacked(bits):
+    """The kernels' packed selection (B, L / bk, bk / 32, L) as a (B, T, S)
+    bool array: bit ``b`` of word row ``r`` of key tile ``j`` is key ``j
+    bk + b bk / 32 + r``."""
+    bits = np.asarray(bits)
+    b, nk, rows, l = bits.shape
+    one = (bits[:, :, None] >> np.arange(32)[None, None, :, None, None]) & 1
+    return np.swapaxes(one.reshape(b, nk * 32 * rows, l), 1, 2) != 0
+
+
+def _planted(l, zeros):
+    """Exact index inputs of a row of ``l`` and a top-k of ``l / 8``; with
+    ``zeros`` a quarter of the keys score exactly 0 against every query and
+    no score is negative, so that ties at 0 fill selections up to the
+    cut."""
+    _, _, _, qi, ki, w = _op_inputs(l, l=l, integer=True)
+    if zeros:
+        w = jnp.abs(w)
+        ki = jnp.where((jnp.arange(l) % 4 == 1)[None, :, None], 0.0, ki)
+    return qi, ki, w, l // 8
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_selection(l, zeros):
+    qi, ki, w, topk = _planted(l, zeros)
+    return SA.select_keys(qi, ki, w, topk, impl="pallas", interpret=True)
+
+
+@pytest.mark.parametrize("l,zeros", [(256, False), (1024, True),
+                                     (1536, False)])
+def test_the_selection_kernel_hands_over_the_array_forms_selection(l, zeros):
+    """The selection kernel's bits unpack to the array form's selection
+    exactly, count the census's selected pairs and are zero past the
+    diagonal; its ``ilse`` is the log-sum-exp of the array form's selected
+    scores to float32's rounding.  Exact index scores, in one row with
+    ties at an exact 0 cut (:func:`_planted`)."""
+    qi, ki, w, topk = _planted(l, zeros)
+    got = _kernel_selection(l, zeros)
+    want = SA._select_array(qi, ki, w, topk)
+    np.testing.assert_array_equal(got.thr, want.thr)
+    np.testing.assert_array_equal(got.cut, want.cut)
+    index = SA._index_array(qi, ki, w)
+    t, s = SA._causal(l)
+    sel = np.asarray(SA._rule(SA.order_key(index), want.thr[..., None],
+                              want.cut[..., None], s, t))
+    np.testing.assert_array_equal(_unpacked(got.bits), sel)
+    popcount = np.unpackbits(np.asarray(got.bits).view(np.uint8)).sum()
+    assert popcount == int(got.counts[0]) == sel.sum()
+    if zeros:
+        cut_at_zero = (np.asarray(got.thr) == 0) & (np.asarray(got.cut)
+                                                    < SA._ALL)
+        assert cut_at_zero.sum() > 10
+    bk = min(SA.BLOCK_K, l)
+    for i in range(l // SA.BLOCK_Q):
+        past = np.asarray(got.bits)[:, SA._last_k(i, SA.BLOCK_Q, bk) + 1:, :,
+                                    i * SA.BLOCK_Q:(i + 1) * SA.BLOCK_Q]
+        assert not past.any()
+    lse = jax.nn.logsumexp(jnp.where(sel, index, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(got.ilse, lse, rtol=2e-7, atol=0)
+
+
+def test_the_forward_kernels_never_read_the_words_past_the_diagonal():
+    """Words of the cells past the diagonal set to all ones: the forward's
+    ``o`` and ``lse`` and the loss kernel's ``kl`` keep their bits."""
+    l = 1024
+    q, k, v, _, _, _ = _op_inputs(8, l=l)
+    qi, ki, w, _ = _planted(l, True)
+    sel = _kernel_selection(l, True)
+    past = np.zeros(sel.bits.shape, bool)
+    for i in range(l // SA.BLOCK_Q):
+        past[:, SA._last_k(i, SA.BLOCK_Q, SA.BLOCK_K) + 1:, :,
+             i * SA.BLOCK_Q:(i + 1) * SA.BLOCK_Q] = True
+    assert past.sum() == 4 * 16 * 128         # query tiles 0-3, key tile 1
+    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+    ops = SA._index_layout(qi, ki, w, q.dtype)
+
+    def run(bits):
+        o, lse = SA._fwd_pallas(qt, kt, vt, bits, 0.25, True)
+        kl = SA._kl_pallas(qt, kt, *ops, bits, lse, sel.ilse[:, None], 0.25,
+                           True)
+        return o, lse, kl
+    for a, b in zip(run(sel.bits), run(jnp.where(past, -1, sel.bits))):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_the_backward_carries_its_query_sums_across_key_chunks(monkeypatch):
@@ -373,6 +459,8 @@ def test_the_backward_carries_its_query_sums_across_key_chunks(monkeypatch):
     assert int((sel.cut < SA._ALL).sum()) > 0          # a tie cut taken
 
     def grads(impl):
+        sel = SA.select_keys(qi, ki, w, 40, impl=impl, interpret=True)
+
         def loss(*a):
             o, kl = SA.sparse_attention(*a, sel, impl=impl, interpret=True)
             return jnp.sum(o * jnp.cos(o)) + jnp.sum(
@@ -399,12 +487,18 @@ def test_the_backward_chunk_divides_the_row_and_fits_its_budget(l):
 def test_the_census_counts_three_kernel_grids_a_layer(monkeypatch):
     """A layer's step at 32,768 visits 8,320 causal cells on each of the
     forward's, the loss's and the backward's grids, and 256 query tiles of
-    the selection; the cut's four layers 100,864 a row."""
+    the selection; the cut's four layers 100,864 a row, and every layer's
+    forward reads the selection kernel's bits."""
     assert SA.train_cells(32768) == 3 * 8320 + 256 == 25216
     monkeypatch.setattr(SA, "sparse_impl", functools.partial(
         SA.sparse_impl, backend="tpu"))
     model = create_model("keye_vl2_30b_a3b_4l")
     assert model.attn_tiles_visited(32768) == 4 * 25216 == 100864
+    # every layer's forward reads the selection kernel's bits; none under
+    # the array form
+    assert model.dsa_fwd_bits_layers(32768) == 4
+    assert create_model("keye_vl2_30b_a3b_4l", attn_impl="full") \
+        .dsa_fwd_bits_layers(32768) == 0
 
 
 # ---- the holds: each loss trains its own parameters ------------------------
@@ -611,6 +705,7 @@ def test_runner_trains_and_logs_the_sparse_attention_census(tmp_path):
     events = [json.loads(line) for line in open(run / "telemetry.jsonl")]
     start = next(e for e in events if e.get("event") == "run_start")
     assert start["dsa_layers"] == 4
+    assert start["dsa_fwd_bits_layers"] == 0      # the array form runs
     records = [e for e in events if "counters" in e]
     last = records[-1]["counters"]
     assert last["dsa_selected_pairs_total"] > 0
@@ -618,7 +713,7 @@ def test_runner_trains_and_logs_the_sparse_attention_census(tmp_path):
         last["dsa_blocks_causal_total"] > 0
     # the drain hands the census and the loss to the telemetry as above
     from deepfake_detection_tpu.obs import TrainTelemetry
-    t = TrainTelemetry(dsa_layers=4)
+    t = TrainTelemetry(dsa_layers=4, dsa_fwd_bits_layers=4)
     t.on_sparse_attention(10, 2, 3, kl_loss=0.5)
     snap = t.snapshot()
     assert (snap["counters"]["dsa_selected_pairs_total"],
@@ -626,4 +721,5 @@ def test_runner_trains_and_logs_the_sparse_attention_census(tmp_path):
             snap["counters"]["dsa_blocks_causal_total"]) == (10, 2, 3)
     assert snap["gauges"]["dsa_kl_loss"] == 0.5
     assert snap["gauges"]["dsa_layers"] == 4
+    assert snap["gauges"]["dsa_fwd_bits_layers"] == 4
     assert "dfd_train_dsa_kl_loss" in t.render_prometheus()

@@ -467,11 +467,13 @@ def test_glm47flash_step_fits_the_chip(one_chip, monkeypatch, tmp_path):
 def test_sparse_attention_keye_32k_compiles(one_chip, grad):
     """Keye-VL-2.0's learned sparse attention at 32,768 tokens: the
     selection kernel (a (32,768, 128) int32 row of keys in VMEM beside the
-    indexer key's whole row), then the attention of 32 query heads of 128
-    over 4 key heads with the indexer's loss: forward and loss kernels, and
-    the one backward kernel (dQ, dK, dV and the indexer's three gradients
-    from each tile's mask, ``p`` and ``dp`` made once), each making the
-    indexer's 16 heads of 64 again a tile."""
+    indexer key's whole row; the selection packed one bit a pair and the
+    selected scores' log-sum-exp handed over), then the attention of 32
+    query heads of 128 over 4 key heads with the indexer's loss: the
+    forward, which reads the bits and no operand of the indexer, the loss
+    kernel, and the one backward kernel (dQ, dK, dV and the indexer's three
+    gradients from each tile's mask, ``p`` and ``dp`` made once), which
+    makes the indexer's 16 heads of 64 again a tile."""
     import importlib
     SA = importlib.import_module("deepfake_detection_tpu.ops.sparse_attention")
     spec = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
@@ -480,24 +482,50 @@ def test_sparse_attention_keye_32k_compiles(one_chip, grad):
     q, k = spec(1, l, 32, 128), spec(1, l, 4, 128)
     qi, ki, w = spec(1, l, 16, 64), spec(1, l, 64), spec(1, l, 16,
                                                         dt=jnp.float32)
-    ints = spec(1, l, dt=jnp.int32)
+    ints, ilse = spec(1, l, dt=jnp.int32), spec(1, l, dt=jnp.float32)
+    bits = spec(1, l // 512, 16, l, dt=jnp.int32)
 
-    def attend(q, k, v, qi, ki, w, thr, cut):
+    def attend(q, k, v, qi, ki, w, thr, cut, ilse, bits):
         o, kl = SA.sparse_attention(q, k, v, qi, ki, w,
-                                    SA.Selection(thr, cut, None),
+                                    SA.Selection(thr, cut, None, ilse, bits),
                                     scale=128 ** -0.5, impl="pallas",
                                     interpret=False)
         return o.astype(jnp.float32).sum() + kl.sum()
+    args = (q, k, k, qi, ki, w, ints, ints, ilse, bits)
     if grad:
-        compiled = _compile(jax.grad(attend, argnums=range(6)), q, k, k, qi,
-                            ki, w, ints, ints)
+        compiled = _compile(jax.grad(attend, argnums=range(6)), *args)
         # the backward, with the forward whose output it reads
         assert compiled.as_text().count("tpu_custom_call") == 2
     else:
-        compiled = _compile(attend, q, k, k, qi, ki, w, ints, ints)
+        compiled = _compile(attend, *args)
         assert compiled.as_text().count("tpu_custom_call") == 2
         _compile(lambda qi, ki, w: SA.select_keys(
             qi, ki, w, 2048, impl="pallas", interpret=False), qi, ki, w)
+        # the forward launch's operands: q, k, v and the bits alone
+        jaxpr = jax.make_jaxpr(attend)(*args).jaxpr
+        fwd = [e for e in _pallas_calls(jaxpr) if e.params[
+            "jaxpr"].debug_info.func_name == "_fwd_kernel"]
+        assert len(fwd) == 1
+        assert [(x.aval.shape, x.aval.dtype) for x in fwd[0].invars] == [
+            ((1, 32, l, 128), jnp.bfloat16), ((1, 4, l, 128), jnp.bfloat16),
+            ((1, 4, l, 128), jnp.bfloat16), ((1, l // 512, 16, l), jnp.int32)]
+
+
+def _pallas_calls(jaxpr, found=None):
+    """Every ``pallas_call`` equation of ``jaxpr`` and the jaxprs nested in
+    it, in order."""
+    from jax.extend import core as jcore
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else [param]:
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    _pallas_calls(sub.jaxpr, found)
+                elif isinstance(sub, jcore.Jaxpr):
+                    _pallas_calls(sub, found)
+    return found
 
 
 def test_keyevl2_step_fits_the_chip(one_chip, monkeypatch, tmp_path):
@@ -542,6 +570,7 @@ def test_keyevl2_step_fits_the_chip(one_chip, monkeypatch, tmp_path):
                            devices=list(one_chip.device_set))
     program = T.build_program(TrainConfig.from_args(flags), mesh=mesh)
     assert program.moe_layers == (4, 0) and program.dsa_layers == 4
+    assert program.dsa_fwd_bits_layers == 4
     state = jax.eval_shape(lambda: create_train_state(init_model(
         program.model, jax.random.PRNGKey(0), (1, 8), training=True,
         dtype=jnp.int32), program.tx))

@@ -252,6 +252,10 @@ def test_telemetry_is_the_runners(built, tmp_path):
     assert snap["gauges"]["dsa_layers"] == p.dsa_layers == (
         p.model.dsa_layers() if hasattr(p.model, "dsa_layers") else 0)
     assert "dfd_train_dsa_layers" in telemetry.render_prometheus()
+    # of those, the layers whose forward reads the selection kernel's bits:
+    # none on the CPU, where the array form runs
+    assert snap["gauges"]["dsa_fwd_bits_layers"] == \
+        p.dsa_fwd_bits_layers == 0
     assert os.path.isfile(tmp_path / "telemetry.jsonl")
 
 

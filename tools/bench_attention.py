@@ -37,6 +37,13 @@ made from seeded operands, chained the same way (the next launch's ``do``
 is this one's plus 1e-30 of a ``dq`` element), and prints the launch's
 Mosaic kernels and a sha256 of each of its six gradients: run it on two
 commits and compare the digests for their bits.
+``--sparse-fwd`` (TPU only) times the three launches before it alone at
+the same shape, the selection (``_select_pallas``, the next launch's
+``qi`` fed), the forward (``_fwd_pallas``) and the indexer's loss
+(``_kl_pallas``, both the next launch's ``q`` fed), each from a chain of
+``CHAIN``, with the operands each launcher names and a sha256 of what they
+make.  Both probes hand a launcher its operands by its parameters' names,
+so they run with either form of the package at the same path.
 """
 
 from __future__ import annotations
@@ -210,64 +217,152 @@ def bwd_probe(names) -> None:
         probe(name)
 
 
-def sparse_bwd_probe() -> None:
-    import hashlib
-    import importlib
-    import statistics
-
+def _sparse_operands(sa):
+    """The seeded operands of ``SPARSE_SHAPE`` in the kernels' layout,
+    the selection its own kernel made of them and the forward's outputs,
+    by the launchers' parameter names, so that one probe calls either
+    form of the package (:func:`_sparse_call`); the cotangents ``do``,
+    ``gkl``; the launches (name: (launcher, the operand its chain feeds))."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
-    sa = importlib.import_module("deepfake_detection_tpu.ops.sparse_attention")
-    assert jax.default_backend() == "tpu", jax.default_backend()
     b, l, h, hk, d, nj, e, topk = SPARSE_SHAPE
     keys = jax.random.split(jax.random.PRNGKey(0), 8)
     normal = lambda i, *s, dt=jnp.bfloat16: jax.random.normal(  # noqa: E731
         keys[i], s, jnp.float32).astype(dt)
     q, k, v = normal(0, b, h, l, d), normal(1, b, hk, l, d), \
         normal(2, b, hk, l, d)
-    qi, ki, w = normal(3, b, l, nj, e), normal(4, b, l, e), \
-        normal(5, b, l, nj, dt=jnp.float32) * (nj * e) ** -0.5
-    sel = sa.select_keys(qi, ki, w, topk, impl="pallas", interpret=False)
-    scale = d ** -0.5
-    ops = (q, k, v, *sa._index_layout(qi, ki, w, q.dtype), sel.thr[:, None],
-           sel.cut[:, None])
-    res = ops + tuple(sa._fwd_pallas(*ops, scale, False))
-    do, gkl = normal(6, b, h, l, d), normal(7, b, l, dt=jnp.float32)
+    qi, ki, w = sa._index_layout(
+        normal(3, b, l, nj, e), normal(4, b, l, e),
+        normal(5, b, l, nj, dt=jnp.float32) * (nj * e) ** -0.5, q.dtype)
+    named = dict(q=q, k=k, v=v, qi=qi, ki=ki, w=w, topk=topk,
+                 scale=d ** -0.5, interpret=False)
+    launches = {"select": (sa._select_pallas, "qi"),
+                "fwd": (sa._fwd_pallas, "q"), "kl": (sa._kl_pallas, "q")}
+    sel = _sparse_call(sa._select_pallas, named)
+    # a row's statistics as the kernels take them, (B, 1, L)
+    named.update({n: x[:, None] if x.ndim == 2 else x
+                  for n, x in sel._asdict().items() if x is not None})
+    named.update(zip(("o", "lse", "ilse"), _sparse_call(sa._fwd_pallas,
+                                                        named)))
+    named["kl"] = _sparse_call(sa._kl_pallas, named)
+    return named, normal(6, b, h, l, d), normal(7, b, l, dt=jnp.float32), \
+        launches
+
+
+def _sparse_call(launcher, named, **over):
+    """``launcher`` on the operands ``named`` gives by its parameters'
+    names, ``over`` taking precedence."""
+    args = dict(named, **over)
+    return launcher(**{p: args[p] for p in _params(launcher)})
+
+
+def _params(launcher):
+    import inspect
+    return list(inspect.signature(launcher).parameters)
+
+
+def _sparse_chain_ms(step, x, *rest) -> float:
+    """Device ms of one launch of ``step(x, *rest)`` from ``CHAIN``
+    launches in one program, each fed by the one before (the next ``x`` is
+    this one's plus 1e-30 of the first output's first element), from the
+    median of five."""
+    import statistics
+
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def chain(x, *rest):
+        keep = jnp.float32(0)
+        for _ in range(CHAIN):
+            first, *others = jax.tree.leaves(step(x, *rest))
+            x = x + (first.reshape(-1)[0].astype(jnp.float32) * 1e-30
+                     ).astype(x.dtype)
+            for o in others:
+                keep = keep + o.reshape(-1)[0].astype(jnp.float32)
+        return x, keep
+    jax.block_until_ready(chain(x, *rest))
+    times = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        jax.block_until_ready(chain(x, *rest))
+        times.append(time.perf_counter() - t0)
+    return 1e3 * statistics.median(times) / CHAIN
+
+
+def _sparse_cells(sa, l):
+    return sum(sa._last_k(i, sa.BLOCK_Q, sa.BLOCK_K) + 1
+               for i in range(l // sa.BLOCK_Q))
+
+
+def _digest(x) -> str:
+    import hashlib
+
+    import numpy as np
+    return hashlib.sha256(np.asarray(x).tobytes()).hexdigest()
+
+
+def sparse_fwd_probe() -> None:
+    import importlib
+
+    import jax
+
+    sa = importlib.import_module("deepfake_detection_tpu.ops.sparse_attention")
+    assert jax.default_backend() == "tpu", jax.default_backend()
+    named, _, _, launches = _sparse_operands(sa)
+    b, l, h, hk, d, nj, e, topk = SPARSE_SHAPE
+    row = {"shape": "keye_dsa_32k", "seq_len": l, "heads": [h, hk, nj],
+           "topk": topk, "selected_pairs": int(named["counts"][0]),
+           "chain": CHAIN}
+    arrays = {n: x for n, x in named.items() if isinstance(x, jax.Array)}
+    statics = {n: x for n, x in named.items() if n not in arrays}
+    for name, (launcher, fed) in launches.items():
+        operands = [n for n in _params(launcher) if n in arrays]
+
+        def step(x, rest, launcher=launcher, fed=fed):
+            return _sparse_call(launcher, dict(statics, **rest), **{fed: x})
+        row[name + "_ms"] = _sparse_chain_ms(
+            step, arrays[fed], {n: arrays[n] for n in operands if n != fed})
+        row[name + "_operands"] = operands
+    cells = _sparse_cells(sa, l)
+    row["fwd_us_per_cell"] = 1e3 * row["fwd_ms"] / cells
+    row["kl_us_per_cell"] = 1e3 * row["kl_ms"] / cells
+    row["sha256"] = {n: _digest(named[n]) for n in
+                     ("thr", "cut", "counts", "ilse", "bits", "o", "lse",
+                      "kl") if n in named}
+    row["device"] = jax.devices()[0].device_kind
+    print(json.dumps(row), flush=True)
+
+
+def sparse_bwd_probe() -> None:
+    import importlib
+
+    import jax
+
+    sa = importlib.import_module("deepfake_detection_tpu.ops.sparse_attention")
+    assert jax.default_backend() == "tpu", jax.default_backend()
+    named, do, gkl, _ = _sparse_operands(sa)
+    b, l, h, hk, d, nj, e, topk = SPARSE_SHAPE
+    res = tuple(named[n] for n in ("q", "k", "v", "qi", "ki", "w", "thr",
+                                   "cut", "o", "lse", "ilse"))
+    scale = named["scale"]
 
     def bwd(do, res, gkl):
         return sa._bwd_pallas(res, do, gkl, scale, False)
 
-    @jax.jit
-    def chain(do, res, gkl):
-        keep = jnp.float32(0)
-        for _ in range(CHAIN):
-            first, *others = bwd(do, res, gkl)
-            do = do + (first.reshape(-1)[0] * 1e-30).astype(do.dtype)
-            for o in others:
-                keep = keep + o.reshape(-1)[0]
-        return do, keep
     one = jax.jit(bwd)
     grads = one(do, res, gkl)
-    jax.block_until_ready(chain(do, res, gkl))
-    times = []
-    for _ in range(5):
-        t0 = time.perf_counter()
-        jax.block_until_ready(chain(do, res, gkl))
-        times.append(time.perf_counter() - t0)
-    bwd_ms = 1e3 * statistics.median(times) / CHAIN
-    cells = sum(sa._last_k(i, sa.BLOCK_Q, sa.BLOCK_K) + 1
-                for i in range(l // sa.BLOCK_Q))
+    bwd_ms = _sparse_chain_ms(bwd, do, res, gkl)
     text = one.lower(do, res, gkl).compile().as_text()
     print(json.dumps({
         "shape": "keye_dsa_32k", "seq_len": l, "heads": [h, hk, nj],
-        "topk": topk, "selected_pairs": int(sel.counts[0]),
+        "topk": topk, "selected_pairs": int(named["counts"][0]),
         "kernels": text.count("tpu_custom_call"), "chain": CHAIN,
-        "bwd_ms": bwd_ms, "bwd_us_per_cell": 1e3 * bwd_ms / cells,
-        "sha256": {name: hashlib.sha256(np.asarray(g).tobytes()).hexdigest()
-                   for name, g in zip(("dq", "dk", "dv", "dqi", "dki", "dw"),
-                                      grads)},
+        "bwd_ms": bwd_ms, "bwd_us_per_cell": 1e3 * bwd_ms / _sparse_cells(
+            sa, l),
+        "sha256": {name: _digest(g) for name, g in zip(
+            ("dq", "dk", "dv", "dqi", "dki", "dw"), grads)},
         "device": jax.devices()[0].device_kind}), flush=True)
 
 
@@ -325,6 +420,10 @@ def main() -> None:
     ap.add_argument("--fwd", nargs="*", choices=sorted(BWD_SHAPES),
                     metavar="SHAPE", help="time the forward launch and hash "
                     "its out and lse at these cells' shapes (none: all)")
+    ap.add_argument("--sparse-fwd", action="store_true",
+                    help="time the sparse attention's selection, forward and "
+                    "loss launches at train_keye_dsa_32k's shape and hash "
+                    "their outputs")
     ap.add_argument("--sparse-bwd", action="store_true",
                     help="time the sparse attention's backward launch at "
                     "train_keye_dsa_32k's shape and hash its gradients")
@@ -340,6 +439,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.fwd is not None:
         return fwd_probe(args.fwd or sorted(BWD_SHAPES))
+    if args.sparse_fwd:
+        return sparse_fwd_probe()
     if args.sparse_bwd:
         return sparse_bwd_probe()
     if args.bwd is not None:
